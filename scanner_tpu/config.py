@@ -38,12 +38,6 @@ def default_config() -> Dict[str, Any]:
             "metrics_port": 0,
         },
         "perf": {
-            # directory for JAX's persistent compilation cache: jitted
-            # kernel executables (one per bucket shape, see PERF.md §5)
-            # survive process restarts instead of recompiling.  "" (the
-            # default) disables; SCANNER_TPU_COMPILATION_CACHE overrides
-            # per process.
-            "compilation_cache_dir": "",
             # paged per-device HBM frame cache (engine/framecache.py):
             # decoded frames are pooled in keyframe-aligned pages and
             # reused across tasks (stencil overlap, Gather samplings,
@@ -244,13 +238,6 @@ class Config:
         if n.get("master"):
             return f"{n['master']}:{n['master_port']}"
         return None
-
-    @property
-    def compilation_cache_dir(self) -> Optional[str]:
-        """Persistent XLA compilation-cache directory, or None when
-        disabled (the default)."""
-        d = self.config.get("perf", {}).get("compilation_cache_dir", "")
-        return d or None
 
     @property
     def frame_cache_enabled(self) -> bool:

@@ -57,6 +57,9 @@ TPU_TOPOLOGIES = {
     "v5litepod": {1: "1x1", 4: "2x2", 8: "2x4", 16: "4x4", 32: "4x8"},
     "v5p": {8: "2x2x1", 16: "2x2x2", 32: "2x4x2"},
 }
+# where a worker pod keeps its XLA compile cache when the cluster config
+# names no directory: a fixed path on the container's own filesystem
+POD_CACHE_DIR = "/var/cache/scanner_tpu/xla"
 
 
 def tpu_topology(tpu_type: str) -> str:
@@ -149,13 +152,14 @@ class ClusterConfig:
     # serves it on that port on master AND workers and exposes the
     # container port for Prometheus scraping (docs/observability.md)
     metrics_port: int = 0
-    # persistent XLA compilation-cache directory for workers ("" =
-    # disabled).  Point it at pod-local scratch or a gs:// prefix shared
-    # by the fleet: a restarted/rescheduled worker then re-loads its
-    # jitted kernel executables instead of re-paying seconds of TPU
-    # compile per bucket shape (PERF.md §5).  Wired into the ConfigMap
-    # toml ([perf] section) and each worker's
-    # SCANNER_TPU_COMPILATION_CACHE env var.
+    # persistent XLA compilation-cache directory for workers, emitted as
+    # each worker's JAX_COMPILATION_CACHE_DIR env var (JAX reads it
+    # itself).  "" = POD_CACHE_DIR, pod-local scratch that dies with
+    # the pod: the image's installed package has no writable checkout
+    # for the in-code default (util/jaxenv.py).  Point it at a gs://
+    # prefix shared by the fleet and a restarted/rescheduled worker
+    # re-loads its jitted kernel executables instead of re-paying TPU
+    # compile time per bucket shape (PERF.md "Bring-up on v5e").
     compilation_cache_dir: str = ""
     # seconds kubernetes waits between SIGTERM and SIGKILL on worker
     # pods.  start_worker maps SIGTERM to drain mode (finish in-flight
@@ -316,9 +320,6 @@ def config_manifest(cfg: ClusterConfig) -> Dict:
                     "worker_port": 5001,
                     "metrics_port": cfg.metrics_port},
     }
-    if cfg.compilation_cache_dir:
-        sections["perf"] = {
-            "compilation_cache_dir": cfg.compilation_cache_dir}
     if cfg.alert_rules:
         sections["alerts"] = {"rules": cfg.alert_rules}
     sections["remediation"] = {
@@ -481,11 +482,11 @@ def _worker_statefulset(cfg: ClusterConfig, name: str, replicas: int,
                             {"name": "POD_NAME",
                              "valueFrom": {"fieldRef": {
                                  "fieldPath": "metadata.name"}}},
-                            # worker-side persistent XLA executable cache
-                            # (Worker.__init__ picks the env var up)
-                            *([{"name": "SCANNER_TPU_COMPILATION_CACHE",
-                                "value": cfg.compilation_cache_dir}]
-                              if cfg.compilation_cache_dir else []),
+                            # worker-side persistent XLA executable
+                            # cache (JAX reads the variable itself)
+                            {"name": "JAX_COMPILATION_CACHE_DIR",
+                             "value": cfg.compilation_cache_dir
+                             or POD_CACHE_DIR},
                             # gang member runners rendezvous with this
                             # bound (engine/gang.py); 0 also strips the
                             # gang port reservation from the worker

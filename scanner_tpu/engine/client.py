@@ -89,7 +89,6 @@ class Client:
                  config_path: Optional[str] = None,
                  storage_options: Optional[Dict[str, Any]] = None,
                  metrics_port: Optional[int] = None,
-                 compilation_cache_dir: Optional[str] = None,
                  **kw):
         if config_path is not None:
             from ..config import Config
@@ -184,22 +183,17 @@ class Client:
                 master = cfg.master_address
             if metrics_port is None:
                 metrics_port = cfg.metrics_port
-            # config is the LAST fallback: an explicit arg or the
-            # per-process env var must win over the config file
-            if compilation_cache_dir is None \
-                    and not os.environ.get("SCANNER_TPU_COMPILATION_CACHE"):
-                compilation_cache_dir = cfg.compilation_cache_dir
             # [faults] plan arms the chaos-injection registry for this
             # process (env var SCANNER_TPU_FAULTS, read at import time,
             # wins — it is the per-process override)
             if cfg.faults_plan and not os.environ.get("SCANNER_TPU_FAULTS"):
                 from ..util import faults
                 faults.install(cfg.faults_plan)
-        # persistent XLA executable cache (arg > SCANNER_TPU_COMPILATION_CACHE
-        # env > [perf] compilation_cache_dir config; unset = no-op): in-process
-        # jobs re-load jitted kernel executables across runs (PERF.md §5)
+        # persistent XLA executable cache, placed from outside
+        # (JAX_COMPILATION_CACHE_DIR) or at the fixed in-checkout default:
+        # jobs re-load jitted kernel executables across processes
         from ..util.jaxenv import enable_compilation_cache
-        enable_compilation_cache(compilation_cache_dir)
+        enable_compilation_cache()
         storage_type = storage_type or "posix"
         if db_path is None and storage_type == "posix":
             db_path = os.path.expanduser("~/.scanner_tpu/db")

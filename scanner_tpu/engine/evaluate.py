@@ -29,6 +29,7 @@ steady-state tasks never stall on a compile."""
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -63,11 +64,13 @@ _M_OP_SECONDS = _mx.registry().counter(
     labels=["op"])
 _M_OP_RECOMPILES = _mx.registry().counter(
     "scanner_tpu_op_recompiles_total",
-    "New input (device, shape, dtype) signatures seen per op — each one "
-    "forces an XLA recompile of a jitted kernel; a climbing count means "
-    "shape churn.  With bucketed dispatch this is bounded by the op's "
-    "bucket-ladder size PER CHIP (evaluator affinity compiles each "
-    "ladder once per assigned device).",
+    "New input (device, shape, dtype) signatures seen per kernel "
+    "instance — each one is a fresh executable for a jitted kernel "
+    "unless a cache serves it; a climbing count means shape churn.  "
+    "With bucketed dispatch this is bounded by the op's bucket-ladder "
+    "size PER CHIP (evaluator affinity compiles each ladder once per "
+    "assigned device).  Compiles that really ran are "
+    "scanner_tpu_compile_total.",
     labels=["op", "device"])
 _M_OP_PAD_ROWS = _mx.registry().counter(
     "scanner_tpu_op_pad_rows_total",
@@ -81,6 +84,41 @@ _M_OP_PRECOMPILE = _mx.registry().gauge(
     "op's bucket ladder on its assigned chip (overlapped with the "
     "first task's decode).",
     labels=["op", "device"])
+_M_WARMING = _mx.registry().gauge(
+    "scanner_tpu_evaluator_warming",
+    "Evaluator work in flight that may block on XLA rather than on the "
+    "device: kernel construction and set-up, bucket-ladder warm-up "
+    "runs, and first dispatches of a new input signature.  While it "
+    "is above 0 a full evaluate queue or a busy evaluate stage says "
+    "nothing about load; the load-signal alert rules hold quiet on it "
+    "(util/health.py `unless`).")
+
+
+@contextlib.contextmanager
+def _warming():
+    _M_WARMING.inc()
+    try:
+        yield
+    finally:
+        _M_WARMING.dec()
+
+
+@contextlib.contextmanager
+def _first_call(op: str, dev_label: str, bucket: int, signature: str,
+                track_cost: bool,
+                members: Optional[Sequence[str]] = None):
+    """Around the first dispatch of a new (device, shape, dtype)
+    signature — the call that may compile: counted as warming, and with
+    coststats on any XLA compile inside lands in the compile ledger
+    under (op, device, bucket)."""
+    with _warming():
+        if track_cost:
+            with _cs.observe_compiles(op, dev_label, bucket, signature,
+                                      members=members):
+                yield
+        else:
+            yield
+
 
 Elem = Any  # np.ndarray | bytes | arbitrary python object | NullElement
 ColKey = Tuple[int, str]  # (node id, column name)
@@ -411,15 +449,18 @@ class KernelInstance:
         return args
 
     def precompile(self, ladder: Sequence[int], h: int, w: int) -> None:
-        """Compile this kernel's jitted function at every ladder bucket
-        (best-effort: a failing warm-up shape is skipped; the real call
-        then compiles it).  Runs on the evaluator's warm-up thread;
-        ensure_warm() on the evaluation thread claims or waits."""
+        """Compile this kernel's jitted function at every ladder bucket.
+        A warm-up shape that fails is logged at WARNING with its
+        exception and the rest of the ladder is abandoned (the job stays
+        alive; the real call then compiles — or raises — itself).  Runs
+        on the evaluator's warm-up thread; ensure_warm() on the
+        evaluation thread claims or waits."""
         with self._warm_lock:
             if self._warm_state != "pending":
                 return  # claimed by a real call racing ahead of us
             self._warm_state = "running"
         t0 = time.time()
+        _M_WARMING.inc()
         try:
             for b in ladder:
                 args = self._example_args(b, h, w)
@@ -453,14 +494,17 @@ class KernelInstance:
                                                  self.dev_label, b,
                                                  f"warmup:b{b}"):
                         self.kernel.execute(*args)
-                except Exception:  # noqa: BLE001 — warm-up is best-effort
-                    _log.debug("precompile of %s at batch %d failed",
-                               self.node.name, b, exc_info=True)
+                except Exception:  # noqa: BLE001 — the job outlives
+                    # a failed warm-up, but never silently
+                    _log.warning("precompile of %s at batch %d failed; "
+                                 "abandoning its ladder warm-up",
+                                 self.node.name, b, exc_info=True)
                     return
             _M_OP_PRECOMPILE.labels(op=self.node.name,
                                     device=self.dev_label).set(
                 time.time() - t0)
         finally:
+            _M_WARMING.dec()
             with self._warm_lock:
                 self._warm_state = "done"
             self._warm_done.set()
@@ -614,8 +658,9 @@ class FusedKernelInstance:
                         [ki.node for ki in self.members],
                         self._stream_args, self.windows)
                 except Exception:  # noqa: BLE001 — fall back per instance
-                    _log.debug("shared program build failed for chain "
-                               "%s", self.chain_id, exc_info=True)
+                    _log.warning("shared program build failed for chain "
+                                 "%s; falling back to a per-instance jit",
+                                 self.chain_id, exc_info=True)
                     fn = None
                 if fn is not None:
                     with _CHAIN_PROGRAMS_LOCK:
@@ -745,18 +790,8 @@ class FusedKernelInstance:
                 return
             self._warm_state = "running"
         t0 = time.time()
+        _M_WARMING.inc()
         try:
-            # bind job-0 stream args first: members like Resize get
-            # their geometry from new_stream, and an unbound warm-up
-            # would compile a degenerate (e.g. 0x0-output) program the
-            # real calls never use.  The real dispatch rebinds only if
-            # its (job, slice group) differs, so the warmed executable
-            # survives into the first call.
-            try:
-                self.bind_stream(0, 0)
-            except Exception:  # noqa: BLE001 — warm-up is best-effort
-                _log.debug("warm-up stream bind failed for chain %s",
-                           self.chain_id, exc_info=True)
             for b in ladder:
                 arr = np.zeros((b * self.width, h, w, 3), np.uint8)
                 if self.device is not None:
@@ -770,14 +805,17 @@ class FusedKernelInstance:
                                                  f"warmup:b{b}",
                                                  members=self.member_names):
                         self.execute(arr)
-                except Exception:  # noqa: BLE001 — warm-up is best-effort
-                    _log.debug("precompile of chain %s at batch %d "
-                               "failed", self.chain_id, b, exc_info=True)
+                except Exception:  # noqa: BLE001 — the job outlives
+                    # a failed warm-up, but never silently
+                    _log.warning("precompile of chain %s at batch %d "
+                                 "failed; abandoning its ladder warm-up",
+                                 self.chain_id, b, exc_info=True)
                     return
             _M_OP_PRECOMPILE.labels(op=self.chain_id,
                                     device=self.dev_label).set(
                 time.time() - t0)
         finally:
+            _M_WARMING.dec()
             with self._warm_lock:
                 self._warm_state = "done"
             self._warm_done.set()
@@ -798,6 +836,16 @@ class FusedKernelInstance:
 # remediation playbook (engine/controller.py) re-warms bucket ladders
 # process-wide through rewarm_all() without owning evaluator lifetimes
 _LIVE_EVALUATORS: "weakref.WeakSet" = weakref.WeakSet()
+# evaluator threads add and discard while another thread lists
+_LIVE_LOCK = threading.Lock()
+
+
+def live_evaluators() -> List["TaskEvaluator"]:
+    """The evaluators alive right now (between construction and
+    close()): what a run is executing on, for the remediation action
+    below and for checks that look at a run's own kernel instances."""
+    with _LIVE_LOCK:
+        return list(_LIVE_EVALUATORS)
 
 
 def rewarm_all() -> int:
@@ -806,7 +854,7 @@ def rewarm_all() -> int:
     Returns the total number of kernels scheduled; best-effort — an
     evaluator failing to re-warm never raises out of the actuator."""
     total = 0
-    for te in list(_LIVE_EVALUATORS):
+    for te in live_evaluators():
         try:
             total += te.rewarm()
         except Exception:  # noqa: BLE001 — remediation is best-effort
@@ -836,19 +884,20 @@ class TaskEvaluator:
         if devices is None:
             devices = instance_devices(instance, instances)
         self.kernels: Dict[int, KernelInstance] = {}
-        for n in info.ops:
-            if not n.is_builtin:
+        # kernel construction and set-up load weights and may compile
+        # (a model's init): the pipeline waits on this, not on the chip
+        with _warming():
+            for n in info.ops:
+                if n.is_builtin:
+                    continue
                 # only device-placed kernels get the chip list: a kernel
                 # explicitly pinned to CPU must not dp-shard onto TPU
-                n_devs = devices \
-                    if n.effective_device() == DeviceType.TPU else None
-                ki = KernelInstance(
-                    n, profiler, n_devs,
-                    device=self.device
-                    if n.effective_device() == DeviceType.TPU else None)
-                self.kernels[n.id] = ki
-        for ki in self.kernels.values():
-            ki.setup(fetch=not skip_fetch_resources)
+                on_chip = n.effective_device() == DeviceType.TPU
+                self.kernels[n.id] = KernelInstance(
+                    n, profiler, devices if on_chip else None,
+                    device=self.device if on_chip else None)
+            for ki in self.kernels.values():
+                ki.setup(fetch=not skip_fetch_resources)
         # whole-pipeline fusion (graph/fusion.py): maximal runs of
         # fusable consecutive device ops execute as ONE jitted program.
         # Non-tail members never dispatch (or materialize an output
@@ -875,13 +924,22 @@ class TaskEvaluator:
                 and _bucketing_enabled():
             targets = self._warm_targets(precompile)
             for ki, _ladder in targets:
+                # bind job-0 stream args HERE, before the warm-up thread
+                # exists (binding on that thread would race the first
+                # task's own bind): kernels like Resize get their
+                # geometry from new_stream, and an unbound warm-up
+                # divides by a 0x0 output size.  The real dispatch
+                # rebinds only if its (job, slice group) differs, so
+                # the warmed executable survives into the first call.
+                ki.bind_stream(0, 0)
                 ki._warm_state = "pending"
             self._spawn_warm(targets, precompile)
         # live-evaluator registry: the recompile_storm remediation
         # (engine/controller.py -> rewarm_all) re-schedules ladder
         # warm-ups on whatever evaluators currently exist; weak so a
         # closed/forgotten evaluator never pins its kernels alive
-        _LIVE_EVALUATORS.add(self)
+        with _LIVE_LOCK:
+            _LIVE_EVALUATORS.add(self)
 
     def _warm_targets(self, precompile: Tuple[int, int, int]
                       ) -> List[Tuple[Any, List[int]]]:
@@ -948,7 +1006,8 @@ class TaskEvaluator:
         return len(claimed)
 
     def close(self) -> None:
-        _LIVE_EVALUATORS.discard(self)
+        with _LIVE_LOCK:
+            _LIVE_EVALUATORS.discard(self)
         for ki in self.kernels.values():
             ki.close()
 
@@ -1329,19 +1388,21 @@ class TaskEvaluator:
                                     "xla.recompile", op=n.name,
                                     device=ki.dev_label)
                             t_call = time.time()
-                            if new_sig and track_cost:
+                            if new_sig:
                                 # first call of a fresh signature: any
                                 # XLA compile inside lands in the
                                 # compile ledger under this (op,
                                 # device, bucket)
-                                with ki._call_lock, _cs.observe_compiles(
+                                with ki._call_lock, _first_call(
                                         n.name, ki.dev_label,
-                                        len(exec_sel), repr(sig[1:])):
+                                        len(exec_sel), repr(sig[1:]),
+                                        track_cost):
                                     res = ki.kernel.execute(*args)
-                                # drain this unmeasured call's queued
-                                # device work so the NEXT (measured)
-                                # call times only itself
-                                res = _cs.block_until_ready(res)
+                                if track_cost:
+                                    # drain this unmeasured call's
+                                    # queued device work so the NEXT
+                                    # (measured) call times only itself
+                                    res = _cs.block_until_ready(res)
                             else:
                                 with ki._call_lock:
                                     res = ki.kernel.execute(*args)
@@ -1613,15 +1674,16 @@ class TaskEvaluator:
                                            op=fki.chain_id,
                                            device=fki.dev_label)
                     t_call = time.time()
-                    if new_sig and track_cost:
+                    if new_sig:
                         # fresh signature: ONE ledger entry for the
                         # whole chain, members recorded for attribution
-                        with fki._call_lock, _cs.observe_compiles(
+                        with fki._call_lock, _first_call(
                                 fki.chain_id, fki.dev_label,
-                                len(exec_sel), repr(sig[1:]),
+                                len(exec_sel), repr(sig[1:]), track_cost,
                                 members=fki.member_names):
                             res = fki.execute(arr)
-                        res = _cs.block_until_ready(res)
+                        if track_cost:
+                            res = _cs.block_until_ready(res)
                     else:
                         with fki._call_lock:
                             res = fki.execute(arr)

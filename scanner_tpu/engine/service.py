@@ -3281,14 +3281,12 @@ class Worker:
                  coordinator=None,
                  metrics_port: Optional[int] = None,
                  metrics_host: str = "0.0.0.0",
-                 advertise_host: Optional[str] = None,
-                 compilation_cache_dir: Optional[str] = None):
+                 advertise_host: Optional[str] = None):
         # persistent XLA executable cache: a restarted/rescheduled worker
         # re-loads its jitted kernels' executables instead of recompiling
-        # (falls back to the SCANNER_TPU_COMPILATION_CACHE env var the
-        # deploy manifests set; no-op when neither is configured)
+        # (the deploy manifests place it via JAX_COMPILATION_CACHE_DIR)
         from ..util.jaxenv import enable_compilation_cache
-        enable_compilation_cache(compilation_cache_dir)
+        enable_compilation_cache()
         if coordinator is not None:
             # join the multi-process JAX runtime BEFORE any backend touch:
             # meshes built by kernels then span all participating hosts

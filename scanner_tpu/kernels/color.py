@@ -2,7 +2,7 @@
 
 The decode pipeline can ship planar I420 (1.5 B/px) to the accelerator
 instead of packed RGB24 (3 B/px) and convert there — halving host->device
-bytes, the first-order term of every device pipeline (PERF.md §1).  The
+bytes, the first-order term of every device pipeline (PERF.md §5).  The
 reference did the same on GPU: NV12 frames converted by a CUDA kernel
 (reference scanner/util/image.cu:22 nv12_to_rgb); here the conversion is
 a jit-compiled jnp op XLA fuses ahead of the first consumer kernel.

@@ -26,25 +26,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:  # pallas ships with jax; guard for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-# vma (varying-mesh-axes) tracking is a newer-jax feature: there,
-# ShapeDtypeStruct takes a `vma=` kwarg the ring path must set when
-# calling inside shard_map.  Old releases have no vma tracking at all —
-# the kwarg must simply be dropped (probed once, version-static).
-try:
-    jax.ShapeDtypeStruct((), jnp.float32, vma=frozenset())
-    _HAVE_VMA = True
-except TypeError:
-    _HAVE_VMA = False
 
 
 def _flash_kernel(offs_ref,                      # SMEM (2,): q_off, k_off
@@ -131,7 +116,7 @@ def flash_block_update(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     bk = _block_size(Tk, block_k)
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
-    vkw = {} if vma is None or not _HAVE_VMA else {"vma": frozenset(vma)}
+    vkw = {} if vma is None else {"vma": frozenset(vma)}
     grid = (BH, Tq // bq, Tk // bk)
     kern = functools.partial(_flash_kernel, causal=causal,
                              block_q=bq, block_k=bk)

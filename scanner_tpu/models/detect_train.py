@@ -338,9 +338,8 @@ def main(argv: Optional[list] = None) -> None:
     ap.add_argument("--which", default="all",
                     choices=["all", "detect", "face", "embed"])
     ap.add_argument("--cpu", action="store_true",
-                    help="force the CPU backend (an ambient accelerator "
-                    "plugin can override JAX_PLATFORMS at config level; "
-                    "this forces it before the first backend touch)")
+                    help="force the CPU backend, before the first "
+                    "backend touch")
     args = ap.parse_args(argv)
     if args.cpu:
         from ..util.jaxenv import force_cpu_platform

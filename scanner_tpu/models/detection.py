@@ -215,7 +215,7 @@ class ObjectDetect(Kernel):
         `valid`-padded (see unpack_detections).  Returned WITHOUT a host
         sync: device arrays chain through the column store and the sink
         fetches once per task (a per-packet fetch would serialize the
-        pipeline on d2h latency, PERF.md §1)."""
+        pipeline on d2h latency, PERF.md §5)."""
         images = jnp.asarray(frame)
         # SAME-padded stride-16 backbone -> ceil-divided feature map
         fh = -(-images.shape[1] // 16)

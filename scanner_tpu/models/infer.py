@@ -53,6 +53,13 @@ class DataParallelApply:
                 params, NamedSharding(self._mesh, P()))
         else:
             self._mesh = None
+            # an instance that owns ONE chip commits its weights there:
+            # init_or_restore leaves them uncommitted on the default
+            # device (chip 0), so instances 1..n-1 would otherwise
+            # re-ship them to their own chip on every call
+            if self.devices:
+                import jax
+                params = jax.device_put(params, self.devices[0])
             self.params = params
 
     def cost_flops(self, *args):

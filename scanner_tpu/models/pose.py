@@ -339,7 +339,7 @@ class PoseDetect(Kernel):
         def apply_and_peaks(params, clip):
             """Forward + on-device argmax: ship (B,K,3) keypoints off the
             chip, not (B,h,w,K) heatmaps — heatmaps are ~MBs per batch
-            and the d2h hop is latency-bound (PERF.md §1)."""
+            and the d2h hop is latency-bound (PERF.md §5)."""
             heat = self.model.apply(params, clip)[:, 0]   # (B, h, w, K)
             B, h, w, K = heat.shape
             flat = heat.reshape(B, h * w, K)

@@ -250,7 +250,7 @@ class InstanceSegment(Kernel):
 
     def execute(self, frame: Sequence[FrameType]) -> Sequence[Any]:
         """Returns a (B, top_k, 6 + M*M) float32 batch, device-resident
-        (single fetch per task at the sink, PERF.md §1)."""
+        (single fetch per task at the sink, PERF.md §5)."""
         images = jnp.asarray(frame)
         fh = -(-images.shape[1] // 16)
         fw = -(-images.shape[2] // 16)

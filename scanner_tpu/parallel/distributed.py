@@ -107,11 +107,7 @@ def initialize(config: CoordinatorConfig,
     # runtimes keep their native ICI/DCN collectives.
     plats = (os.environ.get("JAX_PLATFORMS") or "").lower()
     if "cpu" in [p.strip() for p in plats.split(",")]:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # noqa: BLE001 — older/newer jax without
-            pass           # the flag: keep the default behavior
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     kwargs = {}
     if config.local_device_ids is not None:

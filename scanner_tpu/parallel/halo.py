@@ -16,9 +16,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..util.jaxenv import axis_size as _axis_size
-from ..util.jaxenv import shard_map
 
 
 def _halo_exchange_block(x: jnp.ndarray, lo: int, hi: int,
@@ -27,7 +26,7 @@ def _halo_exchange_block(x: jnp.ndarray, lo: int, hi: int,
     with `lo` trailing rows of the left neighbor and `hi` leading rows of
     the right neighbor.  Edge shards repeat their own edge (REPEAT_EDGE,
     matching the engine's stencil boundary)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     parts = []
     if lo > 0:

@@ -27,7 +27,7 @@ from typing import Any, Callable, List, Sequence
 
 import jax
 import jax.numpy as jnp
-from ..util.jaxenv import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

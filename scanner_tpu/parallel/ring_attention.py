@@ -17,14 +17,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..util.jaxenv import axis_size as _axis_size
-from ..util.jaxenv import pvary as _pvary
-from ..util.jaxenv import shard_map
 
 # single source of truth: the pallas kernel's masked-row guards compare
 # the m carry this module initializes against the same sentinel
-from ..kernels.pallas_attention import HAVE_PALLAS, NEG_INF
+from ..kernels.pallas_attention import NEG_INF
 
 
 def _flash_block_k(tl: int, block_k: Optional[int]) -> int:
@@ -48,7 +46,7 @@ def _ring_attention_block(q, k, v, axis_name: str, causal: bool,
     (B, H, Tl, block_k) instead of (B, Tl, Tl) per step — the long-T
     memory bound that makes ring attention worthwhile in the first
     place."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
     s = scale if scale is not None else (D ** -0.5)
@@ -59,7 +57,7 @@ def _ring_attention_block(q, k, v, axis_name: str, causal: bool,
     # accumulators: running max m, normalizer l, weighted value sum acc.
     # pcast marks them device-varying over the ring axis so the fori_loop
     # carry types match (shard_map vma tracking).
-    vary = lambda x: _pvary(x, (axis_name,))
+    vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
     m0 = vary(jnp.full((B, H, Tl), NEG_INF, jnp.float32))
     l0 = vary(jnp.zeros((B, H, Tl), jnp.float32))
     acc0 = vary(jnp.zeros((B, H, Tl, D), jnp.float32))
@@ -120,7 +118,7 @@ def _ring_attention_block_pallas(q, k, v, axis_name: str, causal: bool,
     logits stay in VMEM, the online-softmax update fuses with both MXU
     matmuls.  Exactness is identical to the XLA path."""
     from ..kernels.pallas_attention import flash_block_update
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Tl, H, D = q.shape
     s = scale if scale is not None else (D ** -0.5)
@@ -128,7 +126,7 @@ def _ring_attention_block_pallas(q, k, v, axis_name: str, causal: bool,
     qf = jnp.transpose(q.astype(jnp.float32) * s, (0, 2, 1, 3)) \
         .reshape(B * H, Tl, D)
 
-    vary = lambda x: _pvary(x, (axis_name,))
+    vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
     m0 = vary(jnp.full((B * H, Tl), NEG_INF, jnp.float32))
     l0 = vary(jnp.zeros((B * H, Tl), jnp.float32))
     acc0 = vary(jnp.zeros((B * H, Tl, D), jnp.float32))
@@ -185,18 +183,11 @@ def make_ring_attention(mesh: Mesh, axis: str = "sp", causal: bool = False,
         return xla_sm
     if impl != "pallas":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
-    if not HAVE_PALLAS:
-        raise RuntimeError(
-            "impl='pallas' requires jax.experimental.pallas, which this "
-            "jax build lacks; use impl='xla'")
     for name, b in (("block_q", block_q), ("block_k", block_k)):
         if b is not None and b < 1:
             raise ValueError(f"{name} must be >= 1, got {b}")
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except Exception:  # pragma: no cover
-            interpret = True
+        interpret = jax.default_backend() != "tpu"
     pfn = functools.partial(_ring_attention_block_pallas, axis_name=axis,
                             causal=causal, scale=scale, block_q=block_q,
                             block_k=block_k, interpret=interpret)

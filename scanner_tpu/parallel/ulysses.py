@@ -27,9 +27,8 @@ import functools
 from typing import Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ..util.jaxenv import axis_size as _axis_size
-from ..util.jaxenv import shard_map
 
 from .ring_attention import reference_attention
 
@@ -38,7 +37,7 @@ def _ulysses_block(q, k, v, axis_name: str, causal: bool,
                    scale: Optional[float]):
     """Local computation: q,k,v are (B, Tl, H, D) time-blocks of a
     sequence sharded over axis_name."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     H = q.shape[2]
     if H % n:
         raise ValueError(

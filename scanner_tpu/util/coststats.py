@@ -172,10 +172,12 @@ class CostDescriptor:
 # ---------------------------------------------------------------------------
 
 # (device_kind substring, peak dense-bf16 FLOP/s, peak HBM bytes/s) per
-# chip generation — public spec-sheet numbers, matched case-insensitively
-# against jax's device_kind.  The table is a *reference* roofline:
-# EFF% compares kernels against each other and across rounds on the
-# same chip; absolute calibration rides on these constants.
+# chip generation — public spec-sheet numbers (Google Cloud TPU
+# documentation), matched case-insensitively against jax's device_kind.
+# The table is a *reference* roofline: EFF% compares kernels against
+# each other and across rounds on the same chip; absolute calibration
+# rides on these constants.  An accelerator kind that is not in the
+# table is an error at the call that asks for a roofline, not a default.
 DEVICE_PEAKS = (
     ("v6e", 918e12, 1.64e12),
     ("v5p", 459e12, 2.765e12),
@@ -185,9 +187,7 @@ DEVICE_PEAKS = (
     ("v3", 123e12, 9.0e11),
     ("v2", 46e12, 7.0e11),
 )
-# generic accelerator fallback when no generation substring matches
-_GENERIC_TPU_PEAK = (197e12, 8.19e11)
-# host fallback: order-of-magnitude for a few AVX cores — CPU EFF% is
+# host entry: order-of-magnitude for a few AVX cores — CPU EFF% is
 # indicative only (tests pin behavior through set_device_peaks)
 _CPU_PEAK = (2e11, 5e10)
 
@@ -207,46 +207,53 @@ def set_device_peaks(device_label: str, peak_flops: float,
 
 
 def _device_kind(device_label: str) -> str:
-    """jax's device_kind string for a metrics device label ("tpu:3"),
-    or "" when unresolvable (no jax, label "default", drift)."""
-    try:
-        import sys
-        if sys.modules.get("jax") is None:
-            return ""
-        import jax
-        from . import memstats as _ms
-        for d in jax.local_devices():
-            if _ms.device_label(d) == device_label:
-                return str(getattr(d, "device_kind", "") or "")
-        if device_label == "default" and jax.local_devices():
-            return str(getattr(jax.local_devices()[0],
-                               "device_kind", "") or "")
-    except Exception:  # noqa: BLE001 — peaks must never raise
-        pass
+    """jax's device_kind string for a metrics device label ("tpu:3";
+    "default" = the first local device), or "" when the label names no
+    local device (a process that never imported jax, a synthetic label
+    in tests)."""
+    import sys
+    if sys.modules.get("jax") is None:
+        return ""
+    import jax
+    from . import memstats as _ms
+    devs = jax.local_devices()
+    if device_label == "default":
+        return str(devs[0].device_kind)
+    for d in devs:
+        if _ms.device_label(d) == device_label:
+            return str(d.device_kind)
     return ""
+
+
+def peaks_for_kind(kind: str) -> Tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) for a jax ``device_kind`` string: the
+    DEVICE_PEAKS generation match, or the host entry for a CPU kind.
+    Any other kind raises — a roofline against a guessed peak is worse
+    than none."""
+    low = kind.lower()
+    for sub, f, b in DEVICE_PEAKS:
+        if sub in low:
+            return (f, b)
+    if low == "cpu":
+        return _CPU_PEAK
+    raise ValueError(
+        f"no roofline peaks for device kind {kind!r}: add it to "
+        "coststats.DEVICE_PEAKS (with its source) or calibrate with "
+        "set_device_peaks()")
 
 
 def device_peaks(device_label: str) -> Tuple[float, float]:
     """(peak FLOP/s, peak bytes/s) for a device label: explicit
-    override > generation match on jax's device_kind > platform
-    fallback."""
+    override > generation match on jax's device_kind.  A label that
+    names no local device (synthetic labels in tests) judges against
+    the host entry; a local accelerator whose kind is not in
+    DEVICE_PEAKS raises (see peaks_for_kind)."""
     with _peak_lock:
         if device_label in _peak_overrides:
             return _peak_overrides[device_label]
         if device_label in _peak_cache:
             return _peak_cache[device_label]
-    kind = _device_kind(device_label).lower()
-    platform = device_label.split(":", 1)[0]
-    peak = None
-    for sub, f, b in DEVICE_PEAKS:
-        if sub in kind:
-            peak = (f, b)
-            break
-    if peak is None:
-        if "tpu" in (kind or platform):
-            peak = _GENERIC_TPU_PEAK
-        else:
-            peak = _CPU_PEAK
+    peak = peaks_for_kind(_device_kind(device_label) or "cpu")
     with _peak_lock:
         _peak_cache[device_label] = peak
     return peak

@@ -16,9 +16,10 @@ budget.  This module is that judgment layer:
     producer/consumer fps imbalance).
   * A built-in **default ruleset** (``DEFAULT_RULES``) covers stage
     backpressure, worker liveness, per-device saturation and HBM
-    pressure, task-latency SLO burn, and recompile storms; user rules
-    ride in via the ``[alerts] rules`` config clause grammar (see
-    docs/observability.md §Health & SLOs).
+    pressure, and recompile storms; user rules (a latency SLO is one: the
+    engine cannot know a deployment's objective) ride in via the
+    ``[alerts] rules`` config clause grammar (see docs/observability.md
+    §Health & SLOs).
   * **Firing/resolving alerts are first-class**: counted as
     ``scanner_tpu_alerts_firing`` / ``scanner_tpu_alerts_transitions_total``,
     recorded as instants on the tracing flight recorder, served on the
@@ -91,6 +92,13 @@ _BP_TASKS_SERIES = "scanner_tpu_stage_tasks_total"
 _BP_UPSTREAM = {"evaluate": "load", "save": "evaluate"}
 _BP_IMBALANCE = 1.5   # producer fps > 1.5x consumer fps counts as skew
 
+# engine/evaluate.py: evaluator work in flight that may block on XLA
+# (kernel set-up, ladder warm-up, first dispatch of a signature).  The
+# load-signal rules hold quiet on it: a pipeline waiting on a compile
+# has a full evaluate queue and a busy evaluate stage, and neither
+# shedding load nor adding replicas is the answer
+_WARMING_SERIES = "scanner_tpu_evaluator_warming"
+
 
 class HealthConfigError(ScannerException):
     """Malformed [alerts] rule spec."""
@@ -144,8 +152,13 @@ class AlertRule:
     severity: str = "warning"
     # label names each alert instance is keyed by (one alert per group)
     by: Tuple[str, ...] = ()
-    # label filters applied before grouping
+    # label filters applied before grouping; a value spelled "!v"
+    # keeps the samples whose label is NOT v
     match: Dict[str, str] = field(default_factory=dict)
+    # quiet gate: a series that explains the condition away.  While its
+    # samples sum above 0 anywhere inside `window`, the rule evaluates
+    # as not met (a pending hold-down restarts, a firing alert resolves)
+    unless: str = ""
     # value form only: divide by this series' matching group (ratios
     # like hbm_in_use / hbm_limit)
     ratio_to: str = ""
@@ -174,6 +187,10 @@ class AlertRule:
                 f"{', '.join(SEVERITIES)}")
         if not self.series:
             raise HealthConfigError(f"rule {self.name}: needs a series")
+        if self.unless and not _mx.NAME_RE.fullmatch(self.unless):
+            raise HealthConfigError(
+                f"rule {self.name}: unless={self.unless!r} is not a "
+                "series name")
         return self
 
 
@@ -185,11 +202,13 @@ DEFAULT_RULES = (
         name="stage_backpressure", form="backpressure",
         series="scanner_tpu_stage_queue_depth",
         op=">=", value=3.0, window=10.0, for_seconds=1.5,
-        severity="warning", by=("stage",),
+        severity="warning", by=("stage",), unless=_WARMING_SERIES,
         description="a pipeline stage's input queue sits at its high "
                     "watermark (or its producer sustainably outruns it "
                     "with a backlog standing): the stage is the "
-                    "bottleneck and upstream work is piling up"),
+                    "bottleneck and upstream work is piling up (quiet "
+                    "while an evaluator is warming up: the queue then "
+                    "waits on a compile)"),
     AlertRule(
         name="worker_heartbeat_stale",
         series="scanner_tpu_worker_heartbeat_age_seconds",
@@ -198,14 +217,21 @@ DEFAULT_RULES = (
         description="a registered worker has missed several heartbeats "
                     "(master view); past WORKER_STALE_AFTER it will be "
                     "deactivated and its tasks requeued"),
+    # window and hold-down are sized to what the signal is for: one
+    # task holds the evaluate stage for tens of seconds on the chip
+    # (PERF.md "Bring-up on v5e"), and the replica a scale-up adds pays
+    # minutes of cold compile before it helps — so two minutes of
+    # evidence, not one long task
     AlertRule(
         name="device_saturation",
         series="scanner_tpu_device_busy_seconds_total",
-        form="rate", op=">", value=0.9, window=15.0, for_seconds=5.0,
-        severity="warning", by=("device",),
-        description="a chip's evaluate-stage busy fraction is ~1.0 "
-                    "sustained: the device is compute-saturated (the "
-                    "autoscaling up-signal, not by itself a fault)"),
+        form="rate", op=">", value=0.9, window=60.0, for_seconds=60.0,
+        severity="warning", by=("device",), unless=_WARMING_SERIES,
+        description="a chip's evaluate-stage busy share is above 0.9 "
+                    "over a minute, for a minute: the device is "
+                    "compute-saturated (the autoscaling up-signal, not "
+                    "by itself a fault; quiet while an evaluator is "
+                    "warming up)"),
     AlertRule(
         name="hbm_pressure",
         series="scanner_tpu_device_hbm_bytes_in_use",
@@ -216,25 +242,22 @@ DEFAULT_RULES = (
                     "the device limit: the next staging or dispatch is "
                     "likely to RESOURCE_EXHAUSTED (see the memstats "
                     "ledger for who owns the bytes)"),
-    AlertRule(
-        name="task_latency_slo_burn",
-        series="scanner_tpu_task_latency_seconds",
-        form="burn", op=">", value=2.0, objective=30.0, budget=0.05,
-        short_window=60.0, window=300.0, for_seconds=0.0,
-        severity="critical",
-        description="end-to-end task latency is burning its error "
-                    "budget (share of tasks over the objective exceeds "
-                    "burn_rate x budget in BOTH the short and the long "
-                    "window — sustained burn, not a transient spike)"),
+    # reads the compile ledger's count of XLA compiles that really ran
+    # (a persistent-cache hit is not one), per (op, device): churn is
+    # one op re-tracing, a cold start spreads its compiles over ops and
+    # chips.  One ladder is at most ~6 rungs of ~4 compiles (61 compiles
+    # for 15 ladder rungs on the chip, PERF.md); holding above 0.5/s
+    # over 60 s for 60 s takes more than 60 compiles of ONE op on ONE
+    # chip inside two minutes, which no ladder explains
     AlertRule(
         name="recompile_storm",
-        series="scanner_tpu_op_recompiles_total",
-        form="rate", op=">", value=0.5, window=30.0, for_seconds=5.0,
-        severity="warning",
-        description="XLA recompiles are arriving continuously — "
-                    "bucketed dispatch should bound them at one ladder "
-                    "per (op, device); a sustained rate means a ragged "
-                    "call path is re-tracing (PERF.md §5)"),
+        series="scanner_tpu_compile_total", match={"cache": "!hit"},
+        form="rate", op=">", value=0.5, window=60.0, for_seconds=60.0,
+        severity="warning", by=("op", "device"),
+        description="one op keeps compiling on one chip — bucketed "
+                    "dispatch bounds real XLA compiles at one ladder "
+                    "per (op, device), so a rate that holds means a "
+                    "ragged call path is re-tracing (PERF.md §3)"),
 )
 
 
@@ -439,6 +462,8 @@ class HealthEngine:
             need.add(r.series)
             if r.ratio_to:
                 need.add(r.ratio_to)
+            if r.unless:
+                need.add(r.unless)
             if r.form == "backpressure":
                 need.add(_BP_TASKS_SERIES)
         return need
@@ -501,7 +526,8 @@ class HealthEngine:
         n_b = len(entry.get("uppers") or ()) + 1
         for s in entry.get("samples", []):
             lbls = s.get("labels") or {}
-            if any(lbls.get(k) != v for k, v in match.items()):
+            if any(lbls.get(k) == v[1:] if v.startswith("!")
+                   else lbls.get(k) != v for k, v in match.items()):
                 continue
             key = tuple(str(lbls.get(b, "")) for b in by)
             if is_hist:
@@ -518,6 +544,20 @@ class HealthEngine:
     def _series_groups(self, sample, series: str, rule: AlertRule
                        ) -> Dict[Tuple[str, ...], Any]:
         return self._groups(sample[1].get(series), rule.match, rule.by)
+
+    def _held_quiet(self, rule: AlertRule, now: float) -> bool:
+        """True while the rule's `unless` series read above 0 in any
+        sample inside its window: the window then holds time the gate
+        explains, whatever the newest sample says."""
+        with self._lock:
+            for ts, data in reversed(self._samples):
+                if ts < now - rule.window:
+                    break
+                entry = data.get(rule.unless)
+                if entry and sum(float(s.get("value", 0.0))
+                                 for s in entry.get("samples", [])) > 0:
+                    return True
+        return False
 
     # -- rule forms ---------------------------------------------------------
 
@@ -703,6 +743,9 @@ class HealthEngine:
                         vals = {}
                     results = {k: (v, _OPS[rule.op](v, rule.value))
                                for k, v in vals.items()}
+                if rule.unless and self._held_quiet(rule, now):
+                    results = {k: (v, False)
+                               for k, (v, _f) in results.items()}
                 seen = set()
                 for key, (val, fired) in results.items():
                     skey = (rule.name, key)
@@ -824,7 +867,8 @@ class HealthEngine:
             "name": r.name, "form": r.form, "series": r.series,
             "op": r.op, "value": r.value, "window": r.window,
             "for": r.for_seconds, "severity": r.severity,
-            "by": list(r.by), "description": r.description,
+            "by": list(r.by), "unless": r.unless,
+            "description": r.description,
         } for r in self.rules()]
         return out
 

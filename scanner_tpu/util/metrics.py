@@ -33,6 +33,7 @@ Design constraints, in order:
 
 from __future__ import annotations
 
+import contextlib
 import json
 import re
 import threading
@@ -75,9 +76,13 @@ class _CounterChild:
     the lock-free fast path.  Cells of dead threads fold into a retained
     total at read time AND whenever a new cell registers past a size
     threshold, so neither scraped nor unscraped processes leak cells
-    (owners are held by weakref — a dead cell must not pin its Thread)."""
+    (owners are held by weakref — a dead cell must not pin its Thread).
 
-    __slots__ = ("_local", "_cells", "_retained", "_lock")
+    timing() is the busy-seconds flavor: the block's wall seconds accrue
+    AS THEY ELAPSE, so a reader mid-block sees the time so far."""
+
+    __slots__ = ("_local", "_cells", "_retained", "_lock", "_open",
+                 "_open_t0", "_timed")
 
     def __init__(self):
         self._local = threading.local()
@@ -85,6 +90,11 @@ class _CounterChild:
         self._cells: List[Tuple[Any, List[float]]] = []
         self._retained = 0.0
         self._lock = threading.Lock()
+        # timing(): blocks currently open, when the first of them
+        # opened, and the seconds of the intervals already closed
+        self._open = 0
+        self._open_t0 = 0.0
+        self._timed = 0.0
 
     def _fold_locked(self) -> None:
         live = []
@@ -109,10 +119,32 @@ class _CounterChild:
             self._local.cell = cell
             cell[0] += n
 
+    @contextlib.contextmanager
+    def timing(self):
+        """Count the block's wall seconds as they elapse.  inc(dt) at the
+        end of a long block lands all of its seconds in one sample, and
+        a rate over a shorter window then reads several seconds per
+        second; through timing() the rate over ANY window is a true
+        busy share.  Overlapping blocks count once (the union of their
+        intervals), so the share never exceeds 1."""
+        with self._lock:
+            if self._open == 0:
+                self._open_t0 = time.monotonic()
+            self._open += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._open -= 1
+                if self._open == 0:
+                    self._timed += time.monotonic() - self._open_t0
+
     def value(self) -> float:
         with self._lock:
             self._fold_locked()
-            return self._retained + sum(c[0] for _o, c in self._cells)
+            live = time.monotonic() - self._open_t0 if self._open else 0.0
+            return self._retained + self._timed + live \
+                + sum(c[0] for _o, c in self._cells)
 
 
 class _GaugeChild:
@@ -398,7 +430,7 @@ def labeled_samples(snapshot: Dict[str, dict], series: str
                     ) -> Dict[str, float]:
     """Flatten one series of a snapshot to {sorted-label-json: value}.
     The stable keying the per-device utilization digests compare across
-    runs and processes (bench.py `multichip`, tools/tpu_window.py, the
+    runs and processes (bench.py `multichip`, the
     tests/test_multichip.py equivalence suite): label order never leaks
     into the key, so `{"device": "tpu:3", "op": "Histogram"}` is the
     same sample wherever it was produced."""

@@ -162,12 +162,17 @@ def load_video_meta(db: Database, table, column: str = "frame",
 
 
 def open_automata(db: Database, table, column: str = "frame",
-                  n_threads: int = 1) -> DecoderAutomata:
+                  n_threads: int = 1,
+                  output_format: str = "rgb24") -> DecoderAutomata:
+    """A decoder over a stored video column.  output_format="yuv420"
+    yields the flat I420 rows the engine ships to an accelerator
+    (kernels/color.py converts them there)."""
     desc = db.table_descriptor(table)
     vd = load_video_meta(db, table, column)
     return DecoderAutomata(db.backend, vd,
                            md.column_item_path(desc.id, column, 0),
-                           n_threads=n_threads)
+                           n_threads=n_threads,
+                           output_format=output_format)
 
 
 def load_frames(db: Database, table, rows: Sequence[int],

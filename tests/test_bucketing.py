@@ -288,7 +288,7 @@ def test_shape_churn_guard_golden_pipeline(sc, monkeypatch):
 
 
 def test_shape_churn_guard_fused_chains(sc, monkeypatch):
-    """Fusion extension of the shape-churn guard (PERF.md §5 sweep, §8):
+    """Fusion extension of the shape-churn guard (PERF.md §3):
     on the golden fusable pipeline under the same ragged-tail +
     null-interleaved geometry sweep, (a) the fused chain's distinct
     input-signature count stays within ITS bucket ladder — chains obey
@@ -477,6 +477,51 @@ def test_precompile_skips_geometry_changed_inputs(sc, monkeypatch):
             te._precompile_thread.join(timeout=60)
     finally:
         te.close()
+
+
+def test_precompile_of_stream_bound_kernel_completes(sc, monkeypatch,
+                                                     caplog):
+    """A staged Resize gets its output size from new_stream: the ladder
+    warm-up must run on a kernel already bound to job 0's stream args
+    (it raised ZeroDivisionError on a 0x0 size from PR 2 until the v5e
+    bring-up, logged at debug).  The whole ladder compiles, no
+    'precompile of Resize ... failed' warning is logged, the precompile
+    gauge is set, and the warming gauge is back at its resting value."""
+    import logging
+
+    from scanner_tpu.engine.evaluate import TaskEvaluator
+    from scanner_tpu.graph import analysis as A
+    from scanner_tpu.graph import fusion
+    from scanner_tpu.util.metrics import registry
+    from scanner_tpu.util.profiler import Profiler
+
+    def warming():
+        entry = registry().snapshot()["scanner_tpu_evaluator_warming"]
+        return sum(smp["value"] for smp in entry["samples"])
+
+    monkeypatch.setenv("SCANNER_TPU_PRECOMPILE", "1")
+    monkeypatch.delenv("SCANNER_TPU_BUCKETED", raising=False)
+    frame = sc.io.Input([NamedVideoStream(sc, "bk")])
+    small = sc.ops.Resize(frame=frame, width=[32], height=[24])
+    outp = sc.io.Output(small, [NamedStream(sc, "warm_resize")])
+    info = A.analyze([outp])
+    rest = warming()
+    prev = fusion.enabled()
+    fusion.set_enabled(False)
+    try:
+        with caplog.at_level(logging.WARNING, logger="scanner_tpu"):
+            te = TaskEvaluator(info, Profiler(), precompile=(H, W, 8))
+            try:
+                assert te._precompile_thread is not None
+                te._precompile_thread.join(timeout=120)
+                assert not te._precompile_thread.is_alive()
+            finally:
+                te.close()
+    finally:
+        fusion.set_enabled(prev)
+    assert "precompile of" not in caplog.text, caplog.text
+    assert "Resize" in _op_counter("scanner_tpu_op_precompile_seconds")
+    assert warming() == rest
 
 
 def test_precompile_claim_beats_warmup(sc, monkeypatch):

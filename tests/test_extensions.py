@@ -250,11 +250,15 @@ def test_deploy_metrics_port_wiring():
     assert "ports" not in wc
 
 
-def test_deploy_compilation_cache_wiring(tmp_path, monkeypatch):
-    """ClusterConfig.compilation_cache_dir threads JAX's persistent
-    compilation cache through the manifests (ConfigMap [perf] section +
-    worker env var) and stays fully absent at the default; the config
-    knob and jaxenv helper resolve the same setting process-side."""
+def test_compilation_cache_rule(tmp_path, monkeypatch):
+    """One rule for where the persistent compilation cache lives
+    (util/jaxenv.enable_compilation_cache): JAX_COMPILATION_CACHE_DIR
+    set -> the code sets no directory; unset -> the fixed in-checkout
+    path.  The three scanner-specific spellings are gone, and
+    ClusterConfig.compilation_cache_dir reaches workers as the JAX
+    variable."""
+    import inspect
+
     from scanner_tpu.deploy import (CloudConfig, Cluster, ClusterConfig,
                                     MachineType)
 
@@ -266,43 +270,66 @@ def test_deploy_compilation_cache_wiring(tmp_path, monkeypatch):
                 for m in Cluster(CloudConfig(project="p"), cfg).manifests()}
 
     on = manifests("gs://bkt/xla-cache")
-    toml = on[("ConfigMap", "sc-config")]["data"]["scanner_tpu.toml"]
-    assert "[perf]" in toml
-    assert 'compilation_cache_dir = "gs://bkt/xla-cache"' in toml
+    assert "compilation_cache_dir" not in on[("ConfigMap", "sc-config")][
+        "data"]["scanner_tpu.toml"]
     wc = on[("StatefulSet", "sc-worker")]["spec"]["template"]["spec"][
         "containers"][0]
-    assert {"name": "SCANNER_TPU_COMPILATION_CACHE",
+    assert {"name": "JAX_COMPILATION_CACHE_DIR",
             "value": "gs://bkt/xla-cache"} in wc["env"]
+    # no directory named: still placed from outside, on pod scratch —
+    # the image's installed package has no writable checkout
+    from scanner_tpu.deploy import POD_CACHE_DIR
+    wc = manifests("")[("StatefulSet", "sc-worker")]["spec"]["template"][
+        "spec"]["containers"][0]
+    assert {"name": "JAX_COMPILATION_CACHE_DIR",
+            "value": POD_CACHE_DIR} in wc["env"]
 
-    off = manifests("")
-    assert "[perf]" not in off[("ConfigMap", "sc-config")]["data"][
-        "scanner_tpu.toml"]
-    wc = off[("StatefulSet", "sc-worker")]["spec"]["template"]["spec"][
-        "containers"][0]
-    assert not any(e.get("name") == "SCANNER_TPU_COMPILATION_CACHE"
-                   for e in wc["env"])
+    # no config key, no Client/Worker argument
+    from scanner_tpu import Client
+    from scanner_tpu.config import Config, default_config
+    from scanner_tpu.engine.service import Worker
+    assert "compilation_cache_dir" not in default_config()["perf"]
+    assert not hasattr(Config, "compilation_cache_dir")
+    for cls in (Client, Worker):
+        assert "compilation_cache_dir" not in inspect.signature(
+            cls.__init__).parameters
 
-    # config knob -> Config property
-    from scanner_tpu.config import Config, dump_toml
-    p = tmp_path / "cfg.toml"
-    p.write_text(dump_toml(
-        {"perf": {"compilation_cache_dir": str(tmp_path / "cc")}}))
-    assert Config(str(p)).compilation_cache_dir == str(tmp_path / "cc")
-    p.write_text(dump_toml({"perf": {"compilation_cache_dir": ""}}))
-    assert Config(str(p)).compilation_cache_dir is None
-
-    # jaxenv helper: env-var fallback, creates the dir, points jax at it
     import jax
 
-    from scanner_tpu.util.jaxenv import enable_compilation_cache
-    monkeypatch.delenv("SCANNER_TPU_COMPILATION_CACHE", raising=False)
-    assert enable_compilation_cache(None) is None  # unset = no-op
-    cache = tmp_path / "xla"
-    monkeypatch.setenv("SCANNER_TPU_COMPILATION_CACHE", str(cache))
-    assert enable_compilation_cache(None) == str(cache)
-    assert cache.is_dir()
-    assert jax.config.jax_compilation_cache_dir == str(cache)
-    jax.config.update("jax_compilation_cache_dir", None)  # detach again
+    from scanner_tpu.util import jaxenv
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # placed from outside: the code sets NO directory
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        assert jaxenv.enable_compilation_cache() == outside
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not os.path.exists(outside)
+        # small ladder executables are kept either way
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+        # unset: the fixed in-checkout path, the same on every call
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = jaxenv.enable_compilation_cache()
+        assert fixed == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert jaxenv.enable_compilation_cache() == fixed
+        # not a checkout (an installed package under a read-only
+        # root): the default cannot be created and the error names the
+        # variable to set; with it set the same layout starts
+        blocker = tmp_path / "site-packages-parent"
+        blocker.write_text("not a directory")
+        monkeypatch.setattr(jaxenv, "_DEFAULT_CACHE_DIR",
+                            str(blocker / ".jax_cache"))
+        with pytest.raises(RuntimeError,
+                           match="JAX_COMPILATION_CACHE_DIR"):
+            jaxenv.enable_compilation_cache()
+        assert jax.config.jax_compilation_cache_dir == fixed
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        assert jaxenv.enable_compilation_cache() == outside
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_deploy_gcloud_commands():

@@ -255,6 +255,119 @@ def test_rate_rule_windowed():
     assert [t["state"] for t in trans] == ["resolved"]
 
 
+def test_unless_gate_holds_rule_quiet_inside_window():
+    """A rule with `unless` evaluates as not met while the gate series
+    read above 0 anywhere inside its window: a pending hold-down
+    restarts, a firing alert resolves, and the rule comes back once
+    the window holds no gated time."""
+    reg = MetricsRegistry()
+    depth = reg.gauge("scanner_tpu_t_qdepth", "x")
+    warming = reg.gauge("scanner_tpu_t_warming", "x")
+    rule = health.AlertRule(
+        name="t_gated", series="scanner_tpu_t_qdepth", form="value",
+        op=">=", value=3, window=10.0, for_seconds=2.0,
+        unless="scanner_tpu_t_warming").validate()
+    eng = health.HealthEngine(reg=reg, rules=[rule], interval=0.1)
+    depth.set(5)
+    warming.set(1)
+    for t in range(100, 140):          # 40 s of backlog behind a compile
+        assert eng.tick(float(t)) == []
+    warming.set(0)
+    for t in range(140, 151):          # gate still inside the window
+        assert eng.tick(float(t)) == []
+    assert eng.tick(151.0) == []       # pending starts only now
+    trans = eng.tick(153.5)
+    assert [t["state"] for t in trans] == ["firing"]
+    warming.set(2)                     # a new graph starts compiling
+    trans = eng.tick(154.0)
+    assert [t["state"] for t in trans] == ["resolved"]
+    with pytest.raises(health.HealthConfigError):
+        health.AlertRule(name="t_bad", series="scanner_tpu_t_qdepth",
+                         unless="not a series").validate()
+
+
+def test_negated_label_match():
+    """`{cache=!hit}` keeps every sample whose label is not `hit`."""
+    reg = MetricsRegistry()
+    c = reg.counter("scanner_tpu_t_compile_total", "x",
+                    labels=["op", "cache"])
+    rule = health.parse_rules(
+        "t_miss:rate(scanner_tpu_t_compile_total{cache=!hit})>0.5"
+        ":window=10:by=op")[0]
+    assert rule.match == {"cache": "!hit"}
+    eng = health.HealthEngine(reg=reg, rules=[rule], interval=0.1)
+    for cache in ("hit", "miss", "uncached"):
+        c.labels(op="A", cache=cache).inc(0)
+    c.labels(op="B", cache="hit").inc(0)
+    assert eng.tick(100.0) == []
+    c.labels(op="A", cache="hit").inc(100)     # 10/s of cache hits
+    c.labels(op="B", cache="hit").inc(100)
+    assert eng.tick(110.0) == []
+    c.labels(op="A", cache="miss").inc(4)      # 0.8/s really compiled
+    c.labels(op="A", cache="uncached").inc(4)
+    trans = eng.tick(120.0)
+    assert [(t["rule"], t["labels"]) for t in trans] == \
+        [("t_miss", {"op": "A"})]
+
+
+def test_default_rules_quiet_through_a_cold_start_on_the_chip():
+    """The timeline the v5e bring-up measured (PERF.md): the first run
+    of a graph holds the evaluate stage for ~80 s of compile with the
+    loader's tasks queued behind it, on every chip at once, each op's
+    ladder compiling ~24 executables; then short warm jobs whose fresh
+    evaluators meet only cache hits.  No default rule may fire on
+    that; sustained saturation afterwards still does."""
+    reg = MetricsRegistry()
+    busy = reg.counter("scanner_tpu_device_busy_seconds_total", "x",
+                       labels=["device"])
+    depth = reg.gauge("scanner_tpu_stage_queue_depth", "x",
+                      labels=["stage"])
+    warming = reg.gauge("scanner_tpu_evaluator_warming", "x")
+    compiles = reg.counter("scanner_tpu_compile_total", "x",
+                           labels=["op", "device", "cache"])
+    devs = [f"tpu:{i}" for i in range(4)]
+    eng = health.HealthEngine(reg=reg, rules=health.default_rules(),
+                              interval=1.0)
+    t = 1000.0
+    fired = []
+
+    def run(seconds, *, warm, busy_share, qdepth, per_tick=None):
+        nonlocal t
+        warming.set(warm)
+        depth.labels(stage="evaluate").set(qdepth)
+        for _ in range(seconds):
+            t += 1.0
+            for d in devs:
+                busy.labels(device=d).inc(busy_share)
+            if per_tick is not None:
+                per_tick()
+            fired.extend(x for x in eng.tick(t)
+                         if x["state"] == "firing")
+
+    ticks = [0]
+
+    def cold_ladders():                # 24 compiles per op per chip
+        ticks[0] += 1                  # spread over the 80 s
+        if ticks[0] % 10 == 0:
+            for d in devs:
+                compiles.labels(op="Histogram", device=d,
+                                cache="miss").inc(3)
+
+    def warm_hits():                   # a fresh evaluator every 2 s,
+        for d in devs:                 # every rung a cache hit
+            compiles.labels(op="Histogram", device=d,
+                            cache="hit").inc(6)
+
+    run(80, warm=4, busy_share=1.0, qdepth=3, per_tick=cold_ladders)
+    run(30, warm=0, busy_share=1.0, qdepth=1)      # the job itself
+    run(20, warm=0, busy_share=0.0, qdepth=0)
+    run(30, warm=0, busy_share=0.9, qdepth=0, per_tick=warm_hits)
+    assert fired == [], fired
+    run(200, warm=0, busy_share=1.0, qdepth=0)     # minutes of load
+    assert {(x["rule"], x["labels"]["device"]) for x in fired} == \
+        {("device_saturation", d) for d in devs}
+
+
 def test_quantile_rule_over_window():
     reg = MetricsRegistry()
     h = reg.histogram("scanner_tpu_t_rpc_seconds", "x",
@@ -743,13 +856,13 @@ def test_job_status_and_statusz_carry_health(health_cluster):
 
 
 def test_bench_history_trajectory_and_regression(tmp_path):
-    """The checked-in BENCH_r01..r05 trajectory prints and exits 0; a
+    """The checked-in BENCH_r01..r02 trajectory prints and exits 0; a
     synthetic same-source regression exits 1."""
     tool = os.path.join(REPO, "tools", "bench_history.py")
     r = subprocess.run([sys.executable, tool, "--dir", REPO],
                        capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "5 rounds" in r.stdout
+    assert "2 rounds" in r.stdout
     assert "histogram" in r.stdout
 
     def write_round(n, value, source=None):
@@ -768,8 +881,8 @@ def test_bench_history_trajectory_and_regression(tmp_path):
     assert r.returncode == 1
     assert "REGRESSIONS" in r.stdout
 
-    # a capture-source change resets the baseline: no regression
-    write_round(3, 20.0, source="opportunistic_capture")
+    # a source change resets the baseline: no regression
+    write_round(3, 20.0, source="other_machine")
     r = subprocess.run([sys.executable, tool, "--dir", str(tmp_path)],
                        capture_output=True, text=True, timeout=60)
     assert r.returncode == 0, r.stdout

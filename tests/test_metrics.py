@@ -53,6 +53,74 @@ def test_counter_concurrency():
     assert child.value() == 2 * n_threads * per_thread
 
 
+def test_counter_timing_accrues_as_it_elapses():
+    """timing() makes a busy-seconds counter a true share: a reader
+    mid-block sees the seconds so far, overlapping blocks count once,
+    and the total never exceeds the wall time that passed."""
+    import time
+    r = MetricsRegistry()
+    child = r.counter("scanner_tpu_t_busy_seconds_total", "t",
+                      labels=["device"]).labels(device="tpu:0")
+    t0 = time.monotonic()
+    inner_done = threading.Event()
+
+    def overlap():
+        with child.timing():
+            time.sleep(0.1)
+        inner_done.set()
+
+    with child.timing():
+        th = threading.Thread(target=overlap)
+        th.start()
+        time.sleep(0.05)
+        mid = child.value()
+        assert 0.04 <= mid <= time.monotonic() - t0
+        th.join()
+    end = child.value()
+    wall = time.monotonic() - t0
+    assert inner_done.is_set()
+    assert mid < end <= wall           # the union, not the 0.2 s sum
+    time.sleep(0.02)
+    assert child.value() == end        # closed: no longer accruing
+    child.inc(1.5)                     # inc() still adds on top
+    assert child.value() == pytest.approx(end + 1.5)
+
+
+def test_counter_timing_concurrency():
+    """More threads than cores opening and closing timing() blocks on
+    one child under a shortened switch interval: no open is lost (the
+    child ends closed and stops accruing) and the union never exceeds
+    the wall time that passed."""
+    import time
+    r = MetricsRegistry()
+    child = r.counter("scanner_tpu_t_busy2_seconds_total", "t")._default
+    n_threads, per_thread = 4 * (os.cpu_count() or 4), 300
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    t0 = time.monotonic()
+    try:
+        def work():
+            for _ in range(per_thread):
+                with child.timing():
+                    pass
+
+        threads = [threading.Thread(target=work)
+                   for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    wall = time.monotonic() - t0
+    end = child.value()
+    assert child._open == 0
+    assert 0 < end <= wall
+    time.sleep(0.02)
+    assert child.value() == end
+
+
 def test_histogram_bucket_edges():
     """Prometheus buckets are upper-INCLUSIVE: v == le lands in that
     bucket; above the last upper lands in +Inf."""

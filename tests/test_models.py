@@ -344,11 +344,10 @@ def test_attention_scheme_selection():
     TWO steps from the same seed — the second step's loss depends on the
     first step's gradients, so this pins the backward pass too (incl.
     the pallas custom_vjp)."""
-    from scanner_tpu.kernels.pallas_attention import HAVE_PALLAS
     from scanner_tpu.models import make_sharded_train_step
     from scanner_tpu.parallel import auto_axes, make_mesh
 
-    schemes = ["ring", "ulysses"] + (["pallas"] if HAVE_PALLAS else [])
+    schemes = ["ring", "ulysses", "pallas"]
     losses = {}
     for scheme in schemes:
         mesh = make_mesh(auto_axes(8))

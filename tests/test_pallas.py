@@ -2,12 +2,10 @@
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from scanner_tpu.kernels import pallas_ops
 
 
-@pytest.mark.skipif(not pallas_ops.HAVE_PALLAS, reason="no pallas")
 def test_pallas_histogram_matches_numpy():
     rng = np.random.RandomState(0)
     vals = rng.randint(0, 16, (5, 1000)).astype(np.int32)
@@ -17,7 +15,6 @@ def test_pallas_histogram_matches_numpy():
     np.testing.assert_array_equal(got, expect)
 
 
-@pytest.mark.skipif(not pallas_ops.HAVE_PALLAS, reason="no pallas")
 def test_pallas_histogram_frames_matches_xla():
     from scanner_tpu.kernels.imgproc import _histogram_impl
     rng = np.random.RandomState(1)
@@ -27,7 +24,6 @@ def test_pallas_histogram_frames_matches_xla():
     np.testing.assert_array_equal(got, expect)
 
 
-@pytest.mark.skipif(not pallas_ops.HAVE_PALLAS, reason="no pallas")
 def test_pallas_histogram_padding_exact():
     # rows/pixels not multiples of the tile sizes; padding must not leak
     vals = jnp.asarray(np.full((3, 7), 2, np.int32))

@@ -1,6 +1,6 @@
 """YUV420 wire format: decode at 1.5 B/px, convert to RGB on the device.
 
-The h2d halving of PERF.md §1 (reference analog: NV12 shipped to the GPU
+The h2d halving of PERF.md §5 (reference analog: NV12 shipped to the GPU
 and converted by scanner/util/image.cu:22).  Pinned here:
   - device and host converters are bit-identical (integer fixed point)
   - YUV-decoded + converted frames agree with the swscale RGB24 decode
