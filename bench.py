@@ -294,8 +294,8 @@ def main():
             "affinity": os.environ.get(
                 "SCANNER_TPU_DEVICE_AFFINITY", "1") not in ("0", "false"),
             "device_tasks": per_labels("scanner_tpu_device_tasks_total"),
-            "device_busy_seconds":
-                per_labels("scanner_tpu_device_busy_seconds_total"),
+            "evaluate_open_seconds":
+                per_labels("scanner_tpu_evaluate_open_seconds_total"),
             "recompiles_by_device":
                 per_labels("scanner_tpu_op_recompiles_total"),
         })
@@ -660,11 +660,12 @@ def main():
         # whole-pipeline fusion digest (graph/fusion.py, PERF.md §3):
         # the golden Resize->Blur->Histogram->HistDiff pipeline run
         # staged (SCANNER_TPU_FUSION semantics, fusion.set_enabled off)
-        # then fused over the same clip.  Banked: the per-mode measured
-        # op seconds (sum over members vs the one chain row), the
-        # executables each mode minted, the intermediate HBM bytes the
-        # fused program never materialized, and the direction-gated
-        # fused_chain_speedup = staged op-seconds / fused chain-seconds
+        # then fused over the same clip.  Banked: the per-mode wall
+        # seconds of the run, the executables each mode minted, the
+        # intermediate HBM bytes the fused program never materialized,
+        # and the direction-gated fused_chain_speedup = staged wall
+        # seconds / fused wall seconds of the warm passes (host seconds
+        # around the ops' asynchronous calls are no longer counted)
         def _fusion_digest() -> dict:
             from scanner_tpu.graph import fusion as _fusion
 
@@ -694,7 +695,6 @@ def main():
                 prev = _fusion.enabled()
                 _fusion.set_enabled(on)
                 try:
-                    s0 = _by_op("scanner_tpu_op_seconds_total")
                     r0 = _by_op("scanner_tpu_op_recompiles_total")
                     col = fc5.io.Input(
                         [NamedVideoStream(fc5, "fz_vid")])
@@ -712,15 +712,11 @@ def main():
                             show_progress=False)
                     wall = time.time() - w0
                     rows = len(list(out.load()))
-                    s1 = _by_op("scanner_tpu_op_seconds_total")
                     r1 = _by_op("scanner_tpu_op_recompiles_total")
                     return {
                         "mode": mode,
                         "rows_ok": rows == n_rows,
                         "wall_s": round(wall, 3),
-                        "op_seconds": round(
-                            sum(s1.get(k, 0.0) - s0.get(k, 0.0)
-                                for k in keys), 4),
                         "executables_minted": int(
                             sum(r1.get(k, 0) - r0.get(k, 0)
                                 for k in keys)),
@@ -737,9 +733,9 @@ def main():
                 staged_w = _run_mode("staged_warm", on=False)
                 fused_w = _run_mode("fused_warm", on=True)
                 speedup = None
-                if staged_w["op_seconds"] and fused_w["op_seconds"]:
-                    speedup = round(staged_w["op_seconds"]
-                                    / fused_w["op_seconds"], 3)
+                if staged_w["wall_s"] and fused_w["wall_s"]:
+                    speedup = round(staged_w["wall_s"]
+                                    / fused_w["wall_s"], 3)
                 snap_f = registry().snapshot()
                 saved = sum(
                     s["value"] for s in snap_f.get(

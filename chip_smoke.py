@@ -502,7 +502,7 @@ def smoke(device):
                 fired[smp["labels"]["rule"]] = int(smp["value"])
         say(f"health alerts fired (rule: times): {fired}")
         say("evaluate-stage busy seconds by device: "
-            f"{metric_by(snap, 'scanner_tpu_device_busy_seconds_total', 'device')}"
+            f"{metric_by(snap, 'scanner_tpu_evaluate_open_seconds_total', 'device')}"
             f"; stage seconds: "
             f"{metric_by(snap, 'scanner_tpu_stage_seconds_total', 'stage')}"
             f"; evaluators warming now: "

@@ -33,17 +33,14 @@ from ..util import metrics as _mx
 
 Elem = Any
 
-# host<->device traffic as a live pair of counters (rate = delta bytes
-# / delta seconds).  h2d
-# seconds cover the device_put call (dispatch + synchronous copy part;
-# the async completion rides under later compute by design), d2h
-# seconds are the full blocking fetch.
+# host<->device traffic as live counters.  h2d has bytes only: the
+# device_put call returns at dispatch and the copy completes under
+# later compute by design, so host seconds around it time an enqueue
+# (the loader's `load:stage` span shows what the dispatch costs the
+# host); d2h seconds are the full blocking fetch.
 _M_H2D_BYTES = _mx.registry().counter(
     "scanner_tpu_h2d_bytes_total",
     "Bytes staged host->device via ColumnBatch.to_device.")
-_M_H2D_SECONDS = _mx.registry().counter(
-    "scanner_tpu_h2d_seconds_total",
-    "Seconds spent in host->device staging calls (dispatch side).")
 _M_D2H_BYTES = _mx.registry().counter(
     "scanner_tpu_d2h_bytes_total",
     "Bytes fetched device->host via ColumnBatch.to_host.")
@@ -56,14 +53,13 @@ def staged_device_put(host: "np.ndarray", device, kind: str,
                       fault_detail: str):
     """The ONE engine host->device staging contract: the
     memory.pressure fault site, RESOURCE_EXHAUSTED forensics
-    (site=staging), the shared h2d byte/second meters, and an
+    (site=staging), the shared h2d byte meter, and an
     allocation-ledger registration under `kind`.  Used by to_device
     AND the frame cache's fresh-row staging (engine/framecache.py), so
     the chaos/forensics/metering behavior of the two paths can never
     drift — and a cache-on/off A/B of `scanner_tpu_h2d_bytes_total`
     bills the same meter on both sides."""
     import jax
-    t0 = time.time()
     lbl = _ms.device_label(device)
     try:
         if _faults.ACTIVE:
@@ -74,7 +70,6 @@ def staged_device_put(host: "np.ndarray", device, kind: str,
             _ms.note_oom(e, site="staging",
                          detail=f"h2d {host.nbytes} bytes -> {lbl}")
         raise
-    _M_H2D_SECONDS.inc(time.time() - t0)
     _M_H2D_BYTES.inc(host.nbytes)
     _ms.track_array(data, kind,
                     device=lbl if device is not None else None)

@@ -504,18 +504,25 @@ class Client:
         # one device-affine instance per local chip on multi-chip hosts
         # (engine/evaluate.py default_pipeline_instances)
         from .evaluate import default_pipeline_instances
-        ex = LocalExecutor(
-            self._db, prof,
-            num_load_workers=self._executor.num_load_workers,
-            num_save_workers=self._executor.num_save_workers,
-            decoder_threads=self._executor.decoder_threads,
-            pipeline_instances=kw.get(
-                "pipeline_instances",
-                default_pipeline_instances(
-                    perf.pipeline_instances_per_node
-                    or self._pipeline_instances_arg)))
-        ex.run(outputs, perf, cache_mode=cache_mode,
-               show_progress=show_progress)
+        # the root of the job's profile: every second between here and
+        # the return lies in one of its children (docs/profiling.md)
+        with prof.span("run", level=0, job=job_id) as root:
+            ex = LocalExecutor(
+                self._db, prof,
+                num_load_workers=self._executor.num_load_workers,
+                num_save_workers=self._executor.num_save_workers,
+                decoder_threads=self._executor.decoder_threads,
+                pipeline_instances=kw.get(
+                    "pipeline_instances",
+                    default_pipeline_instances(
+                        perf.pipeline_instances_per_node
+                        or self._pipeline_instances_arg)))
+            jobs = ex.run(outputs, perf, cache_mode=cache_mode,
+                          show_progress=show_progress)
+            ran = [j for j in jobs if not j.skipped]
+            root.args.update(
+                tasks=sum(len(j.tasks) for j in ran),
+                rows=sum(e - s for j in ran for s, e in j.tasks))
         self._job_profiles[job_id] = [prof]
         self._job_traces[job_id] = {"trace_id": ex.last_trace_id,
                                     "bulk_id": None}

@@ -53,14 +53,11 @@ from .batch import ColumnBatch, concat_batches, is_array_data
 
 _log = get_logger("evaluate")
 
-# per-op live throughput: fps = delta rows / delta seconds per op label
+# per-op live row counts (host seconds around the calls would time
+# asynchronous enqueues; the `evaluate:<op>` spans show the host side)
 _M_OP_ROWS = _mx.registry().counter(
     "scanner_tpu_op_rows_total",
     "Rows evaluated per op (kernel calls, warmup rows included).",
-    labels=["op"])
-_M_OP_SECONDS = _mx.registry().counter(
-    "scanner_tpu_op_seconds_total",
-    "Wall seconds spent inside each op's kernel calls.",
     labels=["op"])
 _M_OP_RECOMPILES = _mx.registry().counter(
     "scanner_tpu_op_recompiles_total",
@@ -560,15 +557,31 @@ def _build_chain_program(nodes: List[O.OpNode],
         k.reset()
         kernels.append(k)
 
+    chain_id = "+".join(n.name for n in nodes)
+
     def chain_fn(y):
-        for k, win in zip(kernels, windows):
+        return _trace_chain(
+            chain_id, [(n.name, k, win)
+                       for n, k, win in zip(nodes, kernels, windows)], y)
+
+    return jax.jit(chain_fn)
+
+
+def _trace_chain(chain_id: str, members, y):
+    """The body of a fused chain's program: `members` are (op name,
+    kernel, window length) head to tail.  Each member is traced under
+    the chain's named scope and its own, so the device trace's ops
+    carry the program's op names in their `op_name` metadata (trace
+    time only)."""
+    import jax
+    with jax.named_scope(chain_id):
+        for name, k, win in members:
             if win:
                 y = y.reshape((y.shape[0] // win, win)
                               + tuple(y.shape[1:]))
-            y = k.execute_traced(y)
-        return y
-
-    return jax.jit(chain_fn)
+            with jax.named_scope(name):
+                y = k.execute_traced(y)
+    return y
 
 
 class FusedKernelInstance:
@@ -630,12 +643,10 @@ class FusedKernelInstance:
         gather (compose_positions) laid positions out with the HEAD's
         window innermost, so the progressive reshape walks the nesting
         exactly."""
-        for ki, win in zip(self.members, self.windows):
-            if win:
-                y = y.reshape((y.shape[0] // win, win)
-                              + tuple(y.shape[1:]))
-            y = ki.kernel.execute_traced(y)
-        return y
+        return _trace_chain(
+            self.chain_id, [(ki.node.name, ki.kernel, win)
+                            for ki, win in zip(self.members, self.windows)],
+            y)
 
     def _fn(self):
         if self._jit is not None:
@@ -1329,7 +1340,6 @@ class TaskEvaluator:
         track_cost = _cs.enabled() and batched_call \
             and n.effective_device() == DeviceType.TPU
         run_secs = run_flops = run_bytes = 0.0
-        t0 = time.time()
         try:
             with self.profiler.span("evaluate:" + n.name,
                                     rows=len(compute)):
@@ -1476,7 +1486,6 @@ class TaskEvaluator:
                              detail=f"op {n.name} on {ki.dev_label}")
             raise
         _M_OP_ROWS.labels(op=n.name).inc(len(compute))
-        _M_OP_SECONDS.labels(op=n.name).inc(time.time() - t0)
 
         # assemble output columns in row order; null-propagated rows (rare)
         # interleave with kernel results, so columns containing them fall
@@ -1634,7 +1643,6 @@ class TaskEvaluator:
         # chains are always batched TPU dispatch by construction
         track_cost = _cs.enabled()
         run_secs = run_flops = run_bytes = 0.0
-        t0 = time.time()
         try:
             with self.profiler.span("evaluate:" + fki.chain_id,
                                     rows=len(compute)):
@@ -1726,7 +1734,6 @@ class TaskEvaluator:
                                     f"{fki.dev_label}")
             raise
         _M_OP_ROWS.labels(op=fki.chain_id).inc(len(compute))
-        _M_OP_SECONDS.labels(op=fki.chain_id).inc(time.time() - t0)
 
         # assembly: identical to _run_kernel (nulls LAST so they win)
         null_set = set(null_out_rows)

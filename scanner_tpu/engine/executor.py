@@ -74,7 +74,7 @@ _M_DECODE_SECONDS = _mx.registry().counter(
     "Seconds spent decoding video frames, per loader thread.",
     labels=["loader"])
 # per-chip utilization under evaluator affinity: every chip of a
-# multi-device host should take tasks and accumulate busy seconds; a
+# multi-device host should take tasks and accumulate open seconds; a
 # chip stuck at zero while siblings climb = an instance wedged or an
 # assignment bug ("default" = affinity off / single device)
 _M_DEV_TASKS = _mx.registry().counter(
@@ -82,14 +82,44 @@ _M_DEV_TASKS = _mx.registry().counter(
     "Tasks evaluated per assigned device (pipeline-instance affinity: "
     "instance i stages and runs on chip i mod n_devices).",
     labels=["device"])
-_M_DEV_BUSY = _mx.registry().counter(
-    "scanner_tpu_device_busy_seconds_total",
+_M_EVAL_OPEN = _mx.registry().counter(
+    "scanner_tpu_evaluate_open_seconds_total",
     "Evaluate-stage wall seconds per assigned device, accrued while a "
     "task runs (overlapping tasks on one device count once): the rate "
     "over any window is the share of it an evaluate task was open on "
-    "the chip, never more than 1.  Compile and chunk-wait time inside "
-    "a task count as busy.",
+    "the chip, never more than 1.  Host time, not the device's: compile "
+    "and chunk-wait time inside a task count.",
     labels=["device"])
+# the run lifecycle on the client thread and the waits of the stage
+# threads, each recorded with the profiler span of the same name at the
+# same two clock reads (docs/profiling.md has the span tree)
+_M_RUNS = _mx.registry().counter(
+    "scanner_tpu_runs_total",
+    "Local runs (LocalExecutor.run) begun in this process.")
+_M_RUN_SECONDS = _mx.registry().counter(
+    "scanner_tpu_run_seconds_total",
+    "Client-thread seconds of local runs by phase: prepare (graph "
+    "analysis, output tables, task list), pipeline (stage threads' "
+    "start to last join), drain (last save's end to the last saver's "
+    "join; inside pipeline), commit (table commits, megafile).",
+    labels=["phase"])
+_M_EVAL_SETUP_SECONDS = _mx.registry().counter(
+    "scanner_tpu_evaluator_setup_seconds_total",
+    "Evaluator-thread seconds spent constructing TaskEvaluators "
+    "(kernel construction, fetch_resources, set-up, weight restore).")
+_M_EVAL_SETUPS = _mx.registry().counter(
+    "scanner_tpu_evaluator_setups_total",
+    "TaskEvaluators constructed: one per pipeline instance per run.")
+_M_STAGE_WAIT = _mx.registry().counter(
+    "scanner_tpu_stage_wait_seconds_total",
+    "Seconds a stage thread waited on a neighbor: load = blocked "
+    "putting into a full evaluate or chunk queue, evaluate = waiting "
+    "for a task (chunk waits are scanner_tpu_chunk_wait_seconds_total), "
+    "save = waiting for an evaluated task.  The polls at a run's end, "
+    "which end with no item, count.",
+    labels=["stage"])
+_M_WAIT_LOAD, _M_WAIT_EVAL, _M_WAIT_SAVE = (
+    _M_STAGE_WAIT.labels(stage=st) for st in ("load", "evaluate", "save"))
 # end-to-end per-task latency: enqueue (task runnable — local admission
 # or master bulk admission) to sink-committed.  The seed for
 # serving-mode p50/p99 (ROADMAP item 2): under a request-shaped
@@ -103,6 +133,9 @@ _M_TASK_LATENCY = _mx.registry().histogram(
     "FinishedWork, observed on the master).",
     buckets=(0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
              120.0, 300.0, 600.0))
+
+# a wait shorter than this leaves no interval (its counter still counts)
+_WAIT_SPAN_MIN_S = 0.005
 
 _SENTINEL = object()
 _CHUNK_DONE = object()   # streaming producer: all chunks delivered
@@ -267,6 +300,9 @@ class LocalExecutor:
         self.tracer = _tr.default_tracer()
         # trace_id of the last local run (Client.trace reads it)
         self.last_trace_id: Optional[str] = None
+        # when the pipeline's savers last finished a task (run:drain
+        # starts there); None until one has
+        self._last_save_end: Optional[float] = None
         # frame-cache source identity: table ids are per-database and
         # restart at 0 (and a database re-created at the same root
         # would restart them too), so pages are keyed under a
@@ -561,6 +597,16 @@ class LocalExecutor:
         stage/op profiler spans inside nest under it."""
         return _tr.use_span(self.tracer, w.trace_span)
 
+    def _note_wait(self, name: str, counter, t0: float, **args) -> None:
+        """A stage thread's wait that began at `t0` is over: its seconds
+        go to `counter`, and one interval covers it whole — however many
+        queue time-outs it spanned — unless it was too short to tell."""
+        waited = time.time() - t0
+        counter.inc(waited)
+        if waited > _WAIT_SPAN_MIN_S:
+            self.profiler.add_interval(name, t0, t0 + waited, level=1,
+                                       **args)
+
     def _task_trace_end(self, w: TaskItem,
                         status: Optional[str] = None) -> None:
         span, w.trace_span = w.trace_span, None
@@ -573,13 +619,18 @@ class LocalExecutor:
     def run(self, outputs: Sequence[O.OpNode], perf: PerfParams,
             cache_mode: CacheMode = CacheMode.Error,
             show_progress: bool = False) -> List[JobContext]:
-        info, jobs = self.prepare(outputs, perf, cache_mode)
-        self.setup_chains(info, jobs, perf)
-        self._stream_opt = bool(getattr(perf, "stream_work_packets", True))
-        self.profiler.level = int(getattr(perf, "profiler_level", 1))
-        work = [TaskItem(job, t, rng)
-                for job in jobs if not job.skipped
-                for t, rng in enumerate(job.tasks)]
+        prof = self.profiler
+        prof.level = int(getattr(perf, "profiler_level", 1))
+        _M_RUNS.inc()
+        with prof.span("run:prepare", level=0,
+                       counter=_M_RUN_SECONDS.labels(phase="prepare")):
+            info, jobs = self.prepare(outputs, perf, cache_mode)
+            self.setup_chains(info, jobs, perf)
+            self._stream_opt = bool(
+                getattr(perf, "stream_work_packets", True))
+            work = [TaskItem(job, t, rng)
+                    for job in jobs if not job.skipped
+                    for t, rng in enumerate(job.tasks)]
         _log.info("job set prepared: %d jobs (%d skipped), %d tasks",
                   len(jobs), sum(1 for j in jobs if j.skipped), len(work))
         # the job's root trace span: every task span of this run chains
@@ -597,22 +648,38 @@ class LocalExecutor:
                 # level >= 2: capture the XLA device timeline around the
                 # job (SURVEY §5; merged into Profile.write_trace output)
                 from ..util.jaxprof import device_trace
-                with device_trace(self.profiler):
-                    self._run_pipeline(
-                        info, work, show_progress,
-                        queue_size=int(perf.queue_size_per_pipeline),
-                        precompile=self.precompile_hint(jobs))
+                self._last_save_end = None
+                with device_trace(prof), prof.span(
+                        "run:pipeline", level=0, tasks=len(work),
+                        counter=_M_RUN_SECONDS.labels(phase="pipeline")):
+                    try:
+                        self._run_pipeline(
+                            info, work, show_progress,
+                            queue_size=int(perf.queue_size_per_pipeline),
+                            precompile=self.precompile_hint(jobs))
+                    finally:
+                        # what the run paid after its last task was
+                        # committed: the stage threads' end-of-run polls
+                        joined = time.time()
+                        if self._last_save_end is not None:
+                            _M_RUN_SECONDS.labels(phase="drain").inc(
+                                joined - self._last_save_end)
+                            prof.add_interval("run:drain",
+                                              self._last_save_end, joined,
+                                              level=0)
         finally:
             _tr.close_span(self.tracer, root)
-        for job in jobs:
-            if job.skipped:
-                continue
-            for desc, _c, _k, _e in job.sink_tables.values():
-                self.db.commit_table(desc.id)
-            for stream in job.custom_sinks.values():
-                # durability barrier (reference Sink::finished)
-                stream.storage.finished(stream, job.jr.output_rows)
-        self.db.write_megafile()
+        with prof.span("run:commit", level=0,
+                       counter=_M_RUN_SECONDS.labels(phase="commit")):
+            for job in jobs:
+                if job.skipped:
+                    continue
+                for desc, _c, _k, _e in job.sink_tables.values():
+                    self.db.commit_table(desc.id)
+                for stream in job.custom_sinks.values():
+                    # durability barrier (reference Sink::finished)
+                    stream.storage.finished(stream, job.jr.output_rows)
+            self.db.write_megafile()
         return jobs
 
     @staticmethod
@@ -803,6 +870,23 @@ class LocalExecutor:
             w.instance = idx
             w.device = inst_devices[idx]
 
+        def next_item(q: "queue.Queue", upstream_done: threading.Event,
+                      wait_name: str, wait_counter, **args):
+            """The stage's next item, or None once the pipeline stops or
+            the stage before has finished and left `q` empty.  One wait,
+            one interval, however many time-outs it takes."""
+            t0 = time.time()
+            item = None
+            while not stop.is_set():
+                try:
+                    item = q.get(timeout=0.25)
+                    break
+                except queue.Empty:
+                    if upstream_done.is_set() and q.empty():
+                        break
+            self._note_wait(wait_name, wait_counter, t0, **args)
+            return item
+
         def loader():
             try:
                 try:
@@ -822,6 +906,7 @@ class LocalExecutor:
                             task_failed(w, e)
                             continue
                         placed = False
+                        t_put = time.time()
                         while not stop.is_set():
                             try:
                                 eval_qs[w.instance].put(w, timeout=0.25)
@@ -829,6 +914,8 @@ class LocalExecutor:
                                 break
                             except queue.Full:
                                 pass
+                        self._note_wait("load:queue_wait", _M_WAIT_LOAD, t_put,
+                                        task=w.task_idx, job=w.job.job_idx)
                         if placed and w.chunk_plans is not None:
                             # streaming task: decode chunks into its
                             # bounded queue while the evaluator consumes
@@ -855,6 +942,7 @@ class LocalExecutor:
         def evaluator(evaluator_idx: int):
             te = None
             my_q = eval_qs[evaluator_idx]
+            dev_lbl = device_label(inst_devices[evaluator_idx])
             import types
             fb_tls = types.SimpleNamespace()  # fallback reload decoders
             try:
@@ -862,17 +950,17 @@ class LocalExecutor:
                 # the rest only setup (reference evaluate_worker.cpp:488-534)
                 if evaluator_idx > 0:
                     fetch_done.wait()
-                te = make_evaluator(evaluator_idx, evaluator_idx > 0)
+                with self.profiler.span(
+                        "evaluate:setup", level=0,
+                        counter=_M_EVAL_SETUP_SECONDS, device=dev_lbl):
+                    te = make_evaluator(evaluator_idx, evaluator_idx > 0)
+                _M_EVAL_SETUPS.inc()
                 if evaluator_idx == 0:
                     fetch_done.set()
-                while not stop.is_set():
-                    try:
-                        w: TaskItem = my_q.get(timeout=0.25)
-                    except queue.Empty:
-                        if loaders_done.is_set() and my_q.empty():
-                            break
-                        continue
-                    if w is _SENTINEL:
+                while True:
+                    w = next_item(my_q, loaders_done, "evaluate:task_wait",
+                                  _M_WAIT_EVAL, device=dev_lbl)
+                    if w is None or w is _SENTINEL:
                         break
                     try:
                         if on_start is not None and on_start(w) is False:
@@ -885,13 +973,14 @@ class LocalExecutor:
                             continue  # revoked attempt: drop silently
                         t0 = time.time()
                         lbl = device_label(w.device)
-                        # busy seconds accrue while the task runs: a
+                        # open seconds accrue while the task runs: a
                         # long task never lands in one health sample
-                        with _M_DEV_BUSY.labels(device=lbl).timing(), \
+                        with _M_EVAL_OPEN.labels(device=lbl).timing(), \
                                 self._task_scope(w), \
                                 self.profiler.span("evaluate", level=0,
                                                    task=w.task_idx,
-                                                   job=w.job.job_idx):
+                                                   job=w.job.job_idx,
+                                                   device=lbl):
                             if w.chunk_q is not None:
                                 w.results = self._consume_chunks(
                                     info, te, w, fb_tls, stop=stop)
@@ -936,13 +1025,11 @@ class LocalExecutor:
 
         def saver():
             try:
-                while not stop.is_set():
-                    try:
-                        w: TaskItem = save_q.get(timeout=0.25)
-                    except queue.Empty:
-                        if evals_done.is_set() and save_q.empty():
-                            break
-                        continue
+                while True:
+                    w = next_item(save_q, evals_done, "save:queue_wait",
+                                  _M_WAIT_SAVE)
+                    if w is None:
+                        break
                     try:
                         t0 = time.time()
                         with self._task_scope(w):
@@ -950,9 +1037,14 @@ class LocalExecutor:
                                                     task=w.task_idx,
                                                     job=w.job.job_idx):
                                 self._save_task(info, w)
+                        t_saved = time.time()
                         _M_STAGE_SECONDS.labels(stage="save").inc(
-                            time.time() - t0)
+                            t_saved - t0)
                         _M_STAGE_TASKS.labels(stage="save").inc()
+                        with done_lock:
+                            # savers finish in any order: keep the latest
+                            self._last_save_end = max(
+                                self._last_save_end or 0.0, t_saved)
                         # close the span BEFORE on_done: the cluster
                         # worker's completion hook ships spans then sends
                         # FinishedWork, so the master holds this task's
@@ -1050,11 +1142,12 @@ class LocalExecutor:
                         continue  # revoked attempt
                     t0 = time.time()
                     lbl = device_label(w.device)
-                    with _M_DEV_BUSY.labels(device=lbl).timing(), \
+                    with _M_EVAL_OPEN.labels(device=lbl).timing(), \
                             self._task_scope(w), \
                             self.profiler.span("evaluate", level=0,
                                                task=w.task_idx,
-                                               job=w.job.job_idx):
+                                               job=w.job.job_idx,
+                                               device=lbl):
                         if w.chunk_plans is not None:
                             # inline streaming on this one thread; the
                             # carry-miss fallback loads through fb_tls —
@@ -1223,6 +1316,7 @@ class LocalExecutor:
                 cur = min(cur, m)
             self._keep_from = list(reversed(suffix))  # per chunk index
             self._chunk_i = 0
+            self._profiler = ex.profiler
             self._buf: Dict[int, Any] = {}
 
             # decode in slices matched to the chunk row count so peak
@@ -1289,9 +1383,12 @@ class LocalExecutor:
                 decoded += len(fr)
                 need -= set(rr.tolist())
             if decoded:
+                t1 = time.time()
                 lbl = threading.current_thread().name
                 _M_DECODED.labels(loader=lbl).inc(decoded)
-                _M_DECODE_SECONDS.labels(loader=lbl).inc(time.time() - t0)
+                _M_DECODE_SECONDS.labels(loader=lbl).inc(t1 - t0)
+                self._profiler.add_interval("load:decode", t0, t1,
+                                            frames=decoded)
             if self._plan is None:
                 data = np.stack([self._buf[int(r)] for r in rows_arr]) \
                     if len(rows_arr) else np.zeros((0,), np.uint8)
@@ -1304,9 +1401,11 @@ class LocalExecutor:
                     - self._item_start
                 fresh_data = (np.stack([self._buf[r] for r in fresh_g])
                               if fresh_g else np.zeros((0, 1), np.uint8))
-                data = self._cache.assemble_rows(
-                    self._plan, rows_arr - self._item_start,
-                    fresh_local, fresh_data, hw=self._hw)
+                with self._profiler.span("load:stage", rows=len(rows_arr),
+                                         fresh=len(fresh_g)):
+                    data = self._cache.assemble_rows(
+                        self._plan, rows_arr - self._item_start,
+                        fresh_local, fresh_data, hw=self._hw)
             keep_from = self._keep_from[self._chunk_i]
             self._chunk_i += 1
             for r in [r for r in self._buf if r < keep_from]:
@@ -1343,15 +1442,20 @@ class LocalExecutor:
             yield plan, elements
 
     def _chunk_put(self, w: TaskItem, item, stop) -> bool:
-        while True:
-            if (stop is not None and stop.is_set()) \
-                    or w.chunk_abort.is_set():
-                return False
-            try:
-                w.chunk_q.put(item, timeout=0.25)
-                return True
-            except queue.Full:
-                pass
+        t0 = time.time()
+        try:
+            while True:
+                if (stop is not None and stop.is_set()) \
+                        or w.chunk_abort.is_set():
+                    return False
+                try:
+                    w.chunk_q.put(item, timeout=0.25)
+                    return True
+                except queue.Full:
+                    pass
+        finally:
+            self._note_wait("load:queue_wait", _M_WAIT_LOAD, t0,
+                            task=w.task_idx, job=w.job.job_idx)
 
     def _produce_chunks(self, info: A.GraphInfo, w: TaskItem, tls,
                         stop=None) -> None:
@@ -1407,16 +1511,12 @@ class LocalExecutor:
                         if stop is not None and stop.is_set():
                             raise JobException(
                                 "pipeline stopped during streaming task")
-                waited = time.time() - t0
-                _M_CHUNK_WAIT.inc(waited)
-                if waited > 0.005:
-                    # starvation attribution: time the evaluator spent
-                    # waiting on the loader's chunk production (decode
-                    # slower than compute shows up here, not as inflated
-                    # kernel spans)
-                    self.profiler.add_interval(
-                        "evaluate:chunk_wait", t0, t0 + waited, level=1,
-                        task=w.task_idx, job=w.job.job_idx)
+                # starvation attribution: time the evaluator spent
+                # waiting on the loader's chunk production (decode
+                # slower than compute shows up here, not as inflated
+                # kernel spans)
+                self._note_wait("evaluate:chunk_wait", _M_CHUNK_WAIT, t0,
+                                task=w.task_idx, job=w.job.job_idx)
                 if item is _CHUNK_DONE:
                     return
                 if isinstance(item, tuple) and item[0] is _CHUNK_ERR:
@@ -1598,11 +1698,12 @@ class LocalExecutor:
         if not _device_staging_enabled():
             return
         cols = w.elements if elements is None else elements
-        for nid, b in cols.items():
-            if self._column_device_bound(info, nid) \
-                    and isinstance(b.data, np.ndarray) \
-                    and b.data.dtype != object:
-                cols[nid] = b.to_device(w.device)
+        with self.profiler.span("load:stage", task=w.task_idx):
+            for nid, b in cols.items():
+                if self._column_device_bound(info, nid) \
+                        and isinstance(b.data, np.ndarray) \
+                        and b.data.dtype != object:
+                    cols[nid] = b.to_device(w.device)
 
     def _yuv_device_wire(self, info: A.GraphInfo, node_id: int) -> bool:
         """Should this video column decode to YUV420 wire format?  Yes
@@ -1693,12 +1794,12 @@ class LocalExecutor:
                     start, _ = desc.item_bounds(it)
                     auto = self._automata(tls, w.job, node_id, si, it,
                                           output_format=fmt)
-                    t0 = time.time()
-                    frames = auto.get_frames(local)
                     lbl = threading.current_thread().name
+                    with self.profiler.span(
+                            "load:decode", frames=len(local),
+                            counter=_M_DECODE_SECONDS.labels(loader=lbl)):
+                        frames = auto.get_frames(local)
                     _M_DECODED.labels(loader=lbl).inc(len(local))
-                    _M_DECODE_SECONDS.labels(loader=lbl).inc(
-                        time.time() - t0)
                     # convert mark carries THIS item's geometry (items of
                     # one table may differ); mixed-geometry concat falls
                     # back to host conversion in concat_batches
@@ -1780,18 +1881,21 @@ class LocalExecutor:
         if len(miss):
             auto = self._automata(tls, w.job, node_id, si, item,
                                   output_format=fmt)
-            t0 = time.time()
-            frames = auto.get_frames(miss.tolist())
             lbl = threading.current_thread().name
+            with self.profiler.span(
+                    "load:decode", frames=len(miss),
+                    counter=_M_DECODE_SECONDS.labels(loader=lbl)):
+                frames = auto.get_frames(miss.tolist())
             _M_DECODED.labels(loader=lbl).inc(len(miss))
-            _M_DECODE_SECONDS.labels(loader=lbl).inc(time.time() - t0)
             hw = (auto.vd.height, auto.vd.width)
         else:
             frames = np.zeros((0, 1), np.uint8)
         if fmt == "yuv420" and not (hw and hw[0]):
             return None  # no geometry for the convert mark: bypass
         try:
-            data = cache.assemble(plan, miss, frames, hw=hw)
+            with self.profiler.span("load:stage", rows=len(rows_l),
+                                    fresh=len(miss)):
+                data = cache.assemble(plan, miss, frames, hw=hw)
         except _fc.CacheBypass:
             # falling back here re-decodes the miss rows on the direct
             # path (double decode for this one task).  Acceptable: a
@@ -1844,15 +1948,18 @@ class LocalExecutor:
         for sink in info.sinks:
             if sink.id in w.job.custom_sinks:
                 stream = w.job.custom_sinks[sink.id]
-                stream.storage.write_item(
-                    stream, start,
-                    self._sink_rows(w.results[sink.id], start, end))
+                with self.profiler.span("save:fetch", task=w.task_idx):
+                    rows = self._sink_rows(w.results[sink.id], start, end)
+                with self.profiler.span("save:write", task=w.task_idx):
+                    stream.storage.write_item(stream, start, rows)
                 continue
             if sink.id not in w.job.sink_tables:
                 continue
             desc, col_name, codec, enc_opts = w.job.sink_tables[sink.id]
             # the single device->host fetch of the batched data path
-            rows = self._sink_rows(w.results[sink.id], start, end)
+            with self.profiler.span("save:fetch", task=w.task_idx):
+                rows = self._sink_rows(w.results[sink.id], start, end)
+            t_write = time.time()
             item_idx = w.task_idx
             if codec == "frame":
                 mode = "video" if self._is_encodable(rows) else "pickle"
@@ -1910,6 +2017,8 @@ class LocalExecutor:
                 IT.write_item(self.db.backend,
                               md.column_item_path(desc.id, col_name,
                                                   item_idx), blobs)
+            self.profiler.add_interval("save:write", t_write, time.time(),
+                                       task=w.task_idx)
 
     @staticmethod
     def _async_sink_fetch_enabled() -> bool:

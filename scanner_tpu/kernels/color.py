@@ -71,6 +71,7 @@ def _device_converter(h: int, w: int):
     import jax
     import jax.numpy as jnp
 
+    @jax.named_scope("yuv420_to_rgb")
     def convert(flat):
         y, u, v = _split_planes(flat, h, w)
         up = jnp.repeat(jnp.repeat(u, 2, axis=-2), 2, axis=-1)[..., :h, :w]

@@ -39,6 +39,7 @@ def _frame_shape(shapes, idx: int = 0):
 
 
 @functools.partial(jax.jit, static_argnames=("bins",))
+@jax.named_scope("Histogram")
 def _histogram_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
     """(batch, H, W, C) uint8 -> (batch, C, bins) int32 counts.
 
@@ -94,6 +95,7 @@ def _histogram_seq_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
 
 
 @functools.partial(jax.jit, static_argnames=("bins",))
+@jax.named_scope("Histogram")
 def _histogram_cmp_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
     """(batch, H, W, C) uint8 -> (batch, C, bins) int32 via one-hot
     compare + reduce: pure VPU work, no scatter — the lowering fused
@@ -216,6 +218,7 @@ def _resize_band(in_size: int, out_size: int):
 
 
 @functools.partial(jax.jit, static_argnames=("h", "w"))
+@jax.named_scope("Resize")
 def _resize_impl(frames: jnp.ndarray, h: int, w: int):
     """Separable gather-based bilinear resize.  The triangle kernel is
     sparse — k taps per output row (k=4 for a 2x downscale) — but
@@ -285,6 +288,7 @@ class Resize(Kernel):
 
 
 @functools.partial(jax.jit, static_argnames=("oh", "ow"))
+@jax.named_scope("CropResize")
 def _crop_resize_impl(frames: jnp.ndarray, boxes: jnp.ndarray, oh: int,
                       ow: int):
     """Crop unit-coordinate boxes [y1,x1,y2,x2] out of (b,H,W,C) frames
@@ -360,6 +364,7 @@ def _gaussian_kernel1d(ksize: int, sigma: float) -> np.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("ksize",))
+@jax.named_scope("Blur")
 def _blur_impl(frames: jnp.ndarray, kern: jnp.ndarray, ksize: int):
     """Separable gaussian as shift-add: per tap, one scaled slice of the
     edge-padded image, summed — pure elementwise VPU work.  The previous
@@ -424,6 +429,7 @@ class Blur(Kernel):
 
 
 @jax.jit
+@jax.named_scope("OpticalFlow")
 def _grayscale(frames: jnp.ndarray) -> jnp.ndarray:
     w = jnp.asarray([0.299, 0.587, 0.114], jnp.float32)
     return (frames.astype(jnp.float32) * w).sum(-1)
@@ -433,6 +439,7 @@ HS_ITERS = 16  # fixed Horn-Schunck iteration count (cost model reads it)
 
 
 @functools.partial(jax.jit, static_argnames=("iters",))
+@jax.named_scope("OpticalFlow")
 def _horn_schunck(prev: jnp.ndarray, nxt: jnp.ndarray, iters: int = HS_ITERS,
                   alpha: float = 15.0):
     """Classic Horn-Schunck optical flow, batched; (b,h,w) grayscale in,

@@ -46,6 +46,7 @@ def _hist_kernel(vals_ref, out_ref, *, bins: int):
 
 
 @functools.partial(jax.jit, static_argnames=("bins", "interpret"))
+@jax.named_scope("Histogram")
 def pallas_histogram(vals: jnp.ndarray, bins: int = 16,
                      interpret: bool = False) -> jnp.ndarray:
     """(R, P) int32 bin indices -> (R, bins) int32 counts.

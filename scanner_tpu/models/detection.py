@@ -197,6 +197,7 @@ class ObjectDetect(Kernel):
         thresh = self.score_thresh
 
         @jax.jit
+        @jax.named_scope(type(self).__name__)
         def infer(params, images, anchors):
             cls, deltas = self.model.apply(params, images)
             packed, _sel = pack_detections(cls, deltas, anchors, thresh)

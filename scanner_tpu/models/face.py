@@ -82,8 +82,9 @@ class FaceEmbedding(Kernel):
             self.model, jax.random.PRNGKey(seed),
             jnp.zeros((1, 128, 128, 3), jnp.uint8), checkpoint_dir)
         # dp-shard batches over every chip the engine handed this kernel
-        self._dp = DataParallelApply(jax.jit(self.model.apply), params,
-                                     config.devices)
+        self._dp = DataParallelApply(
+            jax.jit(jax.named_scope("FaceEmbedding")(self.model.apply)),
+            params, config.devices)
         self.params = self._dp.params
 
     def infer_cost_flops(self, batch):

@@ -336,6 +336,7 @@ class PoseDetect(Kernel):
                 and width == self._shipped_width:
             checkpoint_dir = shipped_weights(self._shipped)
 
+        @jax.named_scope("PoseDetect")
         def apply_and_peaks(params, clip):
             """Forward + on-device argmax: ship (B,K,3) keypoints off the
             chip, not (B,h,w,K) heatmaps — heatmaps are ~MBs per batch

@@ -226,6 +226,7 @@ class InstanceSegment(Kernel):
         model = self.model
 
         @jax.jit
+        @jax.named_scope("InstanceSegment")
         def infer(params, images, anchors):
             def fwd(mdl, images):
                 cls, deltas = mdl.detect(images)

@@ -224,10 +224,10 @@ DEFAULT_RULES = (
     # evidence, not one long task
     AlertRule(
         name="device_saturation",
-        series="scanner_tpu_device_busy_seconds_total",
+        series="scanner_tpu_evaluate_open_seconds_total",
         form="rate", op=">", value=0.9, window=60.0, for_seconds=60.0,
         severity="warning", by=("device",), unless=_WARMING_SERIES,
-        description="a chip's evaluate-stage busy share is above 0.9 "
+        description="a chip's evaluate-open share is above 0.9 "
                     "over a minute, for a minute: the device is "
                     "compute-saturated (the autoscaling up-signal, not "
                     "by itself a fault; quiet while an evaluator is "

@@ -61,8 +61,10 @@ DEVICE_PID_BASE = 1000
 @contextlib.contextmanager
 def device_trace(profiler, out_dir: Optional[str] = None):
     """Capture the XLA device trace around a job when the profiler runs
-    at level >= 2; no-op otherwise (and on any profiler failure — a
-    broken tracer must never take down the job)."""
+    at level >= 2; no-op otherwise.  A failing tracer never takes down
+    the job: it is logged and counted as the profiler counter
+    `device_trace_failed`, so a level-2 profile without device lanes
+    says why."""
     if getattr(profiler, "level", 1) < 2:
         yield
         return
@@ -77,12 +79,20 @@ def device_trace(profiler, out_dir: Optional[str] = None):
         try:
             import jax
             trace_dir = out_dir or tempfile.mkdtemp(prefix="sc_devtrace_")
+            # device planes only: the host tracer's events of the TPU
+            # runtime run to hundreds of MB a job and slow the host
+            # threefold (PERF.md §6 finding 3); the host side of the
+            # merged view is the profiler's own spans
+            opts = jax.profiler.ProfileOptions()
+            opts.host_tracer_level = 0
+            opts.python_tracer_level = 0
             t0 = time.time()
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
             if auto:
                 _AUTO_DIRS.append(trace_dir)
         except Exception as e:  # noqa: BLE001
             _log.warning("jax.profiler.start_trace failed: %s", e)
+            profiler.count("device_trace_failed")
             if auto and trace_dir is not None:
                 shutil.rmtree(trace_dir, ignore_errors=True)
             yield
@@ -94,14 +104,12 @@ def device_trace(profiler, out_dir: Optional[str] = None):
                 jax.profiler.stop_trace()
                 # t0/t1 bound the capture window on the host wall clock;
                 # consumers align against THIS window, not the host
-                # profiler's first span — under the level-2 python
-                # tracer, trace start can precede the first stage span
-                # by many seconds (thread bootstrap, instrumented
-                # setup), which is trace content, not misalignment
+                # profiler's first span
                 profiler.device_traces.append(
                     {"dir": trace_dir, "t0": t0, "t1": time.time()})
             except Exception as e:  # noqa: BLE001
                 _log.warning("jax.profiler.stop_trace failed: %s", e)
+                profiler.count("device_trace_failed")
     finally:
         _ACTIVE.release()
 

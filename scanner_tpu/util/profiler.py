@@ -79,10 +79,14 @@ class Profiler:
         self.count("profiler_dropped")
         return False
 
-    def span(self, name: str, level: int = 1, **args):
-        if level > self.level:
+    def span(self, name: str, level: int = 1, counter=None, **args):
+        """A `with` block recorded as one interval.  `counter` (a
+        counter series of util/metrics.py) takes the block's seconds at
+        the same two clock reads, whether or not the active level keeps
+        the interval: the live series and the profile cannot disagree."""
+        if level > self.level and counter is None:
             return _NULL_SPAN
-        return _Span(self, name, args or None)
+        return _Span(self, name, args or None, counter, level <= self.level)
 
     def add_interval(self, name: str, start: float, end: float,
                      level: int = 1, **args) -> None:
@@ -159,12 +163,16 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("prof", "name", "args", "start", "_trace")
+    __slots__ = ("prof", "name", "args", "counter", "keep", "start",
+                 "_trace")
 
-    def __init__(self, prof: Profiler, name: str, args):
+    def __init__(self, prof: Profiler, name: str, args, counter=None,
+                 keep: bool = True):
         self.prof = prof
         self.name = name
         self.args = args
+        self.counter = counter
+        self.keep = keep
 
     def __enter__(self):
         self.start = time.time()
@@ -173,13 +181,16 @@ class _Span:
         # also records a distributed-trace span — the stage/op timings
         # in the flight recorder and the profile can never disagree
         self._trace = _tracing.begin_interval(self.name, self.args) \
-            if _tracing.enabled() else None
+            if self.keep and _tracing.enabled() else None
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self.prof._room():
+        end = time.time()
+        if self.counter is not None:
+            self.counter.inc(end - self.start)
+        if self.keep and self.prof._room():
             self.prof._list().append(Interval(
-                self.name, self.start, time.time(),
+                self.name, self.start, end,
                 threading.current_thread().name, self.args))
         if self._trace is not None:
             _tracing.end_interval(self._trace, exc)

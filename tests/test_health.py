@@ -318,7 +318,7 @@ def test_default_rules_quiet_through_a_cold_start_on_the_chip():
     evaluators meet only cache hits.  No default rule may fire on
     that; sustained saturation afterwards still does."""
     reg = MetricsRegistry()
-    busy = reg.counter("scanner_tpu_device_busy_seconds_total", "x",
+    busy = reg.counter("scanner_tpu_evaluate_open_seconds_total", "x",
                        labels=["device"])
     depth = reg.gauge("scanner_tpu_stage_queue_depth", "x",
                       labels=["stage"])

@@ -118,20 +118,19 @@ def test_device_trace_merged_at_level2():
         dev = [e for e in evs if e.get("pid", 0) >= DEVICE_PID_BASE]
         assert any(e["name"] == "load" for e in host)
         assert dev, "device events missing from merged trace"
-        # alignment: device events (incl. the Python spans the merge
-        # filters by default — on the CPU backend they may be ALL the
-        # trace has) sit inside the CAPTURE window [t0, t1] after the t0
-        # shift.  The window, not the first host stage span, is the
-        # alignment anchor: the level-2 python tracer records thread
-        # bootstrap/setup work between start_trace and the first load
-        # span, and that gap can be tens of seconds on a slow host.
+        # device planes only (host and python tracers off, PERF.md §6
+        # finding 3): on the CPU backend there is no device plane, so
+        # the capture holds the process metadata alone and never a
+        # python-call span; where a device does report, its events sit
+        # inside the CAPTURE window [t0, t1] after the t0 shift
         from scanner_tpu.util.jaxprof import load_device_events
         full = load_device_events(recs[0], include_python=True)
+        assert not [e for e in full
+                    if str(e.get("name", "")).startswith("$")]
         dev_ts = [e["ts"] for e in full
                   if "ts" in e and e.get("ph") != "M"]
         t0_us, t1_us = recs[0]["t0"] * 1e6, recs[0]["t1"] * 1e6
-        assert dev_ts and min(dev_ts) >= t0_us - 1e6
-        assert max(dev_ts) <= t1_us + 60e6
+        assert all(t0_us - 1e6 <= t <= t1_us + 60e6 for t in dev_ts)
         # and the host stage spans sit inside that same window (one
         # merged perfetto timeline, host and device lanes on one clock:
         # the trace wraps the whole pipeline, so every stage span falls

@@ -218,7 +218,7 @@ def digest(snap: dict) -> dict:
         d["dev_tasks"] = _per_device(
             snap, "scanner_tpu_device_tasks_total", node)
         d["dev_busy"] = _per_device(
-            snap, "scanner_tpu_device_busy_seconds_total", node)
+            snap, "scanner_tpu_evaluate_open_seconds_total", node)
         # per-chip memory (util/memstats.py): backend-reported HBM
         # occupancy/limit plus the allocation ledger's engine-owned
         # live bytes (summed across buffer kinds)
