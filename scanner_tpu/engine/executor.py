@@ -21,6 +21,7 @@ import queue
 import struct
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -115,8 +116,8 @@ _M_STAGE_WAIT = _mx.registry().counter(
     "Seconds a stage thread waited on a neighbor: load = blocked "
     "putting into a full evaluate or chunk queue, evaluate = waiting "
     "for a task (chunk waits are scanner_tpu_chunk_wait_seconds_total), "
-    "save = waiting for an evaluated task.  The polls at a run's end, "
-    "which end with no item, count.",
+    "save = waiting for an evaluated task.  A stage thread's last "
+    "wait, which ends with its queue closed, counts.",
     labels=["stage"])
 _M_WAIT_LOAD, _M_WAIT_EVAL, _M_WAIT_SAVE = (
     _M_STAGE_WAIT.labels(stage=st) for st in ("load", "evaluate", "save"))
@@ -137,11 +138,64 @@ _M_TASK_LATENCY = _mx.registry().histogram(
 # a wait shorter than this leaves no interval (its counter still counts)
 _WAIT_SPAN_MIN_S = 0.005
 
-_SENTINEL = object()
 _CHUNK_DONE = object()   # streaming producer: all chunks delivered
 _CHUNK_ERR = object()    # streaming producer: (marker, exception)
 
 _log = get_logger("engine")
+
+
+class _StageQueue:
+    """Bounded hand-off between two pipeline stages that ends when it is
+    told to, not when a waiter times out.  The side that fills it calls
+    `close()` once its last producer has finished; consumers then take
+    what is left and get None.  `abort()` is the error path: every
+    blocked `put` and `get` returns at once, and what is queued is left
+    where it is."""
+
+    def __init__(self, maxsize: int):
+        self._cond = threading.Condition()
+        self._items: deque = deque()
+        self._maxsize = maxsize
+        self._closed = False
+        self._aborted = False
+
+    def qsize(self) -> int:
+        return len(self._items)
+
+    def put(self, item) -> bool:
+        """Blocks while full.  False, and the item not queued, once the
+        queue is aborted."""
+        with self._cond:
+            while len(self._items) >= self._maxsize and not self._aborted:
+                self._cond.wait()
+            if self._aborted:
+                return False
+            self._items.append(item)
+            # producers and consumers share the condition: wake them all
+            self._cond.notify_all()
+            return True
+
+    def get(self):
+        """Blocks while empty and open.  None once the queue is closed
+        and empty, or aborted."""
+        with self._cond:
+            while not (self._items or self._closed or self._aborted):
+                self._cond.wait()
+            if self._aborted or not self._items:
+                return None
+            item = self._items.popleft()
+            self._cond.notify_all()
+            return item
+
+    def close(self) -> None:
+        with self._cond:
+            self._closed = True
+            self._cond.notify_all()
+
+    def abort(self) -> None:
+        with self._cond:
+            self._aborted = True
+            self._cond.notify_all()
 
 
 @dataclass
@@ -659,7 +713,8 @@ class LocalExecutor:
                             precompile=self.precompile_hint(jobs))
                     finally:
                         # what the run paid after its last task was
-                        # committed: the stage threads' end-of-run polls
+                        # committed: the stage threads' joins and the
+                        # evaluators' close()
                         joined = time.time()
                         if self._last_save_end is not None:
                             _M_RUN_SECONDS.labels(phase="drain").inc(
@@ -799,13 +854,11 @@ class LocalExecutor:
         from .evaluate import assigned_device, device_label
         inst_devices = [assigned_device(i) for i in range(n_evals)]
         if n_evals > 1 and any(d is not None for d in inst_devices):
-            eval_qs: List["queue.Queue"] = [queue.Queue(maxsize=qsize)
-                                            for _ in range(n_evals)]
+            eval_qs = [_StageQueue(qsize) for _ in range(n_evals)]
         else:
-            shared_q: "queue.Queue" = queue.Queue(maxsize=qsize)
-            eval_qs = [shared_q] * n_evals
+            eval_qs = [_StageQueue(qsize)] * n_evals
         uniq_qs = list({id(q): q for q in eval_qs}.values())
-        save_q: "queue.Queue" = queue.Queue(maxsize=qsize)
+        save_q = _StageQueue(qsize)
         # live depth gauges sample the queues at scrape time; the last
         # pipeline to start owns the gauge (concurrent pipelines in one
         # process share the process registry)
@@ -817,12 +870,17 @@ class LocalExecutor:
             _M_QDEPTH.labels(stage=stage).set_function(fn)
         errors: List[BaseException] = []
         err_lock = threading.Lock()
+        # errors only: a run that ends well never sets it
         stop = threading.Event()
 
         def record_err(e: BaseException):
             with err_lock:
                 errors.append(e)
             stop.set()
+            # wake whoever is blocked on a hand-off whose other side
+            # may be dead
+            for q in uniq_qs + [save_q]:
+                q.abort()
 
         def task_failed(w: TaskItem, e: BaseException) -> None:
             """Route one task's failure; abort unless the error handler
@@ -870,20 +928,13 @@ class LocalExecutor:
             w.instance = idx
             w.device = inst_devices[idx]
 
-        def next_item(q: "queue.Queue", upstream_done: threading.Event,
-                      wait_name: str, wait_counter, **args):
+        def next_item(q: _StageQueue, wait_name: str, wait_counter,
+                      **args):
             """The stage's next item, or None once the pipeline stops or
             the stage before has finished and left `q` empty.  One wait,
-            one interval, however many time-outs it takes."""
+            one interval."""
             t0 = time.time()
-            item = None
-            while not stop.is_set():
-                try:
-                    item = q.get(timeout=0.25)
-                    break
-                except queue.Empty:
-                    if upstream_done.is_set() and q.empty():
-                        break
+            item = q.get()
             self._note_wait(wait_name, wait_counter, t0, **args)
             return item
 
@@ -905,15 +956,8 @@ class LocalExecutor:
                         except Exception as e:  # noqa: BLE001
                             task_failed(w, e)
                             continue
-                        placed = False
                         t_put = time.time()
-                        while not stop.is_set():
-                            try:
-                                eval_qs[w.instance].put(w, timeout=0.25)
-                                placed = True
-                                break
-                            except queue.Full:
-                                pass
+                        placed = eval_qs[w.instance].put(w)
                         self._note_wait("load:queue_wait", _M_WAIT_LOAD, t_put,
                                         task=w.task_idx, job=w.job.job_idx)
                         if placed and w.chunk_plans is not None:
@@ -958,9 +1002,9 @@ class LocalExecutor:
                 if evaluator_idx == 0:
                     fetch_done.set()
                 while True:
-                    w = next_item(my_q, loaders_done, "evaluate:task_wait",
-                                  _M_WAIT_EVAL, device=dev_lbl)
-                    if w is None or w is _SENTINEL:
+                    w = next_item(my_q, "evaluate:task_wait", _M_WAIT_EVAL,
+                                  device=dev_lbl)
+                    if w is None:
                         break
                     try:
                         if on_start is not None and on_start(w) is False:
@@ -1005,12 +1049,8 @@ class LocalExecutor:
                         continue
                     if on_eval_done is not None:
                         on_eval_done(w)
-                    while not stop.is_set():
-                        try:
-                            save_q.put(w, timeout=0.25)
-                            break
-                        except queue.Full:
-                            pass
+                    if not save_q.put(w):
+                        break
             except BaseException as e:  # noqa: BLE001
                 record_err(e)
             finally:
@@ -1026,8 +1066,7 @@ class LocalExecutor:
         def saver():
             try:
                 while True:
-                    w = next_item(save_q, evals_done, "save:queue_wait",
-                                  _M_WAIT_SAVE)
+                    w = next_item(save_q, "save:queue_wait", _M_WAIT_SAVE)
                     if w is None:
                         break
                     try:
@@ -1064,8 +1103,6 @@ class LocalExecutor:
                 record_err(e)
 
         fetch_done = threading.Event()
-        loaders_done = threading.Event()
-        evals_done = threading.Event()
 
         loaders = [threading.Thread(target=loader, name=f"load-{i}")
                    for i in range(n_loaders)]
@@ -1077,12 +1114,14 @@ class LocalExecutor:
         try:
             for t in loaders + evals + savers:
                 t.start()
+            # each hand-off closes when the stage that fills it is done
             for t in loaders:
                 t.join()
-            loaders_done.set()
+            for q in uniq_qs:
+                q.close()
             for t in evals:
                 t.join()
-            evals_done.set()
+            save_q.close()
             for t in savers:
                 t.join()
         finally:

@@ -67,6 +67,13 @@ def _run(sc, name, op="Histogram", perf=None, **kw):
     return ivs, t_call, t_done
 
 
+def _by_name(ivs):
+    by = {}
+    for iv in ivs:
+        by.setdefault(iv.name, []).append(iv)
+    return by
+
+
 def _uncovered(lo, hi, pairs):
     covered, edge = 0.0, lo
     for s, e in sorted(pairs):
@@ -81,9 +88,7 @@ def _uncovered(lo, hi, pairs):
 def test_run_lifecycle_spans_cover_the_run(sc, instances):
     ivs, t_call, t_done = _run(sc, f"life{instances}",
                                pipeline_instances=instances)
-    by = {}
-    for iv in ivs:
-        by.setdefault(iv.name, []).append(iv)
+    by = _by_name(ivs)
     for name in LIFECYCLE:
         assert len(by.get(name, [])) == 1, (name, len(by.get(name, [])))
     assert len(by["evaluate:setup"]) == instances
@@ -114,7 +119,30 @@ def test_run_lifecycle_spans_cover_the_run(sc, instances):
             assert any(p.thread == c.thread and p.start <= c.start
                        and c.end <= p.end for p in by[parent]), c
     named = [(iv.start, iv.end) for iv in ivs if iv.name not in CONTAINERS]
-    assert _uncovered(t_call, t_done, named) <= 0.05 * (t_done - t_call)
+    # a run of this size takes some 30 ms: the floor is the stage
+    # threads' starts, which no span covers
+    assert _uncovered(t_call, t_done, named) \
+        <= max(0.05 * (t_done - t_call), 0.005)
+
+
+@pytest.mark.parametrize("savers", [1, 2])
+@pytest.mark.parametrize("instances", [1, 2])
+def test_run_returns_when_its_last_row_is_saved(sc, monkeypatch, instances,
+                                                savers):
+    """The stage hand-offs are closed, not polled: nothing timed stands
+    between the last save's end and the run's return."""
+    monkeypatch.setattr(sc._executor, "num_save_workers", savers)
+    ivs, _, t_done = _run(sc, f"prompt{instances}{savers}",
+                          pipeline_instances=instances)
+    by = _by_name(ivs)
+    assert {iv.thread for iv in by["save"]} \
+        <= {f"save-{i}" for i in range(savers)}
+    (drain,), (commit,) = by["run:drain"], by["run:commit"]
+    last_save = max(iv.end for iv in by["save"])
+    assert 0.0 < drain.end - drain.start < 0.1
+    # the commit is the run's own work after the pipeline; less it, the
+    # return follows the last save at once
+    assert t_done - last_save - (commit.end - commit.start) < 0.1
 
 
 def test_counters_count_what_the_spans_cover(sc):
@@ -160,8 +188,7 @@ def test_counters_count_what_the_spans_cover(sc):
 
 def test_a_starved_evaluator_waits_in_one_interval(sc, monkeypatch):
     """A loader that takes 0.6 s over a task leaves the evaluator and
-    the savers waiting through two 0.25 s queue time-outs: one interval
-    each, not one per time-out."""
+    the savers waiting 0.6 s: one interval each."""
     load_task = _executor.LocalExecutor.load_task
 
     def slow_load(self, info, w, tls):
@@ -176,9 +203,10 @@ def test_a_starved_evaluator_waits_in_one_interval(sc, monkeypatch):
                  and iv.start < first_eval]
         assert len(early) == threads, (name, early)
         assert all(iv.end - iv.start >= 0.5 for iv in early), early
-    # and each stage thread's poll at the run's end is one more
+    # a stage thread's last wait ends when its queue is closed: too
+    # short for an interval of its own
     waits = [iv for iv in ivs if iv.name == "evaluate:task_wait"]
-    assert len(waits) == 2 and waits[1].start >= first_eval
+    assert all(iv.end - iv.start < 0.1 for iv in waits[1:]), waits
 
 
 def test_a_slow_evaluator_holds_the_loaders(sc):
