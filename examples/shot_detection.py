@@ -1,5 +1,6 @@
 """Shot detection app: histogram-difference boundaries + montage export.
-(Reference: examples/apps/shot_detection.)
+(Reference: examples/apps/shot_detection.)  The benchmark's cell
+`shot_dense` (benchmark/configs/shot_1080p.json) guards this graph.
 
 Usage: python examples/shot_detection.py path/to/video.mp4
 """

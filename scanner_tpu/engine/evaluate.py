@@ -59,6 +59,25 @@ _M_OP_ROWS = _mx.registry().counter(
     "scanner_tpu_op_rows_total",
     "Rows evaluated per op (kernel calls, warmup rows included).",
     labels=["op"])
+# a stencilled op's window: what it costs to have the producer's column
+# where the op runs (for a host op behind a device op, the wait for the
+# producer's kernel and the device->host fetch), and the rows the
+# producer evaluated only for the window's reach over a task boundary
+_M_WINDOW_SECONDS = _mx.registry().counter(
+    "scanner_tpu_stencil_window_seconds_total",
+    "Seconds a stencilled op spent having its window ready before its "
+    "first call of a task or chunk: its input columns brought to where "
+    "it runs (a device producer's column fetched to a host op waits "
+    "for the producer's kernel) and the window's rows looked up; "
+    "mirrors the evaluate:window span.",
+    labels=["op"])
+_M_HALO_ROWS = _mx.registry().counter(
+    "scanner_tpu_stencil_halo_rows_total",
+    "Rows of a producing op (label) that a task or chunk evaluated or "
+    "loaded only because a consumer's stencil window reaches outside "
+    "the consumer's own rows there: the back-reach over a task "
+    "boundary, paid again by each task.",
+    labels=["op"])
 _M_OP_RECOMPILES = _mx.registry().counter(
     "scanner_tpu_op_recompiles_total",
     "New input (device, shape, dtype) signatures seen per kernel "
@@ -1183,22 +1202,41 @@ class TaskEvaluator:
         # instead of silently copying cross-chip).
         is_device_kernel = (n.effective_device() == DeviceType.TPU
                             and _device_staging_enabled())
-        for i, (c, b) in enumerate(zip(in_cols, in_batches)):
-            if is_device_kernel and isinstance(b.data, np.ndarray) \
-                    and b.data.dtype != object:
-                b = b.to_device(ki.device)
-            elif not is_device_kernel:
-                b = b.to_host()
-            # resolve a pending wire-format conversion (YUV420 staged at
-            # 1.5 B/px) exactly once, where the data now lives: a jit
-            # device op for device kernels — XLA fuses it ahead of the
-            # kernel — or the bit-identical numpy flavor on host
-            if b.convert is not None:
-                b = b.converted()
-            in_batches[i] = b
-            store[(c.op.id, c.column)] = b
-
         compute = np.asarray(ts.compute_rows, np.int64)
+        sten = np.asarray(stencil, np.int64)
+        # a stencilled op's window is one span: the columns brought to
+        # where the op runs, and the window's rows looked up in them
+        window = self.profiler.span(
+            "evaluate:window", op=n.name, rows=len(compute),
+            counter=_M_WINDOW_SECONDS.labels(op=n.name)) \
+            if has_stencil else contextlib.nullcontext()
+        with window:
+            for i, (c, b) in enumerate(zip(in_cols, in_batches)):
+                if is_device_kernel and isinstance(b.data, np.ndarray) \
+                        and b.data.dtype != object:
+                    b = b.to_device(ki.device)
+                elif not is_device_kernel:
+                    b = b.to_host()
+                # resolve a pending wire-format conversion (YUV420 staged
+                # at 1.5 B/px) exactly once, where the data now lives: a
+                # jit device op for device kernels — XLA fuses it ahead of
+                # the kernel — or the bit-identical numpy flavor on host
+                if b.convert is not None:
+                    b = b.converted()
+                in_batches[i] = b
+                store[(c.op.id, c.column)] = b
+            # window positions per compute row per input column
+            # (REPEAT_EDGE)
+            win_rows = np.clip(compute[:, None] + sten[None, :], 0,
+                               max_in - 1)
+            col_pos = [b.positions(win_rows.reshape(-1)).reshape(
+                win_rows.shape) for b in in_batches]
+        if has_stencil:
+            halo = len(np.setdiff1d(win_rows, compute))
+            if halo:
+                for c in in_cols:
+                    _M_HALO_ROWS.labels(op=c.op.name).inc(halo)
+
         out_cols = [c for c, _ in n.spec.output_columns]
         valid_out = np.asarray(ts.valid_output_rows, np.int64)
         valid_set = set(valid_out.tolist())
@@ -1219,12 +1257,6 @@ class TaskEvaluator:
                     f"{int(compute[0]) - 1} of stream "
                     f"({plan.job_idx}, {plan.slice_group}); instance is "
                     f"at {ki._last_row}")
-
-        # window positions per compute row per input column (REPEAT_EDGE)
-        sten = np.asarray(stencil, np.int64)
-        win_rows = np.clip(compute[:, None] + sten[None, :], 0, max_in - 1)
-        col_pos = [b.positions(win_rows.reshape(-1)).reshape(win_rows.shape)
-                   for b in in_batches]
 
         # null propagation: a row whose inputs (or stencil window) contain a
         # null yields null without running the kernel
@@ -1341,8 +1373,9 @@ class TaskEvaluator:
             and n.effective_device() == DeviceType.TPU
         run_secs = run_flops = run_bytes = 0.0
         try:
-            with self.profiler.span("evaluate:" + n.name,
-                                    rows=len(compute)):
+            with self.profiler.span(
+                    "evaluate:" + n.name, rows=len(compute),
+                    device=ki.dev_label if is_device_kernel else "host"):
                 for lo, hi in run_bounds:
                     ki.maybe_reset(int(compute[lo]))
                     ki._last_row = int(compute[hi - 1])

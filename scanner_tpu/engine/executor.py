@@ -67,8 +67,16 @@ _M_CHUNK_WAIT = _mx.registry().counter(
     "trace intervals).")
 _M_DECODED = _mx.registry().counter(
     "scanner_tpu_decoded_frames_total",
-    "Video frames decoded and delivered to the pipeline, per loader "
-    "thread.",
+    "Video frames delivered to the pipeline by the decoder (the rows "
+    "asked of it), per loader thread.  What the codec decoded to get "
+    "them is scanner_tpu_codec_frames_total.",
+    labels=["loader"])
+_M_CODEC_FRAMES = _mx.registry().counter(
+    "scanner_tpu_codec_frames_total",
+    "Video frames the codec decoded, delivered or dropped: every frame "
+    "of each keyframe-aligned decode run, per loader thread.  Over "
+    "scanner_tpu_decoded_frames_total it is the codec's work per "
+    "delivered frame (1 for a dense scan of whole GOPs).",
     labels=["loader"])
 _M_DECODE_SECONDS = _mx.registry().counter(
     "scanner_tpu_decode_seconds_total",
@@ -1372,6 +1380,7 @@ class LocalExecutor:
             self._item_start = int(item_start)
             auto = ex._automata(tls, w.job, node_id, si, item,
                                 output_format=output_format)
+            self._auto = auto
             self.convert = (("yuv420", auto.vd.height, auto.vd.width)
                             if output_format == "yuv420" else None)
             self._hw = (auto.vd.height, auto.vd.width)
@@ -1415,6 +1424,7 @@ class LocalExecutor:
                     - self._buf.keys()
             t0 = time.time()
             decoded = 0
+            codec0 = self._auto.codec_frames
             while need:
                 rr, fr = next(self._gen)  # StopIteration = decode bug
                 for r, f in zip(rr.tolist(), fr):
@@ -1425,6 +1435,8 @@ class LocalExecutor:
                 t1 = time.time()
                 lbl = threading.current_thread().name
                 _M_DECODED.labels(loader=lbl).inc(decoded)
+                _M_CODEC_FRAMES.labels(loader=lbl).inc(
+                    self._auto.codec_frames - codec0)
                 _M_DECODE_SECONDS.labels(loader=lbl).inc(t1 - t0)
                 self._profiler.add_interval("load:decode", t0, t1,
                                             frames=decoded)
@@ -1833,12 +1845,7 @@ class LocalExecutor:
                     start, _ = desc.item_bounds(it)
                     auto = self._automata(tls, w.job, node_id, si, it,
                                           output_format=fmt)
-                    lbl = threading.current_thread().name
-                    with self.profiler.span(
-                            "load:decode", frames=len(local),
-                            counter=_M_DECODE_SECONDS.labels(loader=lbl)):
-                        frames = auto.get_frames(local)
-                    _M_DECODED.labels(loader=lbl).inc(len(local))
+                    frames = self._decode_counted(auto, local)
                     # convert mark carries THIS item's geometry (items of
                     # one table may differ); mixed-geometry concat falls
                     # back to host conversion in concat_batches
@@ -1849,6 +1856,19 @@ class LocalExecutor:
                         convert=convert))
                 out[node_id] = concat_batches(parts)
         return out
+
+    def _decode_counted(self, auto, local: List[int]) -> np.ndarray:
+        """`auto.get_frames(local)` under the `load:decode` span, with
+        the delivered and the codec's frame counts."""
+        lbl = threading.current_thread().name
+        codec0 = auto.codec_frames
+        with self.profiler.span(
+                "load:decode", frames=len(local),
+                counter=_M_DECODE_SECONDS.labels(loader=lbl)):
+            frames = auto.get_frames(local)
+        _M_DECODED.labels(loader=lbl).inc(len(local))
+        _M_CODEC_FRAMES.labels(loader=lbl).inc(auto.codec_frames - codec0)
+        return frames
 
     def _load_plain_source(self, w: TaskItem, node_id: int,
                            rows_l: List[int]) -> ColumnBatch:
@@ -1920,12 +1940,7 @@ class LocalExecutor:
         if len(miss):
             auto = self._automata(tls, w.job, node_id, si, item,
                                   output_format=fmt)
-            lbl = threading.current_thread().name
-            with self.profiler.span(
-                    "load:decode", frames=len(miss),
-                    counter=_M_DECODE_SECONDS.labels(loader=lbl)):
-                frames = auto.get_frames(miss.tolist())
-            _M_DECODED.labels(loader=lbl).inc(len(miss))
+            frames = self._decode_counted(auto, miss.tolist())
             hw = (auto.vd.height, auto.vd.width)
         else:
             frames = np.zeros((0, 1), np.uint8)

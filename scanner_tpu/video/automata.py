@@ -132,6 +132,13 @@ class DecoderAutomata:
             return yuv420_frame_bytes(self.vd.height, self.vd.width)
         return self.vd.height * self.vd.width * 3
 
+    @property
+    def codec_frames(self) -> int:
+        """Frames the codec has decoded for this automaton so far: the
+        ones delivered and the ones a run decoded only to reach them
+        (from the keyframe up, and through to the next wanted frame)."""
+        return self.decoder.codec_frames
+
     def _scratch_buf(self, nbytes: int) -> np.ndarray:
         if self._scratch.nbytes < nbytes:
             self._scratch = np.empty(int(nbytes * 1.5) + 1, np.uint8)

@@ -269,6 +269,10 @@ class Decoder:
         self.output_format = output_format
         if output_format == "yuv420":
             self._lib.scvid_decoder_set_output_format(self._h, 1)
+        # frames the codec has put out over this handle's life, whether
+        # a caller wanted them or they were dropped on the way to one
+        self.codec_frames = 0
+        self._emitted_seen = 0
 
     def close(self):
         if self._h:
@@ -283,6 +287,14 @@ class Decoder:
 
     def reset(self):
         self._lib.scvid_decoder_reset(self._h)
+        self._emitted_seen = 0
+
+    def _note_emitted(self):
+        """Adds what the codec emitted since the last look (the C side
+        counts every received frame since the last reset)."""
+        now = int(self._lib.scvid_decoder_emitted(self._h))
+        self.codec_frames += now - self._emitted_seen
+        self._emitted_seen = now
 
     def decode_run(self, packets: bytes, sizes: np.ndarray,
                    wanted: np.ndarray, out: np.ndarray,
@@ -300,6 +312,7 @@ class Decoder:
             wanted.ctypes.data_as(C.c_char_p), len(wanted),
             1 if flush else 0,
             out.ctypes.data_as(C.c_void_p), out.nbytes, dims)
+        self._note_emitted()
         if n < 0:
             raise ScannerException(f"decode failed: {_err()}")
         return int(n), int(dims[0]), int(dims[1])
@@ -330,6 +343,7 @@ class Decoder:
             1 if flush else 0, int(max_frames),
             out.ctypes.data_as(C.c_void_p), out.nbytes, dims,
             C.byref(consumed))
+        self._note_emitted()
         if n < 0:
             raise ScannerException(f"decode failed: {_err()}")
         return (int(n), int(dims[0]), int(dims[1]), deliv.astype(bool),
@@ -360,6 +374,7 @@ class Decoder:
             deliv.ctypes.data_as(C.c_char_p),
             1 if flush else 0,
             out.ctypes.data_as(C.c_void_p), out.nbytes, dims)
+        self._note_emitted()
         if n < 0:
             raise ScannerException(f"decode failed: {_err()}")
         return int(n), int(dims[0]), int(dims[1]), deliv.astype(bool)

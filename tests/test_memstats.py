@@ -45,6 +45,17 @@ def _disarm_faults():
     faults.clear()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _empty_frame_cache():
+    """The frame cache is a process singleton, and a worker runs several
+    test files: pages that an earlier file's jobs left resident (tens of
+    KB each) would crowd this file's own entries out of the ledger's
+    top entries.  Their ledger entries go when the pages are collected."""
+    from scanner_tpu.engine import framecache
+    framecache.cache().clear()
+    gc.collect()
+
+
 # ---------------------------------------------------------------------------
 # ledger units
 # ---------------------------------------------------------------------------
