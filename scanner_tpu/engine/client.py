@@ -78,7 +78,11 @@ class Client:
                  storage_type: Optional[str] = None,
                  master: Optional[str] = None,
                  workers: Optional[List[str]] = None,
-                 num_load_workers: int = 2,
+                 # None = derived at each run from the cores this
+                 # process may use, the run's evaluator instances, its
+                 # queue depth and its tasks (engine/evaluate.py
+                 # default_load_workers).  An explicit value wins.
+                 num_load_workers: Optional[int] = None,
                  num_save_workers: int = 2,
                  # None = resolve at job launch: one device-affine
                  # instance per local chip on multi-device hosts

@@ -252,6 +252,46 @@ def default_pipeline_instances(configured: Optional[int] = None) -> int:
     return 1
 
 
+def usable_cores() -> int:
+    """Cores this process may run on: its affinity mask where the
+    platform has one (a container's or taskset's share of the host),
+    else the machine's count."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:
+        return os.cpu_count() or 1
+
+
+def default_load_workers(configured: Optional[int] = None,
+                         instances: int = 1, queues: int = 1,
+                         qsize: int = 4, tasks: int = 0,
+                         decoder_threads: int = 1) -> int:
+    """Resolve the loader-thread count of one pipeline run, under
+    default_pipeline_instances' contract: an explicit setting wins as
+    given — ANY value, 1 included — and only an unset count (None/0)
+    is derived, as the least of what the run can use:
+
+    - the host: usable_cores() less one per evaluator thread, one for
+      the savers and one for the main thread; a loader takes
+      `decoder_threads` of what is left.
+    - the pipeline's depth: a streaming task's chunks are decoded only
+      once the task sits in its evaluator's queue (executor.py
+      loader()), so at most `qsize` tasks per queue plus the one each
+      of the `instances` evaluators holds decode at once; a loader
+      beyond that only waits in load:queue_wait.
+    - `tasks`, where the run knows its count (0 = open-ended, a
+      cluster worker pulling from the master): a one-task query
+      starts one loader.
+    """
+    if configured:
+        return int(configured)
+    spare = (usable_cores() - instances - 2) // max(1, decoder_threads)
+    n = min(spare, queues * qsize + instances)
+    if tasks > 0:
+        n = min(n, tasks)
+    return max(1, n)
+
+
 # canonical implementation lives with the memory accountant so metrics,
 # ledger entries and trace attrs key devices identically; re-exported
 # here because the evaluator/executor are its historical home

@@ -49,8 +49,11 @@ from .evaluate import TaskEvaluator
 # time: a full evaluate queue + idle save queue = compute-bound, etc.
 _M_QDEPTH = _mx.registry().gauge(
     "scanner_tpu_stage_queue_depth",
-    "Tasks currently queued ahead of a pipeline stage (live; sampled "
-    "at scrape time from the bounded inter-stage queues).",
+    "Tasks currently queued ahead of a pipeline stage and waiting on "
+    "that stage alone (live; sampled at scrape time from the bounded "
+    "inter-stage queues).  A streaming task is queued for evaluate "
+    "before its chunks are decoded: it counts once its loader has "
+    "decoded as far ahead as the task's chunk queue lets it.",
     labels=["stage"])
 _M_STAGE_SECONDS = _mx.registry().counter(
     "scanner_tpu_stage_seconds_total",
@@ -119,6 +122,11 @@ _M_EVAL_SETUP_SECONDS = _mx.registry().counter(
 _M_EVAL_SETUPS = _mx.registry().counter(
     "scanner_tpu_evaluator_setups_total",
     "TaskEvaluators constructed: one per pipeline instance per run.")
+_M_LOAD_WORKERS = _mx.registry().gauge(
+    "scanner_tpu_load_workers",
+    "Loader threads of the pipeline run that started last: the count "
+    "given, or the one derived from usable cores, evaluator instances, "
+    "queue depth and tasks (evaluate.py default_load_workers).")
 _M_STAGE_WAIT = _mx.registry().counter(
     "scanner_tpu_stage_wait_seconds_total",
     "Seconds a stage thread waited on a neighbor: load = blocked "
@@ -169,6 +177,11 @@ class _StageQueue:
 
     def qsize(self) -> int:
         return len(self._items)
+
+    def count(self, pred) -> int:
+        """Queued items that `pred` holds for."""
+        with self._cond:
+            return sum(1 for item in self._items if pred(item))
 
     def put(self, item) -> bool:
         """Blocks while full.  False, and the item not queued, once the
@@ -280,6 +293,15 @@ class TaskItem:
     decode_rows: int = 0
 
 
+def _awaits_evaluator(w: TaskItem) -> bool:
+    """A queued task that waits on the evaluator alone: loaded whole,
+    or a streaming task whose loader has filled its chunk queue.  One
+    whose chunks are still being decoded is in the evaluate queue only
+    so that they can stream: a wide load stage keeps that queue full
+    of such tasks while the evaluator starves."""
+    return w.chunk_q is None or w.chunk_q.full()
+
+
 class _StatefulChain:
     """Per-job planning chain for stateful task affinity
     (PerfParams.stateful_task_affinity; reference save_coordinator
@@ -335,12 +357,17 @@ class _StatefulChain:
 
 class LocalExecutor:
     def __init__(self, db: Database, profiler: Optional[Profiler] = None,
-                 num_load_workers: int = 2, num_save_workers: int = 2,
+                 num_load_workers: Optional[int] = None,
+                 num_save_workers: int = 2,
                  pipeline_instances: int = 1, node_id: int = 0,
                  decoder_threads: int = 1):
         self.db = db
         self.profiler = profiler or Profiler()
+        # None = derived at each run (evaluate.py default_load_workers)
         self.num_load_workers = num_load_workers
+        # (loader threads, evaluator instances) the last run_pipeline
+        # started; None until one has
+        self.stage_widths: Optional[Tuple[int, int]] = None
         self.num_save_workers = num_save_workers
         self.pipeline_instances = pipeline_instances
         self.node_id = node_id
@@ -711,9 +738,11 @@ class LocalExecutor:
                 # job (SURVEY §5; merged into Profile.write_trace output)
                 from ..util.jaxprof import device_trace
                 self._last_save_end = None
+                self.stage_widths = None
                 with device_trace(prof), prof.span(
                         "run:pipeline", level=0, tasks=len(work),
-                        counter=_M_RUN_SECONDS.labels(phase="pipeline")):
+                        counter=_M_RUN_SECONDS.labels(phase="pipeline")
+                        ) as pipeline:
                     try:
                         self._run_pipeline(
                             info, work, show_progress,
@@ -724,6 +753,10 @@ class LocalExecutor:
                         # committed: the stage threads' joins and the
                         # evaluators' close()
                         joined = time.time()
+                        if self.stage_widths is not None:
+                            loaders, instances = self.stage_widths
+                            pipeline.args.update(loaders=loaders,
+                                                 instances=instances)
                         if self._last_save_end is not None:
                             _M_RUN_SECONDS.labels(phase="drain").inc(
                                 joined - self._last_save_end)
@@ -829,6 +862,7 @@ class LocalExecutor:
         _controller.ensure_started()
         if os.environ.get("SCANNER_TPU_NO_PIPELINING", "0") not in \
                 ("0", "", "false"):
+            self.stage_widths = (1, 1)  # this thread is every stage
             return self._run_serial(info, source, on_start, on_done,
                                     on_eval_done, on_task_error,
                                     evaluator_factory, close_evaluators,
@@ -851,7 +885,6 @@ class LocalExecutor:
                        for n in info.ops)
         serialize = bool(self._chains) or stateful
         n_evals = 1 if serialize else self.pipeline_instances
-        n_loaders = 1 if serialize else self.num_load_workers
         # Device-affine routing: when instances own distinct chips, each
         # gets its OWN queue and the loader assigns each task to the
         # least-loaded instance (round-robin tie-break) at enqueue time
@@ -859,7 +892,8 @@ class LocalExecutor:
         # device staging targets the chip that will evaluate the task.
         # A chained run (n_evals=1) or a single-chip host keeps today's
         # shared queue (any instance takes any task).
-        from .evaluate import assigned_device, device_label
+        from .evaluate import (assigned_device, default_load_workers,
+                               device_label)
         inst_devices = [assigned_device(i) for i in range(n_evals)]
         if n_evals > 1 and any(d is not None for d in inst_devices):
             eval_qs = [_StageQueue(qsize) for _ in range(n_evals)]
@@ -867,11 +901,17 @@ class LocalExecutor:
             eval_qs = [_StageQueue(qsize)] * n_evals
         uniq_qs = list({id(q): q for q in eval_qs}.values())
         save_q = _StageQueue(qsize)
+        n_loaders = 1 if serialize else default_load_workers(
+            self.num_load_workers, instances=n_evals, queues=len(uniq_qs),
+            qsize=qsize, tasks=total, decoder_threads=self.decoder_threads)
+        self.stage_widths = (n_loaders, n_evals)
+        _M_LOAD_WORKERS.set(n_loaders)
         # live depth gauges sample the queues at scrape time; the last
         # pipeline to start owns the gauge (concurrent pipelines in one
         # process share the process registry)
         depth_fns = {
-            "evaluate": lambda: sum(q.qsize() for q in uniq_qs),
+            "evaluate": lambda: sum(q.count(_awaits_evaluator)
+                                    for q in uniq_qs),
             "save": save_q.qsize,
         }
         for stage, fn in depth_fns.items():

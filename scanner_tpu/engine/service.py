@@ -1170,8 +1170,15 @@ class Master:
                 return self._gang_next_work_locked(bulk, wid, recs)
             if window:
                 # per-worker in-flight window: don't let one node's
-                # loaders hoard the queue while its siblings idle
-                if bulk.held.get(wid, 0) >= window and bulk.q_has_work():
+                # loaders hoard the queue while its siblings idle.  A
+                # worker sizes its window from its own cores and chips
+                # and cannot see its siblings, so the window is also
+                # held to the worker's share of the bulk: a bulk
+                # smaller than the cluster's windows still spreads
+                active = sum(1 for x in self._workers.values() if x.active)
+                share = -(-bulk.total_tasks // max(1, active))
+                if bulk.held.get(wid, 0) >= min(window, share) \
+                        and bulk.q_has_work():
                     return {"status": "wait"}
             # round-robin over jobs; a sticky (stateful-affinity) job
             # bound to a live other worker is skipped as a whole, so it
@@ -3273,7 +3280,10 @@ class Worker:
 
     def __init__(self, master_address: str, db_path: str, port: int = 0,
                  storage_type: str = "posix",
-                 num_load_workers: int = 2, num_save_workers: int = 2,
+                 # None = derived per bulk from cores, instances and queue
+                 # depth (evaluate.py default_load_workers); explicit wins
+                 num_load_workers: Optional[int] = None,
+                 num_save_workers: int = 2,
                  # None = one device-affine instance per local chip on
                  # multi-chip hosts (resolved per bulk); explicit wins
                  pipeline_instances: Optional[int] = None,
@@ -3850,7 +3860,10 @@ class Worker:
             "gang_hosts": getattr(self, "_gang_hosts", 0),
             "gang_address": getattr(self, "_gang_address", ""),
             "pipeline_instances": ex.pipeline_instances if ex else None,
-            "num_load_workers": ex.num_load_workers if ex else None,
+            # what the last pipeline started, else what was given
+            # (None = derived per run)
+            "num_load_workers": (ex.stage_widths[0] if ex.stage_widths
+                                 else ex.num_load_workers) if ex else None,
             "num_save_workers": ex.num_save_workers if ex else None,
             # the Health panel: roll-up + firing alerts (util/health.py)
             "health": _health.status_dict(),
@@ -4009,8 +4022,9 @@ class Worker:
         # tasks are released from the master's held-count by the EvalDone
         # RPC, so lagging savers can't throttle the evaluators while a
         # small window still spreads small jobs across workers
-        window = (self.executor.pipeline_instances
-                  + self.executor.num_load_workers)
+        # (one loader where no pipeline has started: a direct call)
+        loaders, _ = self.executor.stage_widths or (1, 1)
+        window = self.executor.pipeline_instances + loaders
         reply = self.master.try_call("NextWork", worker_id=self.worker_id,
                                      bulk_id=bulk_id, window=window)
         if reply is not None and not self._gen.observe(reply):
