@@ -31,7 +31,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-# the bench clip's geometry (bench.py): 600 frames of 640x480, keyint 32
+# the smoke clip: 600 frames of 640x480, keyint 32
 N_FRAMES, W, H, KEYINT = 600, 640, 480, 32
 CHAIN_ROWS = 96      # Resize -> Blur -> Histogram -> HistDiff golden chain
 POSE_ROWS = 128      # PoseDetect at its default width

@@ -1,7 +1,7 @@
 """scanner-check CLI.
 
     scanner-check [paths...]            # human output, exit 1 on findings
-    scanner-check --json                # machine output (CI, bench.py)
+    scanner-check --json                # machine output (CI)
     scanner-check --write-baseline      # accept current findings
     scanner-check --list-codes          # what the passes check
 
@@ -61,7 +61,7 @@ def all_passes(select: Optional[Sequence[str]] = None):
 def analyze(paths: Sequence[str], root: Optional[str] = None,
             select: Optional[Sequence[str]] = None
             ) -> "tuple[Project, List[Finding]]":
-    """THE run protocol, shared by the CLI, bench.py, and the tests:
+    """THE run protocol, shared by the CLI and the tests:
     build ONE Project shared by every pass family, seed findings with
     parse errors, run the (select-filtered) passes, sort."""
     project = Project(paths, root=root)

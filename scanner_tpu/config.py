@@ -42,7 +42,7 @@ def default_config() -> Dict[str, Any]:
             # decoded frames are pooled in keyframe-aligned pages and
             # reused across tasks (stencil overlap, Gather samplings,
             # hot clips) instead of re-decoding + re-staging.  On by
-            # default; SCANNER_TPU_FRAME_CACHE=0 overrides per process.
+            # default.
             "frame_cache_enabled": True,
             # per-device capacity target in MB (LRU-evicted past it; a
             # firing hbm_pressure alert shrinks it further);
@@ -55,8 +55,8 @@ def default_config() -> Dict[str, Any]:
             # whole-pipeline XLA fusion (graph/fusion.py): chains of
             # consecutive fusable device ops compile into ONE jitted
             # program per bucket, so op-boundary intermediates never
-            # materialize in HBM.  On by default; SCANNER_TPU_FUSION=0
-            # overrides per process (the staged-path A/B lever).
+            # materialize in HBM.  On by default (off = the staged
+            # path).
             "fusion_enabled": True,
             # minimum chain length the fusion planner will fuse (a
             # singleton IS the staged path; raise to bound planner
@@ -241,8 +241,7 @@ class Config:
 
     @property
     def frame_cache_enabled(self) -> bool:
-        """Paged per-device HBM frame cache (the deployment default;
-        SCANNER_TPU_FRAME_CACHE overrides per process)."""
+        """Paged per-device HBM frame cache."""
         return bool(self.config.get("perf", {}).get(
             "frame_cache_enabled", True))
 
@@ -261,9 +260,7 @@ class Config:
 
     @property
     def fusion_enabled(self) -> bool:
-        """Whole-pipeline XLA fusion of device op chains (the
-        deployment default; SCANNER_TPU_FUSION overrides per
-        process)."""
+        """Whole-pipeline XLA fusion of device op chains."""
         return bool(self.config.get("perf", {}).get("fusion_enabled",
                                                     True))
 

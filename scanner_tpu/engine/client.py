@@ -120,19 +120,16 @@ class Client:
                 memstats.set_enabled(cfg.memstats_enabled)
             memstats.set_report_top_n(cfg.memstats_report_top_n)
             # [perf] frame_cache_*: the paged HBM frame cache's
-            # deployment defaults; the SCANNER_TPU_FRAME_CACHE* env
-            # vars (read at import) win when set
+            # deployment defaults (SCANNER_TPU_FRAME_CACHE_MB, read at
+            # import, wins over frame_cache_mb)
             from .framecache import (set_capacity_mb, set_enabled,
                                      set_page_frames)
-            if not os.environ.get("SCANNER_TPU_FRAME_CACHE"):
-                set_enabled(cfg.frame_cache_enabled)
+            set_enabled(cfg.frame_cache_enabled)
             set_capacity_mb(cfg.frame_cache_mb)
             set_page_frames(cfg.frame_cache_page_frames)
-            # [perf] fusion_*: whole-pipeline XLA fusion defaults; the
-            # SCANNER_TPU_FUSION env var (read at import) wins when set
+            # [perf] fusion_*: whole-pipeline XLA fusion defaults
             from ..graph import fusion as _fusion_cfg
-            if not os.environ.get("SCANNER_TPU_FUSION"):
-                _fusion_cfg.set_enabled(cfg.fusion_enabled)
+            _fusion_cfg.set_enabled(cfg.fusion_enabled)
             _fusion_cfg.set_min_chain(cfg.fusion_min_chain)
             # [alerts] section: health/SLO engine default + user rules;
             # the SCANNER_TPU_HEALTH env var (read at import) wins

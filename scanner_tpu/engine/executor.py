@@ -392,6 +392,7 @@ class LocalExecutor:
         # when the pipeline's savers last finished a task (run:drain
         # starts there); None until one has
         self._last_save_end: Optional[float] = None
+        self._save_end_lock = threading.Lock()
         # frame-cache source identity: table ids are per-database and
         # restart at 0 (and a database re-created at the same root
         # would restart them too), so pages are keyed under a
@@ -671,7 +672,7 @@ class LocalExecutor:
 
     # -- tracing glue (util/tracing.py) --------------------------------
 
-    def _task_trace_begin(self, w: TaskItem) -> None:
+    def _task_trace_begin(self, w: TaskItem, **attrs) -> None:
         """Open the task's span (idempotent): child of its trace context
         — the job root span locally, the master's assign span in
         cluster mode.  No context = no span (tracing off or untraced
@@ -679,7 +680,8 @@ class LocalExecutor:
         if w.trace_span is None and w.trace_ctx is not None:
             w.trace_span = _tr.open_span(
                 self.tracer, "task", parent=w.trace_ctx,
-                job=w.job.job_idx, task=w.task_idx, attempt=w.attempt)
+                job=w.job.job_idx, task=w.task_idx, attempt=w.attempt,
+                **attrs)
 
     def _task_scope(self, w: TaskItem):
         """Resume the task span on the calling stage thread, so the
@@ -737,7 +739,8 @@ class LocalExecutor:
                 # level >= 2: capture the XLA device timeline around the
                 # job (SURVEY §5; merged into Profile.write_trace output)
                 from ..util.jaxprof import device_trace
-                self._last_save_end = None
+                with self._save_end_lock:
+                    self._last_save_end = None
                 self.stage_widths = None
                 with device_trace(prof), prof.span(
                         "run:pipeline", level=0, tasks=len(work),
@@ -933,18 +936,7 @@ class LocalExecutor:
         def task_failed(w: TaskItem, e: BaseException) -> None:
             """Route one task's failure; abort unless the error handler
             accepts it (cluster mode reports FailedWork and moves on)."""
-            if w.trace_span is not None:
-                w.trace_span.add_event("error", type=type(e).__name__,
-                                       message=str(e)[:200])
-            self._task_trace_end(w, status="error")
-            # drop the failed attempt's staged columns/results NOW: a
-            # task requeued after memory pressure must not keep holding
-            # the very device buffers that caused it (the ledger
-            # releases as the arrays are collected; cache pins likewise
-            # must not outlive the attempt)
-            w.elements = None
-            w.results = None
-            self._release_cache(w)
+            self._fail_task(w, e)
             if on_task_error is not None and on_task_error(w, e):
                 return
             _log.exception("task (%d,%d) failed; aborting pipeline",
@@ -1023,14 +1015,6 @@ class LocalExecutor:
             except BaseException as e:  # noqa: BLE001
                 record_err(e)
 
-        def make_evaluator(idx: int, skip_fetch: bool) -> TaskEvaluator:
-            if evaluator_factory is not None:
-                return evaluator_factory(idx, skip_fetch)
-            return TaskEvaluator(info, self.profiler,
-                                 skip_fetch_resources=skip_fetch,
-                                 precompile=precompile,
-                                 instance=idx, instances=n_evals)
-
         def evaluator(evaluator_idx: int):
             te = None
             my_q = eval_qs[evaluator_idx]
@@ -1042,11 +1026,10 @@ class LocalExecutor:
                 # the rest only setup (reference evaluate_worker.cpp:488-534)
                 if evaluator_idx > 0:
                     fetch_done.wait()
-                with self.profiler.span(
-                        "evaluate:setup", level=0,
-                        counter=_M_EVAL_SETUP_SECONDS, device=dev_lbl):
-                    te = make_evaluator(evaluator_idx, evaluator_idx > 0)
-                _M_EVAL_SETUPS.inc()
+                te = self._open_evaluator(
+                    info, evaluator_idx, n_evals,
+                    skip_fetch=evaluator_idx > 0,
+                    factory=evaluator_factory, precompile=precompile)
                 if evaluator_idx == 0:
                     fetch_done.set()
                 while True:
@@ -1055,43 +1038,11 @@ class LocalExecutor:
                     if w is None:
                         break
                     try:
-                        if on_start is not None and on_start(w) is False:
-                            if w.chunk_abort is not None:
-                                w.chunk_abort.set()  # unblock the loader
-                            # leases the producing loader adds after
-                            # this are released by its abort path
-                            self._release_cache(w)
-                            self._task_trace_end(w, status="revoked")
+                        chunks = None if w.chunk_q is None \
+                            else self._queued_chunks(w, stop)
+                        if not self._evaluate_stage(info, te, w, fb_tls,
+                                                    chunks, on_start):
                             continue  # revoked attempt: drop silently
-                        t0 = time.time()
-                        lbl = device_label(w.device)
-                        # open seconds accrue while the task runs: a
-                        # long task never lands in one health sample
-                        with _M_EVAL_OPEN.labels(device=lbl).timing(), \
-                                self._task_scope(w), \
-                                self.profiler.span("evaluate", level=0,
-                                                   task=w.task_idx,
-                                                   job=w.job.job_idx,
-                                                   device=lbl):
-                            if w.chunk_q is not None:
-                                w.results = self._consume_chunks(
-                                    info, te, w, fb_tls, stop=stop)
-                            else:
-                                w.results = self._evaluate_with_fallback(
-                                    info, te, w, fb_tls)
-                        # start the sink d2h now: the copy rides under
-                        # the NEXT task's evaluation instead of blocking
-                        # the saver
-                        self._prefetch_results(w)
-                        dt = time.time() - t0
-                        _M_STAGE_SECONDS.labels(stage="evaluate").inc(dt)
-                        _M_STAGE_TASKS.labels(stage="evaluate").inc()
-                        _M_DEV_TASKS.labels(device=lbl).inc()
-                        w.elements = None
-                        # evaluation is done with the cached pages:
-                        # unpin them (the sink batches are the task's
-                        # own arrays, never cache pages)
-                        self._release_cache(w)
                     except Exception as e:  # noqa: BLE001
                         task_failed(w, e)
                         continue
@@ -1103,10 +1054,7 @@ class LocalExecutor:
                 record_err(e)
             finally:
                 fetch_done.set()  # never leave siblings waiting
-                for auto in getattr(fb_tls, "automata", {}).values():
-                    auto.close()
-                if te is not None and close_evaluators:
-                    te.close()
+                self._close_evaluator(te, close_evaluators, fb_tls)
 
         done_count = [0]
         done_lock = threading.Lock()
@@ -1118,27 +1066,7 @@ class LocalExecutor:
                     if w is None:
                         break
                     try:
-                        t0 = time.time()
-                        with self._task_scope(w):
-                            with self.profiler.span("save", level=0,
-                                                    task=w.task_idx,
-                                                    job=w.job.job_idx):
-                                self._save_task(info, w)
-                        t_saved = time.time()
-                        _M_STAGE_SECONDS.labels(stage="save").inc(
-                            t_saved - t0)
-                        _M_STAGE_TASKS.labels(stage="save").inc()
-                        with done_lock:
-                            # savers finish in any order: keep the latest
-                            self._last_save_end = max(
-                                self._last_save_end or 0.0, t_saved)
-                        # close the span BEFORE on_done: the cluster
-                        # worker's completion hook ships spans then sends
-                        # FinishedWork, so the master holds this task's
-                        # full chain before the bulk can finish
-                        self._task_trace_end(w)
-                        if on_done is not None:
-                            on_done(w)
+                        self.save_results(info, w, on_done)
                     except Exception as e:  # noqa: BLE001
                         task_failed(w, e)
                         continue
@@ -1192,17 +1120,24 @@ class LocalExecutor:
                     total: int,
                     precompile: Optional[Tuple[int, int, int]] = None
                     ) -> int:
-        """The NO_PIPELINING path: every stage inline on this thread."""
+        """The NO_PIPELINING path: every stage inline on this thread.
+        A task failure is routed as the threaded path routes it (load /
+        evaluate(+on_start) / save(+on_done) failures are the task's and
+        on_task_error may absorb them; an on_eval_done failure is the
+        pipeline's), but what is not absorbed raises from here."""
         import types
-        from .evaluate import device_label
         tls = types.SimpleNamespace()
         fb_tls = types.SimpleNamespace()  # carry-miss fallback decoders
-        if evaluator_factory is not None:
-            te = evaluator_factory(0, False)
-        else:
-            te = TaskEvaluator(info, self.profiler, precompile=precompile)
+        te = None
         done = 0
+
+        def absorbed(w: TaskItem, e: BaseException) -> bool:
+            self._fail_task(w, e)
+            return on_task_error is not None and on_task_error(w, e)
+
         try:
+            te = self._open_evaluator(info, factory=evaluator_factory,
+                                      precompile=precompile)
             while True:
                 w = source()
                 if w is None:
@@ -1213,99 +1148,39 @@ class LocalExecutor:
                 # single inline instance: staging still targets its
                 # assigned chip so serial runs match the threaded path
                 w.device = te.device
-                # Error routing mirrors the threaded path stage by stage:
-                # load / evaluate(+on_start) / save(+on_done) failures are
-                # task failures (on_task_error may absorb them), while an
-                # on_eval_done failure — cluster bookkeeping RPC, not task
-                # work — is a pipeline error and propagates (the threaded
-                # evaluator calls it outside its per-task try).
                 self._task_trace_begin(w)
                 try:
                     with self._task_scope(w):
                         self.load_task(info, w, tls)
-                    if on_start is not None and on_start(w) is False:
-                        self._release_cache(w)
-                        self._task_trace_end(w, status="revoked")
+                    # a streaming task decodes inline on this one thread;
+                    # the carry-miss fallback loads through fb_tls — NOT
+                    # tls, whose decoder sessions are suspended mid-run
+                    # and must not be reset
+                    chunks = None if w.chunk_plans is None \
+                        else self._iter_chunk_items(info, w, tls)
+                    if not self._evaluate_stage(info, te, w, fb_tls,
+                                                chunks, on_start):
                         continue  # revoked attempt
-                    t0 = time.time()
-                    lbl = device_label(w.device)
-                    with _M_EVAL_OPEN.labels(device=lbl).timing(), \
-                            self._task_scope(w), \
-                            self.profiler.span("evaluate", level=0,
-                                               task=w.task_idx,
-                                               job=w.job.job_idx,
-                                               device=lbl):
-                        if w.chunk_plans is not None:
-                            # inline streaming on this one thread; the
-                            # carry-miss fallback loads through fb_tls —
-                            # NOT tls, whose decoder sessions are
-                            # suspended mid-run and must not be reset
-                            w.results = self._consume_iter(
-                                info, te, w,
-                                self._iter_chunk_items(info, w, tls),
-                                fb_tls)
-                        else:
-                            w.results = self._evaluate_with_fallback(
-                                info, te, w, fb_tls)
-                    self._prefetch_results(w)
-                    dt = time.time() - t0
-                    _M_STAGE_SECONDS.labels(stage="evaluate").inc(dt)
-                    _M_STAGE_TASKS.labels(stage="evaluate").inc()
-                    _M_DEV_TASKS.labels(device=lbl).inc()
-                    w.elements = None
-                    self._release_cache(w)
                 except Exception as e:  # noqa: BLE001
-                    if w.trace_span is not None:
-                        w.trace_span.add_event(
-                            "error", type=type(e).__name__,
-                            message=str(e)[:200])
-                    self._task_trace_end(w, status="error")
-                    w.elements = None
-                    w.results = None
-                    self._release_cache(w)
-                    if on_task_error is not None and on_task_error(w, e):
+                    if absorbed(w, e):
                         continue
                     raise
                 if on_eval_done is not None:
                     on_eval_done(w)
                 try:
-                    t0 = time.time()
-                    with self._task_scope(w):
-                        with self.profiler.span("save", level=0,
-                                                task=w.task_idx,
-                                                job=w.job.job_idx):
-                            self._save_task(info, w)
-                    _M_STAGE_SECONDS.labels(stage="save").inc(
-                        time.time() - t0)
-                    _M_STAGE_TASKS.labels(stage="save").inc()
-                    self._task_trace_end(w)
-                    if on_done is not None:
-                        on_done(w)
+                    self.save_results(info, w, on_done)
                 except Exception as e:  # noqa: BLE001
-                    if w.trace_span is not None:
-                        w.trace_span.add_event(
-                            "error", type=type(e).__name__,
-                            message=str(e)[:200])
-                    self._task_trace_end(w, status="error")
-                    w.elements = None
-                    w.results = None
-                    if on_task_error is not None and on_task_error(w, e):
+                    if absorbed(w, e):
                         continue
                     raise
                 done += 1
                 if show_progress:
                     print(f"\rtasks {done}/{total}", end="", flush=True)
         finally:
-            for ns in (tls, fb_tls):
-                for auto in getattr(ns, "automata", {}).values():
-                    auto.close()
-            if close_evaluators:
-                te.close()
+            self._close_evaluator(te, close_evaluators, tls, fb_tls)
         if show_progress:
             print()
         return done
-
-    # ------------------------------------------------------------------
 
     def run_single_task(self, info: A.GraphInfo, w: TaskItem,
                         save: bool = True,
@@ -1323,53 +1198,152 @@ class LocalExecutor:
         import types
         tls = types.SimpleNamespace()
         fb_tls = types.SimpleNamespace()
-        if w.trace_span is None and w.trace_ctx is not None:
-            w.trace_span = _tr.open_span(
-                self.tracer, "task", parent=w.trace_ctx,
-                job=w.job.job_idx, task=w.task_idx, attempt=w.attempt,
-                **(span_attrs or {}))
+        self._task_trace_begin(w, **(span_attrs or {}))
         te = None
         try:
             with self._task_scope(w):
                 self.load_task(info, w, tls)
-            te = TaskEvaluator(info, self.profiler)
+            te = self._open_evaluator(info)
             w.device = te.device
-            with self._task_scope(w), \
-                    self.profiler.span("evaluate", level=0,
-                                       task=w.task_idx,
-                                       job=w.job.job_idx):
-                w.results = self._evaluate_with_fallback(
-                    info, te, w, fb_tls)
-            w.elements = None
-            self._release_cache(w)
+            self._evaluate_stage(info, te, w, fb_tls)
             if save:
                 self.save_results(info, w)
             return w
         except Exception as e:  # noqa: BLE001
-            if w.trace_span is not None:
-                w.trace_span.add_event("error", type=type(e).__name__,
-                                       message=str(e)[:200])
-            self._task_trace_end(w, status="error")
-            w.elements = None
-            w.results = None
-            self._release_cache(w)
+            self._fail_task(w, e)
             raise
         finally:
-            for auto in getattr(tls, "automata", {}).values():
-                auto.close()
-            if te is not None:
-                te.close()
+            self._close_evaluator(te, True, tls, fb_tls)
 
-    def save_results(self, info: A.GraphInfo, w: TaskItem) -> None:
-        """Persist a task's evaluated results and close its span — the
-        deferred half of `run_single_task(save=False)`, run by a gang's
-        single writer (member 0) only after the collective agreement
-        check passed."""
+    # ------------------------------------------------------------------
+    # The stage bodies of one task.  Every driver of a task runs these:
+    # the stage threads of run_pipeline, the one thread of _run_serial,
+    # the gang member (run_single_task).  The drivers differ in how a
+    # task reaches a stage and in where its failure goes, not in what
+    # the stage does and records.
+    # ------------------------------------------------------------------
+
+    def _open_evaluator(self, info: A.GraphInfo, idx: int = 0,
+                        instances: int = 1, skip_fetch: bool = False,
+                        factory=None,
+                        precompile: Optional[Tuple[int, int, int]] = None
+                        ) -> TaskEvaluator:
+        """Pipeline instance `idx`'s evaluator, made (or handed out by
+        `factory(idx, skip_fetch)`, which may keep it across pipeline
+        entries) under the `evaluate:setup` span."""
+        from .evaluate import assigned_device, device_label
+        with self.profiler.span(
+                "evaluate:setup", level=0, counter=_M_EVAL_SETUP_SECONDS,
+                device=device_label(assigned_device(idx))):
+            if factory is not None:
+                te = factory(idx, skip_fetch)
+            else:
+                te = TaskEvaluator(info, self.profiler,
+                                   skip_fetch_resources=skip_fetch,
+                                   precompile=precompile,
+                                   instance=idx, instances=instances)
+        _M_EVAL_SETUPS.inc()
+        return te
+
+    @staticmethod
+    def _close_evaluator(te: Optional[TaskEvaluator], close: bool,
+                         *decoders) -> None:
+        """The end of an evaluator's driver: the decoder handles its
+        thread held (`decoders`: the namespaces they were cached in) go,
+        and the evaluator itself unless whoever made it keeps it."""
+        for ns in decoders:
+            for auto in getattr(ns, "automata", {}).values():
+                auto.close()
+        if te is not None and close:
+            te.close()
+
+    def _evaluate_stage(self, info: A.GraphInfo, te: TaskEvaluator,
+                        w: TaskItem, fb_tls, chunks=None,
+                        on_start=None) -> bool:
+        """The evaluate stage of one loaded task, on the calling thread.
+        `chunks` is how a streaming task's (plan, elements) arrive —
+        `_queued_chunks` from its loader, `_iter_chunk_items` decoded
+        inline — and None for a task loaded whole.  False: `on_start`
+        revoked the attempt and nothing ran.  What evaluation raises is
+        the caller's to route (`_fail_task` is the cleanup)."""
+        from .evaluate import device_label
+        if on_start is not None and on_start(w) is False:
+            if w.chunk_abort is not None:
+                w.chunk_abort.set()  # unblock the loader
+            # leases the producing loader adds after this are released
+            # by its abort path
+            self._release_cache(w)
+            self._task_trace_end(w, status="revoked")
+            return False
+        t0 = time.time()
+        lbl = device_label(w.device)
+        # open seconds accrue while the task runs: a long task never
+        # lands in one health sample
+        with _M_EVAL_OPEN.labels(device=lbl).timing(), \
+                self._task_scope(w), \
+                self.profiler.span("evaluate", level=0, task=w.task_idx,
+                                   job=w.job.job_idx, device=lbl):
+            if chunks is not None:
+                try:
+                    w.results = self._consume_iter(info, te, w, chunks,
+                                                   fb_tls)
+                except BaseException:
+                    w.chunk_abort.set()  # any failure stops the producer
+                    raise
+            else:
+                w.results = self._evaluate_with_fallback(info, te, w,
+                                                         fb_tls)
+        # start the sink d2h now: the copy rides under the NEXT task's
+        # evaluation instead of blocking the saver
+        self._prefetch_results(w)
+        dt = time.time() - t0
+        _M_STAGE_SECONDS.labels(stage="evaluate").inc(dt)
+        _M_STAGE_TASKS.labels(stage="evaluate").inc()
+        _M_DEV_TASKS.labels(device=lbl).inc()
+        w.elements = None
+        # evaluation is done with the cached pages: unpin them (the sink
+        # batches are the task's own arrays, never cache pages)
+        self._release_cache(w)
+        return True
+
+    def save_results(self, info: A.GraphInfo, w: TaskItem,
+                     on_done=None) -> None:
+        """The save stage of one evaluated task: persist its results and
+        close its span.  Also the deferred half of
+        `run_single_task(save=False)`, run by a gang's single writer
+        (member 0) only after the collective agreement check passed."""
+        t0 = time.time()
         with self._task_scope(w):
             with self.profiler.span("save", level=0, task=w.task_idx,
                                     job=w.job.job_idx):
                 self._save_task(info, w)
+        t_saved = time.time()
+        _M_STAGE_SECONDS.labels(stage="save").inc(t_saved - t0)
+        _M_STAGE_TASKS.labels(stage="save").inc()
+        with self._save_end_lock:
+            # savers finish in any order: keep the latest
+            self._last_save_end = max(self._last_save_end or 0.0, t_saved)
+        # close the span BEFORE on_done: the cluster worker's completion
+        # hook ships spans then sends FinishedWork, so the master holds
+        # this task's full chain before the bulk can finish
         self._task_trace_end(w)
+        if on_done is not None:
+            on_done(w)
+
+    def _fail_task(self, w: TaskItem, e: BaseException) -> None:
+        """What a failed attempt gets from whoever drove it: the error
+        on its span, the span closed, and nothing of it left held.  Its
+        staged columns and results go NOW: a task requeued after memory
+        pressure must not keep holding the very device buffers that
+        caused it (the ledger releases as the arrays are collected),
+        and cache pins must not outlive the attempt."""
+        if w.trace_span is not None:
+            w.trace_span.add_event("error", type=type(e).__name__,
+                                   message=str(e)[:200])
+        self._task_trace_end(w, status="error")
+        w.elements = None
+        w.results = None
+        self._release_cache(w)
 
     # ------------------------------------------------------------------
     # Work-packet streaming (PerfParams.stream_work_packets)
@@ -1571,8 +1545,7 @@ class LocalExecutor:
     def _consume_iter(self, info: A.GraphInfo, te, w: TaskItem,
                       chunk_iter, fb_tls) -> Dict[int, ColumnBatch]:
         """Execute (plan, elements) chunks from any iterator; merge
-        per-sink results in row order (shared by the threaded queue
-        consumer and the serial NO_PIPELINING path)."""
+        per-sink results in row order."""
         if _faults.ACTIVE:
             _faults.inject("pipeline.eval",
                            detail=f"task={w.job.job_idx},{w.task_idx}")
@@ -1586,39 +1559,29 @@ class LocalExecutor:
         self.profiler.count("stream_chunks", n)
         return {sid: concat_batches(lst) for sid, lst in parts.items()}
 
-    def _consume_chunks(self, info: A.GraphInfo, te, w: TaskItem, fb_tls,
-                        stop=None) -> Dict[int, ColumnBatch]:
-        """Evaluator-side: execute chunks as they arrive over the
-        producer queue.  Any failure aborts the producer."""
-
-        def from_queue():
+    def _queued_chunks(self, w: TaskItem, stop=None):
+        """Evaluator-side: a streaming task's chunks as they arrive over
+        its producer queue."""
+        while True:
+            t0 = time.time()
             while True:
-                t0 = time.time()
-                while True:
-                    try:
-                        item = w.chunk_q.get(timeout=0.25)
-                        break
-                    except queue.Empty:
-                        if stop is not None and stop.is_set():
-                            raise JobException(
-                                "pipeline stopped during streaming task")
-                # starvation attribution: time the evaluator spent
-                # waiting on the loader's chunk production (decode
-                # slower than compute shows up here, not as inflated
-                # kernel spans)
-                self._note_wait("evaluate:chunk_wait", _M_CHUNK_WAIT, t0,
-                                task=w.task_idx, job=w.job.job_idx)
-                if item is _CHUNK_DONE:
-                    return
-                if isinstance(item, tuple) and item[0] is _CHUNK_ERR:
-                    raise item[1]
-                yield item
-
-        try:
-            return self._consume_iter(info, te, w, from_queue(), fb_tls)
-        except BaseException:
-            w.chunk_abort.set()
-            raise
+                try:
+                    item = w.chunk_q.get(timeout=0.25)
+                    break
+                except queue.Empty:
+                    if stop is not None and stop.is_set():
+                        raise JobException(
+                            "pipeline stopped during streaming task")
+            # starvation attribution: time the evaluator spent waiting
+            # on the loader's chunk production (decode slower than
+            # compute shows up here, not as inflated kernel spans)
+            self._note_wait("evaluate:chunk_wait", _M_CHUNK_WAIT, t0,
+                            task=w.task_idx, job=w.job.job_idx)
+            if item is _CHUNK_DONE:
+                return
+            if isinstance(item, tuple) and item[0] is _CHUNK_ERR:
+                raise item[1]
+            yield item
 
     def _execute_chunk(self, info: A.GraphInfo, te, w: TaskItem, plan,
                        elements, fb_tls) -> Dict[int, ColumnBatch]:
@@ -1931,8 +1894,7 @@ class LocalExecutor:
     def _cache_eligible(self, info: A.GraphInfo, node_id: int) -> bool:
         """Frame-cache eligibility for one video column: the cache is
         an HBM pool, so only device-staged columns qualify — and only
-        when the kill switch is up (SCANNER_TPU_FRAME_CACHE=0 /
-        [perf] frame_cache_enabled)."""
+        when the kill switch is up ([perf] frame_cache_enabled)."""
         from .evaluate import _device_staging_enabled
         return _fc.enabled() and _device_staging_enabled() \
             and self._column_device_bound(info, node_id)

@@ -42,7 +42,7 @@ Design points:
     evicts down *before* OOM strikes a task
     (``scanner_tpu_framecache_pressure_shrinks_total``).
 
-``SCANNER_TPU_FRAME_CACHE=0`` is the kill switch / A/B lever;
+``set_enabled(False)`` is the kill switch / A/B lever;
 ``SCANNER_TPU_FRAME_CACHE_MB`` overrides the per-device capacity.  The
 ``[perf] frame_cache_*`` config keys carry deployment defaults (see
 docs/guide.md); docs/observability.md §Frame cache catalogs the series
@@ -130,9 +130,7 @@ _M_SHRINKS = _mx.registry().counter(
 
 # -- knobs ------------------------------------------------------------------
 
-# same env semantics as SCANNER_TPU_MEMSTATS (one parser, no drift);
-# SCANNER_TPU_FRAME_CACHE=0 is the A/B kill switch
-_ENABLED = _tracing._env_on("SCANNER_TPU_FRAME_CACHE")
+_ENABLED = True
 
 
 def enabled() -> bool:
@@ -140,9 +138,8 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> None:
-    """Programmatic override ([perf] frame_cache_enabled config key,
-    tests, bench A/B); the SCANNER_TPU_FRAME_CACHE env var is read at
-    import and wins when set (call sites guard on it)."""
+    """The kill switch ([perf] frame_cache_enabled config key, tests'
+    cache-off A/B)."""
     global _ENABLED
     _ENABLED = bool(on)
 

@@ -36,7 +36,7 @@ The execution half — ``FusedKernelInstance`` composing the member
 ``execute_traced`` bodies into one jitted program per bucket — lives in
 engine/evaluate.py.
 
-``SCANNER_TPU_FUSION=0`` is the kill switch / A/B lever; the ``[perf]
+``set_enabled(False)`` is the kill switch / A/B lever; the ``[perf]
 fusion_enabled`` / ``fusion_min_chain`` config keys carry deployment
 defaults (docs/guide.md).  docs/observability.md §Fusion catalogs the
 series below (scanner-check SC317 pins both contracts).
@@ -49,7 +49,6 @@ from typing import Callable, Dict, List, Optional
 
 from ..common import DeviceType
 from ..util import metrics as _mx
-from ..util import tracing as _tracing
 from ..util.log import get_logger
 from . import ops as O
 
@@ -97,9 +96,7 @@ _M_BYTES_SAVED = _mx.registry().counter(
 
 # -- knobs ------------------------------------------------------------------
 
-# same env semantics as SCANNER_TPU_FRAME_CACHE (one parser, no drift);
-# SCANNER_TPU_FUSION=0 is the A/B kill switch
-_ENABLED = _tracing._env_on("SCANNER_TPU_FUSION")
+_ENABLED = True
 
 
 def enabled() -> bool:
@@ -107,9 +104,8 @@ def enabled() -> bool:
 
 
 def set_enabled(on: bool) -> None:
-    """Programmatic override ([perf] fusion_enabled config key, tests,
-    bench A/B); the SCANNER_TPU_FUSION env var is read at import and
-    wins when set (call sites guard on it)."""
+    """The kill switch ([perf] fusion_enabled config key, the tests'
+    and chip_smoke.py's fused-vs-staged A/B)."""
     global _ENABLED
     _ENABLED = bool(on)
 

@@ -36,8 +36,7 @@ efficiency plane, two halves:
     which is the question straggler analytics could not answer.
 
 Consumers: the /statusz Efficiency panel, ``scanner_top`` EFF%/bound
-columns and compile-cache hit rate, the bench.py ``op_efficiency``
-digest in BENCH_DETAIL.json, and ``tools/scanner_cost.py``.
+columns and compile-cache hit rate, and ``tools/scanner_cost.py``.
 
 Knobs: ``SCANNER_TPU_COSTSTATS=0`` disables both halves (the dispatch
 sites then skip descriptor/ledger work entirely);
@@ -664,8 +663,8 @@ def record_op_call(op: str, device: str, bucket: int, rows: int,
 
 def op_efficiency() -> List[Dict[str, Any]]:
     """The roofline table: one row per (op, device, bucket) with
-    measured rates, the bound classification and EFF% — the digest
-    bench.py banks and /statusz / scanner_cost render."""
+    measured rates, the bound classification and EFF% — what /statusz
+    and scanner_cost render."""
     with _op_lock:
         items = sorted(_op_stats.items())
     out = []

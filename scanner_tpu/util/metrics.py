@@ -430,8 +430,8 @@ def labeled_samples(snapshot: Dict[str, dict], series: str
                     ) -> Dict[str, float]:
     """Flatten one series of a snapshot to {sorted-label-json: value}.
     The stable keying the per-device utilization digests compare across
-    runs and processes (bench.py `multichip`, the
-    tests/test_multichip.py equivalence suite): label order never leaks
+    runs and processes (the tests/test_multichip.py equivalence
+    suite): label order never leaks
     into the key, so `{"device": "tpu:3", "op": "Histogram"}` is the
     same sample wherever it was produced."""
     return {json.dumps(s["labels"], sort_keys=True): s["value"]
@@ -482,8 +482,7 @@ def snapshot_histogram_quantiles(snapshot: Dict[str, dict], series: str,
                                  ) -> Dict[str, Any]:
     """Aggregate every sample of a histogram series in a (plain or
     merged) snapshot and estimate quantiles: {"count", "mean_s",
-    "p50_s", ...}, or {} when the series is absent or empty.  The
-    digest shape bench.py banks and tools consume."""
+    "p50_s", ...}, or {} when the series is absent or empty."""
     e = snapshot.get(series)
     if not e or not e.get("samples"):
         return {}

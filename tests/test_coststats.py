@@ -408,52 +408,6 @@ def test_cluster_compile_report_rpc_and_surfaces(eff_cluster):
 
 
 # ---------------------------------------------------------------------------
-# bench_history: the per-direction baseline gate
-# ---------------------------------------------------------------------------
-
-def test_bench_history_baseline_gate(tmp_path):
-    """bench_history --write-baselines banks the stable
-    baseline_metrics keys; a later round that regresses a metric
-    against its declared direction beyond the threshold exits 1."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_history_under_test",
-        os.path.join(os.path.dirname(HERE), "tools", "bench_history.py"))
-    bh = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bh)
-
-    def write_round(p99, eff, hit):
-        with open(tmp_path / "BENCH_r01.json", "w") as f:
-            json.dump({"parsed": {"metric": "m", "value": 10.0}}, f)
-        with open(tmp_path / "BENCH_DETAIL.json", "w") as f:
-            json.dump([{"config": "baseline_metrics", "metrics": {
-                "task_latency_p99_s": {"value": p99, "better": "lower"},
-                "op_efficiency_mean": {"value": eff, "better": "higher"},
-                "compile_cache_hit_rate": {"value": hit,
-                                           "better": "higher"},
-            }}], f)
-
-    write_round(p99=2.0, eff=0.5, hit=0.9)
-    assert bh.main(["--dir", str(tmp_path), "--write-baselines"]) == 0
-    base = bh.load_baselines(str(tmp_path))
-    assert base["task_latency_p99_s"]["value"] == 2.0
-    # same numbers: clean
-    assert bh.main(["--dir", str(tmp_path)]) == 0
-    # latency p99 doubles (lower-is-better): gate trips
-    write_round(p99=4.0, eff=0.5, hit=0.9)
-    assert bh.main(["--dir", str(tmp_path)]) == 1
-    # efficiency halves (higher-is-better): gate trips
-    write_round(p99=2.0, eff=0.2, hit=0.9)
-    assert bh.main(["--dir", str(tmp_path)]) == 1
-    # a metric going unmeasured (None) must NOT page
-    write_round(p99=2.0, eff=None, hit=None)
-    assert bh.main(["--dir", str(tmp_path)]) == 0
-    # improvements never page
-    write_round(p99=1.0, eff=0.9, hit=1.0)
-    assert bh.main(["--dir", str(tmp_path)]) == 0
-
-
-# ---------------------------------------------------------------------------
 # acceptance e2e: warm-up ladder ledger on a virtual multi-device host
 # ---------------------------------------------------------------------------
 

@@ -16,9 +16,7 @@ Three layers:
 """
 
 import json
-import os
 import struct
-import subprocess
 import sys
 import threading
 import time
@@ -40,8 +38,6 @@ from scanner_tpu.util.metrics import (MetricsRegistry, MetricsServer,
 
 # test kernels travel to worker subprocesses inside the job spec
 cloudpickle.register_pickle_by_value(sys.modules[__name__])
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 N_ROWS = 48
 
@@ -853,51 +849,3 @@ def test_job_status_and_statusz_carry_health(health_cluster):
     h = sc.health()
     assert set(h) >= {"status", "reasons", "firing", "nodes"}
     assert "master" in h["nodes"]
-
-
-def test_bench_history_trajectory_and_regression(tmp_path):
-    """The checked-in BENCH_r01..r02 trajectory prints and exits 0; a
-    synthetic same-source regression exits 1."""
-    tool = os.path.join(REPO, "tools", "bench_history.py")
-    r = subprocess.run([sys.executable, tool, "--dir", REPO],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "2 rounds" in r.stdout
-    assert "histogram" in r.stdout
-
-    def write_round(n, value, source=None):
-        parsed = {"metric": "m_x", "value": value,
-                  "unit": "frames/sec/chip"}
-        if source:
-            parsed["source"] = source
-        with open(os.path.join(str(tmp_path),
-                               f"BENCH_r{n:02d}.json"), "w") as f:
-            json.dump({"n": n, "rc": 0, "parsed": parsed}, f)
-
-    write_round(1, 100.0)
-    write_round(2, 50.0)               # 50% drop, same source
-    r = subprocess.run([sys.executable, tool, "--dir", str(tmp_path)],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 1
-    assert "REGRESSIONS" in r.stdout
-
-    # a source change resets the baseline: no regression
-    write_round(3, 20.0, source="other_machine")
-    r = subprocess.run([sys.executable, tool, "--dir", str(tmp_path)],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 0, r.stdout
-
-    # --json view
-    r = subprocess.run([sys.executable, tool, "--dir", str(tmp_path),
-                        "--json"], capture_output=True, text=True,
-                       timeout=60)
-    doc = json.loads(r.stdout)
-    assert doc["rounds"] == [1, 2, 3]
-    assert "m_x" in doc["metrics"]
-
-    # empty dir -> exit 2
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    r = subprocess.run([sys.executable, tool, "--dir", str(empty)],
-                       capture_output=True, text=True, timeout=60)
-    assert r.returncode == 2

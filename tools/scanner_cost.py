@@ -13,9 +13,8 @@ GetCompileLedger RPC and renders, per node,
     python tools/scanner_cost.py --master localhost:5000
     python tools/scanner_cost.py --master localhost:5000 --ledger 20
     python tools/scanner_cost.py --master localhost:5000 --json
-    python tools/scanner_cost.py --detail BENCH_DETAIL.json   # offline
 
-Exit codes: 0 ok, 2 master unreachable / detail file unreadable.
+Exit codes: 0 ok, 2 master unreachable.
 """
 
 import argparse
@@ -99,28 +98,12 @@ def render(nodes: dict, ledger_n: int) -> str:
     return "\n".join(lines).rstrip() or "no efficiency data recorded"
 
 
-def detail_nodes(path: str):
-    """Offline mode: reshape a BENCH_DETAIL.json op_efficiency digest
-    into the per-node report shape the renderer expects."""
-    with open(path) as f:
-        detail = json.load(f)
-    for d in detail if isinstance(detail, list) else []:
-        if isinstance(d, dict) and d.get("config") == "op_efficiency":
-            return {"bench": {"summary": d.get("compile") or {},
-                              "op_efficiency": d.get("ops") or [],
-                              "ledger": []}}
-    return None
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="per-op roofline efficiency + XLA compile ledger "
                     "for a scanner_tpu cluster")
     ap.add_argument("--master", default=None,
                     help="master address host:port")
-    ap.add_argument("--detail", default=None,
-                    help="offline: read a BENCH_DETAIL.json "
-                         "op_efficiency digest instead of a cluster")
     ap.add_argument("--ledger", type=int, default=10,
                     help="newest compile-ledger entries to show per "
                          "node (default %(default)s)")
@@ -128,32 +111,20 @@ def main(argv=None) -> int:
                     help="machine-readable output")
     args = ap.parse_args(argv)
 
-    if args.detail:
-        try:
-            nodes = detail_nodes(args.detail)
-        except (OSError, ValueError) as e:
-            print(f"scanner-cost: cannot read {args.detail}: {e}",
-                  file=sys.stderr)
-            return 2
-        if nodes is None:
-            print(f"scanner-cost: no op_efficiency digest in "
-                  f"{args.detail}", file=sys.stderr)
-            return 2
-    else:
-        from scanner_tpu.engine.rpc import RpcClient
-        from scanner_tpu.engine.service import MASTER_SERVICE
+    from scanner_tpu.engine.rpc import RpcClient
+    from scanner_tpu.engine.service import MASTER_SERVICE
 
-        master = args.master or "localhost:5000"
-        client = RpcClient(master, MASTER_SERVICE, timeout=10.0)
-        try:
-            reply = client.try_call("GetCompileLedger", retries=1)
-        finally:
-            client.close()
-        if reply is None or "nodes" not in reply:
-            print(f"scanner-cost: master {master} unreachable",
-                  file=sys.stderr)
-            return 2
-        nodes = reply["nodes"]
+    master = args.master or "localhost:5000"
+    client = RpcClient(master, MASTER_SERVICE, timeout=10.0)
+    try:
+        reply = client.try_call("GetCompileLedger", retries=1)
+    finally:
+        client.close()
+    if reply is None or "nodes" not in reply:
+        print(f"scanner-cost: master {master} unreachable",
+              file=sys.stderr)
+        return 2
+    nodes = reply["nodes"]
 
     if args.json:
         print(json.dumps({"nodes": nodes}, indent=1, default=str))
