@@ -19,6 +19,7 @@ from ..storage import Database, make_storage
 from ..storage import metadata as md
 from ..storage.streams import NamedStream, NamedVideoStream
 from ..util.profiler import Profile, Profiler
+from .evaluate import EvaluatorPool
 from .executor import LocalExecutor
 
 
@@ -257,6 +258,10 @@ class Client:
             num_save_workers=num_save_workers,
             pipeline_instances=pipeline_instances or 1,
             decoder_threads=decoder_threads)
+        # the evaluators of the graph a local run ran last: the next
+        # run of the same graph takes them over instead of constructing
+        # its own (engine/evaluate.py EvaluatorPool); closed by stop()
+        self._evaluators = EvaluatorPool()
         # health/SLO engine (util/health.py): local-mode runs get the
         # same backpressure/latency judgment cluster nodes do; no-op
         # when SCANNER_TPU_HEALTH=0 / [alerts] enabled=false
@@ -277,6 +282,7 @@ class Client:
         self.stop()
 
     def stop(self) -> None:
+        self._evaluators.close()
         if self._cluster is not None:
             self._cluster.close()
         if self._metrics_server is not None:
@@ -517,9 +523,15 @@ class Client:
                     "pipeline_instances",
                     default_pipeline_instances(
                         perf.pipeline_instances_per_node
-                        or self._pipeline_instances_arg)))
-            jobs = ex.run(outputs, perf, cache_mode=cache_mode,
-                          show_progress=show_progress)
+                        or self._pipeline_instances_arg)),
+                evaluators=self._evaluators)
+            try:
+                jobs = ex.run(outputs, perf, cache_mode=cache_mode,
+                              show_progress=show_progress)
+            except BaseException:
+                # a run that raised leaves nothing kept
+                self._evaluators.close()
+                raise
             ran = [j for j in jobs if not j.skipped]
             root.args.update(
                 tasks=sum(len(j.tasks) for j in ran),

@@ -30,6 +30,7 @@ steady-state tasks never stall on a compile."""
 from __future__ import annotations
 
 import contextlib
+import enum
 import os
 import threading
 import time
@@ -932,6 +933,127 @@ def rewarm_all() -> int:
     return total
 
 
+def _value_key(v: Any) -> Any:
+    """`v` as a hashable that is equal where the values are: what an op
+    was constructed from, for graph_key.  A string that names a file or
+    a directory carries its size and mtime_ns, so weights written again
+    between two runs are read again.  TypeError: not keyable by value."""
+    if isinstance(v, str):
+        try:
+            st = os.stat(v)
+        except (OSError, ValueError):
+            return v
+        return (v, st.st_size, st.st_mtime_ns)
+    if v is None or isinstance(v, (bool, int, float, bytes)):
+        return (type(v).__name__, v)
+    if isinstance(v, enum.Enum):
+        return (type(v).__qualname__, v.name)
+    if isinstance(v, (list, tuple)):
+        return (type(v).__name__,) + tuple(_value_key(x) for x in v)
+    if isinstance(v, dict):
+        return ("dict",) + tuple(sorted(
+            (str(k), _value_key(x)) for k, x in v.items()))
+    if isinstance(v, np.ndarray) and v.dtype != object:
+        return ("ndarray", v.shape, str(v.dtype), v.tobytes())
+    raise TypeError(f"{type(v).__name__} is not keyed by value")
+
+
+def graph_key(info: A.GraphInfo) -> Optional[Tuple]:
+    """What of a graph its evaluators are made from, and nothing of the
+    request: per op in topological order its name, canonical kernel
+    class, init args by value, effective device, batch, stencil, what
+    decides fusion, and its input edges by position and column.  Node
+    ids, tables, samplers and their arguments, per-stream args and
+    output names are not in it.  None: the evaluators of this graph are
+    not to be kept — an init arg that cannot be keyed by value, or a
+    stateful op (its kernels' state is positioned by the run's task
+    order; see run_pipeline)."""
+    pos = {n.id: i for i, n in enumerate(info.ops)}
+    key = []
+    try:
+        for n in info.ops:
+            if n.spec is not None and n.spec.is_stateful:
+                return None
+            key.append((
+                n.name,
+                None if n.spec is None
+                else O.registry.canonical_factory(n.spec),
+                _value_key(n.init_args), n.effective_device().name,
+                n.effective_batch(), tuple(n.effective_stencil()),
+                n.warmup, n.fuse,
+                tuple(c.is_frame for c in n.outputs),
+                tuple((pos[c.op.id], c.column)
+                      for c in n.input_columns())))
+    except TypeError:
+        return None
+    return tuple(key)
+
+
+class EvaluatorPool:
+    """The evaluators of the graph that ran last, kept by whoever owns
+    their lifetime (a Client; a cluster Worker for the length of a
+    bulk) and handed to the next run with the same key: that run pays
+    no kernel construction, no weight restore, no retrace of a
+    per-kernel jit and no ladder warm-up.  `key(info)` says which runs
+    may share evaluators; None never keeps.
+
+    One key at a time: a take under another key closes what was kept
+    before the caller builds its own, so two graphs' weights never sit
+    in device memory together.  An evaluator is checked out while a run
+    holds it: a second run in flight with the same key finds nothing,
+    builds its own, and whichever is given back second is closed."""
+
+    def __init__(self, key=graph_key):
+        self._key_of = key
+        self._lock = threading.Lock()
+        self._key: Any = None
+        self._kept: Dict[int, "TaskEvaluator"] = {}
+
+    def take(self, info: A.GraphInfo, profiler: Profiler, idx: int,
+             instances: int, precompile: Optional[Tuple[int, int, int]]
+             ) -> Tuple[Any, Optional["TaskEvaluator"]]:
+        """(key, pipeline instance `idx`'s kept evaluator adopted to
+        `info`, or None).  The key goes on the evaluator the caller
+        makes on a miss (`pool_key`), for give()."""
+        key = self._key_of(info)
+        if key is not None:
+            # an instance's chip and dp-shard set follow from these
+            key = (key, instances, precompile, _fusion.enabled(),
+                   tuple(device_label(assigned_device(i))
+                         for i in range(instances)))
+        with self._lock:
+            if key is None or key != self._key:
+                self._close_kept()
+                self._key = key
+            te = self._kept.pop(idx, None)
+        if te is not None:
+            te.adopt(info, profiler)
+        return key, te
+
+    def give(self, te: "TaskEvaluator") -> None:
+        """The end of a run's hold on `te`: kept for the next run of
+        its key, or closed."""
+        with self._lock:
+            keep = te.pool_key is not None and te.pool_key == self._key \
+                and te.instance not in self._kept
+            if keep:
+                self._kept[te.instance] = te
+        if not keep:
+            te.close()
+
+    def _close_kept(self) -> None:
+        kept, self._kept = self._kept, {}
+        for te in kept.values():
+            te.close()
+
+    def close(self) -> None:
+        """Close what is kept; what a run still holds is closed when it
+        is given back."""
+        with self._lock:
+            self._close_kept()
+            self._key = None
+
+
 class TaskEvaluator:
     def __init__(self, info: A.GraphInfo, profiler: Profiler,
                  devices: Optional[List[Any]] = None,
@@ -951,6 +1073,8 @@ class TaskEvaluator:
         # so dryruns/tests exercise them on a virtual multi-device host.
         self.instance = instance
         self.device = assigned_device(instance)
+        # what an EvaluatorPool keeps this evaluator under; None: not kept
+        self.pool_key: Any = None
         if devices is None:
             devices = instance_devices(instance, instances)
         self.kernels: Dict[int, KernelInstance] = {}
@@ -1080,6 +1204,43 @@ class TaskEvaluator:
             _LIVE_EVALUATORS.discard(self)
         for ki in self.kernels.values():
             ki.close()
+
+    def adopt(self, info: A.GraphInfo, profiler: Profiler) -> None:
+        """Take the next run of the graph this evaluator was made for
+        (EvaluatorPool: equal keys, so equal ops at equal positions).
+        Node ids come from a global counter, so everything keyed by
+        them moves to `info`'s by position; streams are unbound so that
+        new_stream and reset() fire on the run's first task as on a
+        fresh instance.  Kept: the kernel objects, their parameters on
+        their chip, their jitted callables, `_shape_sigs` and the
+        warm-up's outcome.  The same `info` again (a Worker's bulk,
+        entered again) keeps its streams and state where they are."""
+        self.profiler = profiler
+        for ki in self.kernels.values():
+            ki.profiler = profiler
+        if info is self.info:
+            return
+        # a ladder still warming executes the kernel: let it finish (or
+        # never start) before this run's new_stream
+        for ki in [*self.kernels.values(), *self.fused.values()]:
+            ki.ensure_warm()
+        node = {old.id: new for old, new in zip(self.info.ops, info.ops)}
+        kernels = {}
+        for ki in self.kernels.values():
+            ki.node = node[ki.node.id]
+            ki._cur_stream = (-1, -1)
+            ki._last_row = None
+            kernels[ki.node.id] = ki
+        chains, fused = {}, {}
+        for fki in self.fused.values():
+            fki.chain = _fusion.FusionChain(
+                [node[m.id] for m in fki.chain.members])
+            chains[fki.chain.tail.id] = fki.chain
+            fused[fki.chain.tail.id] = fki
+        self._chain_member_ids = {node[i].id
+                                  for i in self._chain_member_ids}
+        self.kernels, self.chains, self.fused = kernels, chains, fused
+        self.info = info
 
     # ------------------------------------------------------------------
 

@@ -42,7 +42,7 @@ from ..util.log import get_logger
 from ..util.profiler import Profiler
 from . import framecache as _fc
 from .batch import ColumnBatch, concat_batches
-from .evaluate import TaskEvaluator
+from .evaluate import EvaluatorPool, TaskEvaluator
 
 # live pipeline telemetry (docs/observability.md).  Queue depths answer
 # the round-3 attribution question ("which stage starves?") in real
@@ -117,11 +117,17 @@ _M_RUN_SECONDS = _mx.registry().counter(
     labels=["phase"])
 _M_EVAL_SETUP_SECONDS = _mx.registry().counter(
     "scanner_tpu_evaluator_setup_seconds_total",
-    "Evaluator-thread seconds spent constructing TaskEvaluators "
-    "(kernel construction, fetch_resources, set-up, weight restore).")
+    "Evaluator-thread seconds spent opening evaluators: constructing "
+    "one (kernel construction, fetch_resources, set-up, weight "
+    "restore) or adopting a kept one.")
 _M_EVAL_SETUPS = _mx.registry().counter(
     "scanner_tpu_evaluator_setups_total",
-    "TaskEvaluators constructed: one per pipeline instance per run.")
+    "Evaluators opened, constructed or reused: one per pipeline "
+    "instance per run.")
+_M_EVAL_REUSES = _mx.registry().counter(
+    "scanner_tpu_evaluator_reuses_total",
+    "Evaluators a run was handed by its owner's EvaluatorPool instead "
+    "of constructing them: the graph that ran last, run again.")
 _M_LOAD_WORKERS = _mx.registry().gauge(
     "scanner_tpu_load_workers",
     "Loader threads of the pipeline run that started last: the count "
@@ -360,9 +366,13 @@ class LocalExecutor:
                  num_load_workers: Optional[int] = None,
                  num_save_workers: int = 2,
                  pipeline_instances: int = 1, node_id: int = 0,
-                 decoder_threads: int = 1):
+                 decoder_threads: int = 1,
+                 evaluators: Optional[EvaluatorPool] = None):
         self.db = db
         self.profiler = profiler or Profiler()
+        # who keeps this executor's evaluators between runs (a Client,
+        # a Worker); None: each is made for its run and closed with it
+        self.evaluators = evaluators
         # None = derived at each run (evaluate.py default_load_workers)
         self.num_load_workers = num_load_workers
         # (loader threads, evaluator instances) the last run_pipeline
@@ -820,7 +830,6 @@ class LocalExecutor:
     def run_pipeline(self, info: A.GraphInfo, source,
                      on_start=None, on_done=None, on_eval_done=None,
                      on_task_error=None,
-                     evaluator_factory=None, close_evaluators: bool = True,
                      queue_size: Optional[int] = None,
                      show_progress: bool = False, total: int = 0,
                      precompile: Optional[Tuple[int, int, int]] = None
@@ -844,8 +853,8 @@ class LocalExecutor:
         FinishedWork RPC).
         on_task_error(w, exc) -> bool: True = task failure is reported and
         the pipeline continues (cluster); False/None = abort (local).
-        evaluator_factory(idx, skip_fetch) -> TaskEvaluator: override to
-        reuse evaluators across pipeline entries (cluster worker).
+        Evaluators are kept across pipeline entries by `self.evaluators`
+        (a Client's, a cluster worker's), if there is one.
         Returns the number of tasks fully saved.
 
         SCANNER_TPU_NO_PIPELINING=1 (reference worker.cpp:140 NO_PIPELINING)
@@ -868,7 +877,6 @@ class LocalExecutor:
             self.stage_widths = (1, 1)  # this thread is every stage
             return self._run_serial(info, source, on_start, on_done,
                                     on_eval_done, on_task_error,
-                                    evaluator_factory, close_evaluators,
                                     show_progress, total, precompile)
         qsize = queue_size or 4
         # stateful affinity: kernel state lives in ONE instance's kernels,
@@ -1028,8 +1036,7 @@ class LocalExecutor:
                     fetch_done.wait()
                 te = self._open_evaluator(
                     info, evaluator_idx, n_evals,
-                    skip_fetch=evaluator_idx > 0,
-                    factory=evaluator_factory, precompile=precompile)
+                    skip_fetch=evaluator_idx > 0, precompile=precompile)
                 if evaluator_idx == 0:
                     fetch_done.set()
                 while True:
@@ -1054,7 +1061,7 @@ class LocalExecutor:
                 record_err(e)
             finally:
                 fetch_done.set()  # never leave siblings waiting
-                self._close_evaluator(te, close_evaluators, fb_tls)
+                self._close_evaluator(te, fb_tls)
 
         done_count = [0]
         done_lock = threading.Lock()
@@ -1088,7 +1095,10 @@ class LocalExecutor:
         savers = [threading.Thread(target=saver, name=f"save-{i}")
                   for i in range(self.num_save_workers)]
         try:
-            for t in loaders + evals + savers:
+            # evaluators first: taking over a kept evaluator needs 0.1 ms
+            # of the interpreter lock, which it gets at once while no
+            # loader is running yet
+            for t in evals + loaders + savers:
                 t.start()
             # each hand-off closes when the stage that fills it is done
             for t in loaders:
@@ -1115,8 +1125,7 @@ class LocalExecutor:
         return done_count[0]
 
     def _run_serial(self, info: A.GraphInfo, source, on_start, on_done,
-                    on_eval_done, on_task_error, evaluator_factory,
-                    close_evaluators: bool, show_progress: bool,
+                    on_eval_done, on_task_error, show_progress: bool,
                     total: int,
                     precompile: Optional[Tuple[int, int, int]] = None
                     ) -> int:
@@ -1136,8 +1145,7 @@ class LocalExecutor:
             return on_task_error is not None and on_task_error(w, e)
 
         try:
-            te = self._open_evaluator(info, factory=evaluator_factory,
-                                      precompile=precompile)
+            te = self._open_evaluator(info, precompile=precompile)
             while True:
                 w = source()
                 if w is None:
@@ -1177,7 +1185,7 @@ class LocalExecutor:
                 if show_progress:
                     print(f"\rtasks {done}/{total}", end="", flush=True)
         finally:
-            self._close_evaluator(te, close_evaluators, tls, fb_tls)
+            self._close_evaluator(te, tls, fb_tls)
         if show_progress:
             print()
         return done
@@ -1213,7 +1221,7 @@ class LocalExecutor:
             self._fail_task(w, e)
             raise
         finally:
-            self._close_evaluator(te, True, tls, fb_tls)
+            self._close_evaluator(te, tls, fb_tls)
 
     # ------------------------------------------------------------------
     # The stage bodies of one task.  Every driver of a task runs these:
@@ -1225,36 +1233,44 @@ class LocalExecutor:
 
     def _open_evaluator(self, info: A.GraphInfo, idx: int = 0,
                         instances: int = 1, skip_fetch: bool = False,
-                        factory=None,
                         precompile: Optional[Tuple[int, int, int]] = None
                         ) -> TaskEvaluator:
-        """Pipeline instance `idx`'s evaluator, made (or handed out by
-        `factory(idx, skip_fetch)`, which may keep it across pipeline
-        entries) under the `evaluate:setup` span."""
+        """Pipeline instance `idx`'s evaluator under the
+        `evaluate:setup` span: the one `self.evaluators` kept from the
+        last run of this graph, or a new one."""
         from .evaluate import assigned_device, device_label
         with self.profiler.span(
                 "evaluate:setup", level=0, counter=_M_EVAL_SETUP_SECONDS,
-                device=device_label(assigned_device(idx))):
-            if factory is not None:
-                te = factory(idx, skip_fetch)
+                device=device_label(assigned_device(idx))) as span:
+            key, te = None, None
+            if self.evaluators is not None:
+                key, te = self.evaluators.take(info, self.profiler, idx,
+                                               instances, precompile)
+            span.args["reused"] = te is not None
+            if te is not None:
+                _M_EVAL_REUSES.inc()
             else:
                 te = TaskEvaluator(info, self.profiler,
                                    skip_fetch_resources=skip_fetch,
                                    precompile=precompile,
                                    instance=idx, instances=instances)
+                te.pool_key = key
         _M_EVAL_SETUPS.inc()
         return te
 
-    @staticmethod
-    def _close_evaluator(te: Optional[TaskEvaluator], close: bool,
+    def _close_evaluator(self, te: Optional[TaskEvaluator],
                          *decoders) -> None:
         """The end of an evaluator's driver: the decoder handles its
         thread held (`decoders`: the namespaces they were cached in) go,
-        and the evaluator itself unless whoever made it keeps it."""
+        and the evaluator goes back to whoever keeps it, or is closed."""
         for ns in decoders:
             for auto in getattr(ns, "automata", {}).values():
                 auto.close()
-        if te is not None and close:
+        if te is None:
+            return
+        if self.evaluators is not None:
+            self.evaluators.give(te)
+        else:
             te.close()
 
     def _evaluate_stage(self, info: A.GraphInfo, te: TaskEvaluator,

@@ -49,7 +49,7 @@ from . import gang as _gang
 from . import journal as _journal
 from . import rpc
 from . import shardmap as _shardmap
-from .evaluate import TaskEvaluator
+from .evaluate import EvaluatorPool
 from .executor import _M_TASK_LATENCY, LocalExecutor, TaskItem
 
 PING_INTERVAL = 1.0          # worker heartbeat period
@@ -3373,7 +3373,10 @@ class Worker:
             # the per-bulk resolution (_ensure_bulk) overwrites this;
             # the executor field itself just needs a concrete int
             pipeline_instances=pipeline_instances or 1,
-            decoder_threads=decoder_threads)
+            decoder_threads=decoder_threads,
+            # evaluator instances reused across pipeline entries of one
+            # bulk, stateful kernels with their state included
+            evaluators=EvaluatorPool(key=lambda info: self._bulk_key))
         rpc.wait_for_server(master_address, MASTER_SERVICE)
         # dial the master only AFTER it provably listens: a gRPC channel
         # first dialed against a not-yet-listening address can wedge in
@@ -3472,9 +3475,6 @@ class Worker:
         self._spec_raw: Optional[bytes] = None
         self._task_timeout = 0.0
         self._default_pipeline_instances = pipeline_instances
-        # evaluator instances reused across pipeline entries of one bulk
-        self._evaluators: Dict[int, TaskEvaluator] = {}
-        self._eval_lock = threading.Lock()
         self._posted_profiles: set = set()
         # heartbeat runs on its own thread so a long task never makes the
         # master think this worker died (stale-worker scan).  The
@@ -4000,10 +4000,8 @@ class Worker:
         self.executor.setup_chains(info, jobs, perf)
         self.executor._stream_opt = bool(
             getattr(perf, "stream_work_packets", True))
-        with self._eval_lock:
-            for te in self._evaluators.values():
-                te.close()
-            self._evaluators = {}
+        # the last bulk's evaluators go before this one's are built
+        self.executor.evaluators.close()
         self._info, self._jobs = info, jobs
         self._bulk_id = bulk_id
         self._bulk_key = (self._active_shard, bulk_id)
@@ -4136,22 +4134,6 @@ class Worker:
                 error=f"{type(exc).__name__}: {exc}")
             return True  # keep the pipeline running
 
-        def evaluator_factory(idx: int, skip_fetch: bool) -> TaskEvaluator:
-            with self._eval_lock:
-                te = self._evaluators.get(idx)
-                if te is None:
-                    te = TaskEvaluator(
-                        self._info, self.profiler,
-                        skip_fetch_resources=skip_fetch,
-                        precompile=LocalExecutor.precompile_hint(
-                            self._jobs or []),
-                        # device affinity: reused instance idx keeps
-                        # owning chip idx mod n across pipeline entries
-                        instance=idx,
-                        instances=self.executor.pipeline_instances)
-                    self._evaluators[idx] = te
-                return te
-
         # level >= 2: capture this node's XLA device timeline for the
         # bulk; the trace dir ships in the profile (PostProfile) and
         # Profile.write_trace merges it when readable from that host
@@ -4160,8 +4142,8 @@ class Worker:
             self.executor.run_pipeline(
                 self._info, source, on_start=on_start, on_done=on_done,
                 on_eval_done=on_eval_done, on_task_error=on_task_error,
-                evaluator_factory=evaluator_factory, close_evaluators=False,
-                queue_size=self._queue_size)
+                queue_size=self._queue_size,
+                precompile=LocalExecutor.precompile_hint(self._jobs or []))
 
     # -- gang member path (engine/gang.py) ---------------------------------
 
@@ -4322,10 +4304,7 @@ class Worker:
         if self.metrics_server is not None:
             self.metrics_server.stop()
             self.metrics_server = None
-        with self._eval_lock:
-            for te in self._evaluators.values():
-                te.close()
-            self._evaluators = {}
+        self.executor.evaluators.close()
         if self._links:
             for link in self._links.values():
                 link.close()  # the active link IS self.master
