@@ -2,6 +2,14 @@
 
 Frame outputs re-encode to H.264 by default; .lossless() / .compress()
 tune it, save_mp4 exports a playable file without re-encoding.
+
+Upstream's tutorial filters every frame (Blur, kernel 3) and writes the
+frame column back as video; this port shrinks to 320x240 instead, so
+that a tutorial clip encodes in a moment.  The benchmark runs upstream's
+graph at the source's own 1080p, with the default encode (libx264
+veryfast, crf 20, keyint 16): configuration `blur_1080p`, cell
+`blur_dense` (benchmark/configs/blur_1080p.json, PERF.md sec. 4), where
+the save stage bounds the rate.
 """
 
 import sys

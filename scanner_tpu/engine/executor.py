@@ -138,11 +138,38 @@ _M_STAGE_WAIT = _mx.registry().counter(
     "Seconds a stage thread waited on a neighbor: load = blocked "
     "putting into a full evaluate or chunk queue, evaluate = waiting "
     "for a task (chunk waits are scanner_tpu_chunk_wait_seconds_total), "
-    "save = waiting for an evaluated task.  A stage thread's last "
-    "wait, which ends with its queue closed, counts.",
+    "evaluate_out = blocked handing an evaluated task to a full save "
+    "queue, save = waiting for an evaluated task.  A stage thread's "
+    "last wait, which ends with its queue closed, counts.",
     labels=["stage"])
-_M_WAIT_LOAD, _M_WAIT_EVAL, _M_WAIT_SAVE = (
-    _M_STAGE_WAIT.labels(stage=st) for st in ("load", "evaluate", "save"))
+_M_WAIT_LOAD, _M_WAIT_EVAL, _M_WAIT_EVAL_OUT, _M_WAIT_SAVE = (
+    _M_STAGE_WAIT.labels(stage=st)
+    for st in ("load", "evaluate", "evaluate_out", "save"))
+# the save stage's parts, each recorded with the profiler span of the
+# same name at the same two clock reads: the fetch of a sink's batch
+# (save:fetch) and the encode of a frame column's item (save:encode,
+# inside save:write; what is left of save:write is the storage write)
+_M_SINK_FETCH_SECONDS = _mx.registry().counter(
+    "scanner_tpu_sink_fetch_seconds_total",
+    "Saver seconds spent bringing sinks' batches to the host and "
+    "taking their rows (span save:fetch): the blocking end of the "
+    "device->host copy started at eval-done.")
+_M_SINK_FETCH_BYTES = _mx.registry().counter(
+    "scanner_tpu_sink_fetch_bytes_total",
+    "Bytes of sinks' batches brought from a device to the host by the "
+    "save stage (a batch already on the host counts nothing).")
+_M_ENCODE_SECONDS = _mx.registry().counter(
+    "scanner_tpu_encode_seconds_total",
+    "Saver seconds spent encoding frame columns to video (span "
+    "save:encode): the encoder's creation, feeds, flush and the take "
+    "of its packets, one encoder an item.")
+_M_ENCODED_FRAMES = _mx.registry().counter(
+    "scanner_tpu_encoded_frames_total",
+    "Frames the save stage encoded to video.")
+_M_ENCODED_BYTES = _mx.registry().counter(
+    "scanner_tpu_encoded_bytes_total",
+    "Bytes of encoded video the save stage produced (packet data, "
+    "before the item's index).")
 # end-to-end per-task latency: enqueue (task runnable — local admission
 # or master bulk admission) to sink-committed.  The seed for
 # serving-mode p50/p99 (ROADMAP item 2): under a request-shaped
@@ -375,9 +402,9 @@ class LocalExecutor:
         self.evaluators = evaluators
         # None = derived at each run (evaluate.py default_load_workers)
         self.num_load_workers = num_load_workers
-        # (loader threads, evaluator instances) the last run_pipeline
-        # started; None until one has
-        self.stage_widths: Optional[Tuple[int, int]] = None
+        # (loader threads, evaluator instances, saver threads) the last
+        # run_pipeline started; None until one has
+        self.stage_widths: Optional[Tuple[int, int, int]] = None
         self.num_save_workers = num_save_workers
         self.pipeline_instances = pipeline_instances
         self.node_id = node_id
@@ -767,9 +794,10 @@ class LocalExecutor:
                         # evaluators' close()
                         joined = time.time()
                         if self.stage_widths is not None:
-                            loaders, instances = self.stage_widths
+                            loaders, instances, savers = self.stage_widths
                             pipeline.args.update(loaders=loaders,
-                                                 instances=instances)
+                                                 instances=instances,
+                                                 savers=savers)
                         if self._last_save_end is not None:
                             _M_RUN_SECONDS.labels(phase="drain").inc(
                                 joined - self._last_save_end)
@@ -874,7 +902,7 @@ class LocalExecutor:
         _controller.ensure_started()
         if os.environ.get("SCANNER_TPU_NO_PIPELINING", "0") not in \
                 ("0", "", "false"):
-            self.stage_widths = (1, 1)  # this thread is every stage
+            self.stage_widths = (1, 1, 1)  # this thread is every stage
             return self._run_serial(info, source, on_start, on_done,
                                     on_eval_done, on_task_error,
                                     show_progress, total, precompile)
@@ -915,7 +943,7 @@ class LocalExecutor:
         n_loaders = 1 if serialize else default_load_workers(
             self.num_load_workers, instances=n_evals, queues=len(uniq_qs),
             qsize=qsize, tasks=total, decoder_threads=self.decoder_threads)
-        self.stage_widths = (n_loaders, n_evals)
+        self.stage_widths = (n_loaders, n_evals, self.num_save_workers)
         _M_LOAD_WORKERS.set(n_loaders)
         # live depth gauges sample the queues at scrape time; the last
         # pipeline to start owns the gauge (concurrent pipelines in one
@@ -1055,7 +1083,11 @@ class LocalExecutor:
                         continue
                     if on_eval_done is not None:
                         on_eval_done(w)
-                    if not save_q.put(w):
+                    t_put = time.time()
+                    placed = save_q.put(w)
+                    self._note_wait("evaluate:save_wait", _M_WAIT_EVAL_OUT,
+                                    t_put, task=w.task_idx, device=dev_lbl)
+                    if not placed:
                         break
             except BaseException as e:  # noqa: BLE001
                 record_err(e)
@@ -1333,6 +1365,10 @@ class LocalExecutor:
             with self.profiler.span("save", level=0, task=w.task_idx,
                                     job=w.job.job_idx):
                 self._save_task(info, w)
+        # saved: its results go now, as a failed task's do.  The run's
+        # work list holds every TaskItem until the run returns, and a
+        # frame column's results are 6.2 MB of HBM a 1080p row
+        w.results = None
         t_saved = time.time()
         _M_STAGE_SECONDS.labels(stage="save").inc(t_saved - t0)
         _M_STAGE_TASKS.labels(stage="save").inc()
@@ -2020,17 +2056,14 @@ class LocalExecutor:
         for sink in info.sinks:
             if sink.id in w.job.custom_sinks:
                 stream = w.job.custom_sinks[sink.id]
-                with self.profiler.span("save:fetch", task=w.task_idx):
-                    rows = self._sink_rows(w.results[sink.id], start, end)
+                rows = self._fetch_sink(w, sink.id)
                 with self.profiler.span("save:write", task=w.task_idx):
                     stream.storage.write_item(stream, start, rows)
                 continue
             if sink.id not in w.job.sink_tables:
                 continue
             desc, col_name, codec, enc_opts = w.job.sink_tables[sink.id]
-            # the single device->host fetch of the batched data path
-            with self.profiler.span("save:fetch", task=w.task_idx):
-                rows = self._sink_rows(w.results[sink.id], start, end)
+            rows = self._fetch_sink(w, sink.id)
             t_write = time.time()
             item_idx = w.task_idx
             if codec == "frame":
@@ -2112,6 +2145,17 @@ class LocalExecutor:
             if isinstance(b, ColumnBatch):
                 b.prefetch_host()
 
+    def _fetch_sink(self, w: TaskItem, sink_id: int) -> List[Any]:
+        """The single device->host fetch of the batched data path: one
+        sink's rows of the task's output range, on the host."""
+        batch = w.results[sink_id]
+        with self.profiler.span("save:fetch", counter=_M_SINK_FETCH_SECONDS,
+                                task=w.task_idx):
+            host = batch.to_host()
+            if host is not batch:
+                _M_SINK_FETCH_BYTES.inc(host.data.nbytes)
+            return host.take_range(*w.output_range).elements()
+
     @staticmethod
     def _sink_rows(batch, start: int, end: int) -> List[Any]:
         """Materialize a sink ColumnBatch's rows [start, end) as host
@@ -2163,14 +2207,21 @@ class LocalExecutor:
             frames.append(a)
         h, w_ = frames[0].shape[:2]
         keyint = int(enc_opts.get("keyint", 16))
-        enc = Encoder(w_, h, fps=job.fps or 30.0, codec="libx264",
-                      bitrate=int(enc_opts.get("bitrate", 0)),
-                      crf=int(enc_opts.get("crf", 20)), keyint=keyint)
+        enc = None
         try:
-            for f in frames:
-                enc.feed(f)
-            enc.flush()
-            data, sizes, keys, pts, dts = enc.take_packets()
+            with self.profiler.span("save:encode",
+                                    counter=_M_ENCODE_SECONDS,
+                                    item=item_idx, frames=len(frames)):
+                enc = Encoder(w_, h, fps=job.fps or 30.0, codec="libx264",
+                              bitrate=int(enc_opts.get("bitrate", 0)),
+                              crf=int(enc_opts.get("crf", 20)),
+                              keyint=keyint)
+                for f in frames:
+                    enc.feed(f)
+                enc.flush()
+                data, sizes, keys, pts, dts = enc.take_packets()
+            _M_ENCODED_FRAMES.inc(len(frames))
+            _M_ENCODED_BYTES.inc(len(data))
             vd = md.VideoDescriptor(
                 width=w_, height=h, fps=job.fps or 30.0,
                 num_frames=len(frames), codec="h264",
@@ -2188,4 +2239,5 @@ class LocalExecutor:
                 md.video_meta_path(desc.id, col_name, item_idx),
                 vd.serialize())
         finally:
-            enc.close()
+            if enc is not None:
+                enc.close()

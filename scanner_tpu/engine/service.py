@@ -4021,7 +4021,7 @@ class Worker:
         # RPC, so lagging savers can't throttle the evaluators while a
         # small window still spreads small jobs across workers
         # (one loader where no pipeline has started: a direct call)
-        loaders, _ = self.executor.stage_widths or (1, 1)
+        loaders = (self.executor.stage_widths or (1,))[0]
         window = self.executor.pipeline_instances + loaders
         reply = self.master.try_call("NextWork", worker_id=self.worker_id,
                                      bulk_id=bulk_id, window=window)
