@@ -236,7 +236,7 @@ def smoke(device):
         with phase("Histogram 600 rows, first run (compile + warm-up)",
                    setup=True), \
                 LiveKernels(ev.live_evaluators, "Histogram",
-                            lambda te, k: k._use_pallas) as hist_live:
+                            lambda te, k: k._on_tpu) as hist_live:
             hist_rows = run(sc.ops.Histogram(frame=frames_col()),
                             "smoke_hist")
         check(len(hist_rows) == N_FRAMES, f"{N_FRAMES} Histogram rows")
@@ -245,7 +245,7 @@ def smoke(device):
               f"Histogram output shape {got.shape} dtype {got.dtype}")
         check(hist_live.seen and all(hist_live.seen.values()),
               f"the run's own Histogram kernel instances took the Pallas "
-              f"path (instance: _use_pallas = {hist_live.seen})")
+              f"path (instance: _on_tpu = {hist_live.seen})")
 
         def bincount_rows(frames):
             return np.stack([

@@ -44,9 +44,8 @@ def _histogram_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
     """(batch, H, W, C) uint8 -> (batch, C, bins) int32 counts.
 
     vmapped bincount: lowers to a segment reduction — good on CPU/GPU
-    XLA, but on TPU the scatter machinery serializes (116 fps for a
-    480x640 batch on v5e vs 932 fps for compare+sum in the July
-    capture; on today's code: not measured)."""
+    XLA; no TPU path runs it (a scatter there; its speed on a TPU: not
+    measured)."""
     b, c = frames.shape[0], frames.shape[-1]
     vals = (frames.astype(jnp.int32) * bins) // 256
     vals = vals.reshape(b, -1, c).transpose(0, 2, 1).reshape(b * c, -1)
@@ -99,8 +98,9 @@ def _histogram_seq_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
 def _histogram_cmp_impl(frames: jnp.ndarray, bins: int = HISTOGRAM_BINS):
     """(batch, H, W, C) uint8 -> (batch, C, bins) int32 via one-hot
     compare + reduce: pure VPU work, no scatter — the lowering fused
-    chains trace on TPU (8x over bincount on v5e in the July
-    capture)."""
+    chains trace on TPU.  Alone, one jit from a uint8 16 x 1080p packet
+    on a v5e: 4.64 ms, against the pallas kernel's 1.21 (PERF.md §6,
+    PR 32), which is why a staged call does not take it."""
     b, c = frames.shape[0], frames.shape[-1]
     vals = (frames.astype(jnp.int32) * bins) // 256
     vals = vals.reshape(b, -1, c)                       # (B, P, C)
@@ -114,26 +114,21 @@ class Histogram(Kernel):
     """Per-channel 16-bin color histogram; returns [r, g, b] int32 arrays
     per frame (matching scannertools' UniformList(Histogram, parts=3)).
 
-    Backend selection: TPU runs the hand-written pallas compare+reduce
-    kernel (kernels/pallas_ops.py; the July capture's kernel-level
-    comparison against the XLA lowerings is in PERF.md §6) — a kernel
-    that does not compile raises, there is no silent XLA fallback; a
-    host-only backend uses numpy's C bincount; other accelerators the
-    vmapped-bincount XLA path.  Set SCANNER_TPU_PALLAS=0 to force the
-    XLA compare+sum path on TPU."""
+    Backend selection, by what was measured and by nothing a user
+    sets: a TPU runs the uint8 pallas compare+reduce kernel
+    (kernels/pallas_ops.py; PERF.md §6, PR 32 has the two packet timings
+    that chose it over _histogram_cmp_impl) — a kernel that does not
+    compile raises, there is no silent XLA fallback; a host-only backend
+    uses numpy's C bincount; other accelerators the vmapped-bincount XLA
+    path."""
 
     def __init__(self, config):
         super().__init__(config)
-        import os
-
         from . import pallas_ops
         self._on_tpu = pallas_ops.on_tpu()
-        self._use_pallas = (self._on_tpu
-                            and os.environ.get("SCANNER_TPU_PALLAS") != "0")
         # on a host-only backend numpy's C bincount beats the XLA-CPU
         # scatter lowering; accelerators take the XLA/pallas path
-        self._use_numpy = (not self._use_pallas and not self._on_tpu
-                           and jax.default_backend() == "cpu")
+        self._use_numpy = jax.default_backend() == "cpu"
 
     @staticmethod
     def _histogram_np(frames: np.ndarray) -> np.ndarray:
@@ -177,19 +172,18 @@ class Histogram(Kernel):
         UniformList(Histogram, parts=3))."""
         if self._use_numpy and isinstance(frame, np.ndarray):
             return self._histogram_np(frame)
-        if self._use_pallas:
+        if self._on_tpu:
             from .pallas_ops import histogram_frames
             return histogram_frames(jnp.asarray(frame))
-        if self._on_tpu:
-            return _histogram_cmp_impl(jnp.asarray(frame))
         return _histogram_impl(jnp.asarray(frame))
 
     def execute_traced(self, frame):
         """Fusion-chain core: inside a composed trace the numpy fast
         path is unreachable (the input is a tracer), and the bincount
         lowering serializes on scatter on every backend.  TPU traces
-        the measured-fast compare+sum; hosts and other accelerators the
-        per-bin compare+sum (see _histogram_seq_impl)."""
+        the one-hot compare+sum, which XLA can fuse with the chain's
+        producer; hosts and other accelerators the per-bin compare+sum
+        (see _histogram_seq_impl)."""
         frame = jnp.asarray(frame)
         if self._on_tpu:
             return _histogram_cmp_impl(frame)

@@ -263,9 +263,8 @@ def test_histogram_program_carries_the_op_name():
     x = jnp.zeros((2, 48, 64, 3), jnp.uint8)
     for fn in (imgproc._histogram_impl, imgproc._histogram_cmp_impl):
         assert f"jit({fn.__name__})/Histogram/" in _op_names(fn.lower(x))
-    vals = jnp.zeros((6, 48 * 64), jnp.int32)
-    assert "jit(pallas_histogram)/Histogram/" in _op_names(
-        pallas_ops.pallas_histogram.lower(vals, interpret=True))
+    assert "jit(histogram_frames)/Histogram/" in _op_names(
+        pallas_ops.histogram_frames.lower(x, interpret=True))
 
 
 def test_fused_chain_program_names_chain_and_members(sc):
