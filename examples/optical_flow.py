@@ -1,7 +1,10 @@
 """Optical flow app: dense per-frame flow fields over a video.
-(Reference: examples/apps/optical_flow — OpenCV flow in a kernel; here
-the OpticalFlow op is a jitted Horn-Schunck solve on device, a stencil
-[-1, 0] op so the engine decodes exactly one extra frame per task.)
+(Reference: examples/apps/optical_flow, whose OpticalFlow op is OpenCV's
+Farneback flow in a CPU or GPU kernel.  Here the op of that name is a
+jitted float32 Horn-Schunck solve on the device, sixteen fixed
+iterations: another algorithm under the same name, the same [-1, 0]
+stencil, so the engine decodes exactly one extra frame per task, and
+the same column type, a (H, W, 2) float32 field a row; row 0 reads 0.)
 
 Usage: python examples/optical_flow.py path/to/video.mp4 [db_path]
 """

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import enum
+import functools
 import os
 import threading
 import time
@@ -78,6 +79,21 @@ _M_HALO_ROWS = _mx.registry().counter(
     "loaded only because a consumer's stencil window reaches outside "
     "the consumer's own rows there: the back-reach over a task "
     "boundary, paid again by each task.",
+    labels=["op"])
+# the window argument itself: a stencilled op over an array column is
+# handed a (k, W, ...) array gathered from it, a copy of every row W
+# times over (the evaluate:gather span, inside evaluate:<op>)
+_M_GATHER_SECONDS = _mx.registry().counter(
+    "scanner_tpu_stencil_gather_seconds_total",
+    "Evaluator seconds a stencilled op spent gathering its (k, W, ...) "
+    "window argument out of its input column's array, a call (for a "
+    "device column the dispatch of the gather program, not its run); "
+    "mirrors the evaluate:gather span.",
+    labels=["op"])
+_M_GATHER_BYTES = _mx.registry().counter(
+    "scanner_tpu_stencil_gather_bytes_total",
+    "Bytes of the (k, W, ...) window arguments gathered for a "
+    "stencilled op: W times its input rows, padding rows included.",
     labels=["op"])
 _M_OP_RECOMPILES = _mx.registry().counter(
     "scanner_tpu_op_recompiles_total",
@@ -375,6 +391,27 @@ def _source_geometry_inputs(node: O.OpNode) -> bool:
     return True
 
 
+def _window_rows(data, p):
+    return data[p.reshape(-1)].reshape(p.shape + data.shape[1:])
+
+
+@functools.lru_cache(maxsize=None)
+def _window_gatherer():
+    import jax
+    return jax.jit(jax.named_scope("stencil_window")(_window_rows))
+
+
+def _gather_window(data, p: np.ndarray):
+    """Rows `p` (k, W) of an array column `data` (n, ...) as the
+    (k, W, ...) argument of a stencilled op.  A device column goes
+    through one jitted program a (column, window) shape, whose device
+    operations carry the scope `stencil_window`; a host column through
+    numpy."""
+    if isinstance(data, np.ndarray):
+        return _window_rows(data, p)
+    return _window_gatherer()(data, p.astype(np.int32))
+
+
 def _strip_pad(res, k: int, n_out: int):
     """Drop bucket-padding rows from a kernel result before emission.
     Accepts every result protocol emit_result does: a single batch, a
@@ -431,6 +468,12 @@ class KernelInstance:
         self._warm_lock = threading.Lock()
         self._warm_state = "idle"
         self._warm_done = threading.Event()
+        # what the warm-up rehearses of a stencilled op's window besides
+        # the op (TaskEvaluator._warm_targets): the row counts its input
+        # column arrives in, chunk by chunk, and whether that column
+        # travels as YUV420 wire
+        self.window_chunks: Tuple[int, ...] = ()
+        self.yuv_wire = False
         # serializes kernel.execute between the evaluation thread and a
         # warm-up/re-warm thread: two concurrent execute() calls on one
         # kernel instance are not guaranteed safe, and the ensure_warm
@@ -557,6 +600,7 @@ class KernelInstance:
                                  "abandoning its ladder warm-up",
                                  self.node.name, b, exc_info=True)
                     return
+            self._warm_window(ladder, h, w)
             _M_OP_PRECOMPILE.labels(op=self.node.name,
                                     device=self.dev_label).set(
                 time.time() - t0)
@@ -565,6 +609,37 @@ class KernelInstance:
             with self._warm_lock:
                 self._warm_state = "done"
             self._warm_done.set()
+
+    def _warm_window(self, ladder: Sequence[int], h: int, w: int) -> None:
+        """The window's programs in front of a stencilled op, which the
+        ladder's synthesized arguments pass by: for every row count a
+        streamed chunk's column can have (the work packet plus the
+        window's reach, and fewer at a table's edge, where the window
+        repeats the edge instead) the column's wire conversion and the
+        gather at every rung.  Which of them a task meets depends on
+        where its rows lie in the table, so the first request need not
+        show them all."""
+        if not self.window_chunks or not _device_staging_enabled():
+            return
+        import jax
+        sten = np.asarray(self.node.effective_stencil(), np.int64)
+        shape = (h * w + 2 * ((h + 1) // 2) * ((w + 1) // 2),) \
+            if self.yuv_wire else (h, w, 3)
+        for n in self.window_chunks:
+            try:
+                col = ColumnBatch(
+                    np.arange(n),
+                    jax.device_put(np.zeros((n,) + shape, np.uint8),
+                                   self.device),
+                    convert=("yuv420", h, w) if self.yuv_wire else None
+                ).converted()
+                for b in ladder:
+                    _gather_window(col.data, np.clip(
+                        np.arange(b)[:, None] + sten - sten.min(), 0, n - 1))
+            except Exception:  # noqa: BLE001 — as the ladder's rungs
+                _log.warning("warm-up of %s's window at %d rows failed",
+                             self.node.name, n, exc_info=True)
+                return
 
     def ensure_warm(self) -> None:
         """Called before a real execute(): if this kernel's warm-up is
@@ -1059,7 +1134,8 @@ class TaskEvaluator:
                  devices: Optional[List[Any]] = None,
                  skip_fetch_resources: bool = False,
                  precompile: Optional[Tuple[int, int, int]] = None,
-                 instance: int = 0, instances: int = 1):
+                 instance: int = 0, instances: int = 1,
+                 yuv_wire: bool = False):
         self.info = info
         self.profiler = profiler
         # device affinity: this pipeline instance owns ONE chip (instance
@@ -1114,6 +1190,10 @@ class TaskEvaluator:
         # alone); evaluation threads join per-kernel via ensure_warm().
         self._precompile_thread: Optional[threading.Thread] = None
         self._precompile_hint = precompile
+        # the executor's word that source frame columns reach this
+        # graph's device ops as YUV420 wire (the warm-up converts as the
+        # tasks will)
+        self._yuv_wire = yuv_wire
         if precompile is not None and _precompile_enabled() \
                 and _bucketing_enabled():
             targets = self._warm_targets(precompile)
@@ -1155,6 +1235,12 @@ class TaskEvaluator:
                 cap = max(1, min(n.effective_batch(), int(wp)))
             else:
                 cap = max(1, n.effective_batch())
+            sten = n.effective_stencil()
+            if sten != [0] and wp:
+                reach = max(sten) - min(sten)
+                ki.window_chunks = tuple(int(wp) + reach - k
+                                         for k in range(reach + 1))
+                ki.yuv_wire = self._yuv_wire
             targets.append((ki, bucket_ladder(cap)))
         # fused chains warm their ONE chain ladder (precompile is
         # polymorphic over KernelInstance / FusedKernelInstance)
@@ -1548,8 +1634,13 @@ class TaskEvaluator:
                 p = pos[sel]           # (k, W)
                 if is_array_data(b.data):
                     if has_stencil:
-                        args.append(b.data[p.reshape(-1)].reshape(
-                            p.shape + tuple(b.data.shape[1:])))
+                        with self.profiler.span(
+                                "evaluate:gather", op=n.name, rows=len(p),
+                                counter=_M_GATHER_SECONDS.labels(
+                                    op=n.name)):
+                            args.append(_gather_window(b.data, p))
+                        _M_GATHER_BYTES.labels(op=n.name).inc(
+                            args[-1].nbytes)
                     else:
                         q = p[:, 0]
                         if len(q) and np.array_equal(

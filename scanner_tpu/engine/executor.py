@@ -158,6 +158,18 @@ _M_SINK_FETCH_BYTES = _mx.registry().counter(
     "scanner_tpu_sink_fetch_bytes_total",
     "Bytes of sinks' batches brought from a device to the host by the "
     "save stage (a batch already on the host counts nothing).")
+# a frame column that is not uint8 RGB (float32 flow fields) is not
+# video: its rows are pickled one by one into a blob item (save:raw,
+# inside save:write)
+_M_RAW_FRAME_SECONDS = _mx.registry().counter(
+    "scanner_tpu_raw_frame_seconds_total",
+    "Saver seconds spent writing items of frame columns that are not "
+    "video (span save:raw): a pickle of each row's array, the item's "
+    "build (sizes, checksum, one joined buffer) and the backend write.")
+_M_RAW_FRAME_BYTES = _mx.registry().counter(
+    "scanner_tpu_raw_frame_bytes_total",
+    "Bytes of the items written for frame columns that are not video, "
+    "header and pickle framing included.")
 _M_ENCODE_SECONDS = _mx.registry().counter(
     "scanner_tpu_encode_seconds_total",
     "Saver seconds spent encoding frame columns to video (span "
@@ -1282,10 +1294,13 @@ class LocalExecutor:
             if te is not None:
                 _M_EVAL_REUSES.inc()
             else:
-                te = TaskEvaluator(info, self.profiler,
-                                   skip_fetch_resources=skip_fetch,
-                                   precompile=precompile,
-                                   instance=idx, instances=instances)
+                te = TaskEvaluator(
+                    info, self.profiler, skip_fetch_resources=skip_fetch,
+                    precompile=precompile, instance=idx,
+                    instances=instances,
+                    yuv_wire=any(self._yuv_device_wire(info, n.id)
+                                 for n in info.ops
+                                 if n.name == O.INPUT_OP))
                 te.pool_key = key
         _M_EVAL_SETUPS.inc()
         return te
@@ -2095,15 +2110,26 @@ class LocalExecutor:
                 else:
                     # non-uint8/RGB frame data (e.g. float32 flow fields):
                     # the reference stores these as RAW-format video
-                    # columns; here the column degrades to pickled arrays
+                    # columns; here the column degrades to pickled arrays.
+                    # A row pays its bytes (16,588,800 for a 1080p flow
+                    # field) three times on this thread: the pickle's copy,
+                    # the item's checksum and the item's join, then the
+                    # backend's write (span save:raw; one contiguous buffer
+                    # a task would pay the write alone)
                     import pickle
-                    IT.write_item(
-                        self.db.backend,
-                        md.column_item_path(desc.id, col_name, item_idx),
-                        [e if isinstance(e, NullElement)
-                         else pickle.dumps(np.asarray(e),
-                                           protocol=pickle.HIGHEST_PROTOCOL)
-                         for e in rows])
+                    with self.profiler.span(
+                            "save:raw", counter=_M_RAW_FRAME_SECONDS,
+                            task=w.task_idx, rows=len(rows)):
+                        item = IT.build_item(
+                            [e if isinstance(e, NullElement)
+                             else pickle.dumps(
+                                 np.asarray(e),
+                                 protocol=pickle.HIGHEST_PROTOCOL)
+                             for e in rows])
+                        self.db.backend.write(
+                            md.column_item_path(desc.id, col_name,
+                                                item_idx), item)
+                    _M_RAW_FRAME_BYTES.inc(len(item))
             else:
                 blobs = []
                 for e in rows:

@@ -82,7 +82,7 @@ def test_optical_flow_cost_scales_with_window():
     d = k.cost([(2, 2, 16, 16, 3)])
     from scanner_tpu.kernels.imgproc import HS_ITERS
     px = 2 * 16 * 16
-    assert d.flops == px * (2 * 5 + 6 + HS_ITERS * 48)
+    assert d.flops == px * (2 * 5 + 6 + HS_ITERS * 30)
     assert d.bytes_in == 2 * 2 * 16 * 16 * 3
     assert d.bytes_out == px * 2 * 4
 

@@ -674,6 +674,13 @@ def health_cluster(tmp_path):
     """Master (with /metrics+/alertz enabled) + 2 in-process workers
     over a packed-int source table, health engine on a fast clock."""
     health.set_interval(0.1)
+    # the engine is the process's one: a sample of the last ten seconds
+    # in which another test file's evaluator was warming (its first
+    # dispatch, a compile) would hold `stage_backpressure` quiet past
+    # the end of these short runs (its `unless` gate)
+    eng = health.engine()
+    with eng._lock:
+        eng._samples.clear()
     db_path = str(tmp_path / "db")
     seed = Client(db_path=db_path)
     seed.new_table("health_src", ["output"],
