@@ -1505,9 +1505,10 @@ class TaskEvaluator:
                 elif not is_device_kernel:
                     b = b.to_host()
                 # resolve a pending wire-format conversion (YUV420 staged
-                # at 1.5 B/px) exactly once, where the data now lives: a
-                # jit device op for device kernels — XLA fuses it ahead of
-                # the kernel — or the bit-identical numpy flavor on host
+                # at 1.5 B/px) exactly once, where the data now lives: for
+                # device kernels a jitted program of its own ahead of the
+                # op's (kernels/color.py), on host the bit-identical numpy
+                # flavor
                 if b.convert is not None:
                     b = b.converted()
                 in_batches[i] = b

@@ -5,12 +5,16 @@ instead of packed RGB24 (3 B/px) and convert there — halving host->device
 bytes, the first-order term of every device pipeline (PERF.md §5).  The
 reference did the same on GPU: NV12 frames converted by a CUDA kernel
 (reference scanner/util/image.cu:22 nv12_to_rgb); here the conversion is
-a jit-compiled jnp op XLA fuses ahead of the first consumer kernel.
+a jitted program of its own, the first of every packet: one uint8
+relayout of the wire's planes, then a Pallas kernel
+(`pallas_ops.yuv420_planes`) that widens, upsamples, combines and
+narrows a block of rows in VMEM and writes the three uint8 planes once.
+Nothing wider than uint8 touches HBM (PERF.md §6, PR 35).
 
 Both flavors implement the SAME arithmetic — BT.601 limited range with
 nearest-neighbor chroma upsampling in 8-bit integer fixed point — so
 device and host pipelines are bit-identical on every backend
-(test_video.py pins this).  Note
+(test_yuv_wire.py pins this).  Note
 swscale's own yuv420p->RGB24 path (the decoder's "rgb24" output) uses
 fixed-point coefficients and bilinear chroma; the two conversions agree
 closely but not bit-for-bit, which is why a pipeline picks ONE decode
@@ -44,8 +48,9 @@ def _split_planes(flat, h: int, w: int):
 
 
 def _combine(y, u, v, xp):
-    """Shared fixed-point arithmetic on int32 planes already at full
-    resolution; returns int32 0..255."""
+    """The fixed-point arithmetic on int32 planes already at full
+    resolution; returns int32 0..255.  The statement of it: the device's
+    kernel (`pallas_ops._yuv_kernel`) is held to these bits."""
     yy = 298 * (y - 16)
     uu = u - 128
     vv = v - 128
@@ -71,14 +76,36 @@ def _device_converter(h: int, w: int):
     import jax
     import jax.numpy as jnp
 
+    from .pallas_ops import LANES, SUBLANES, yuv420_planes
+
+    # the kernel's geometry: chroma rows pair up along lanes, so H a
+    # multiple of 4 and W of 128; anything else is padded to that and
+    # cropped (1080p is neither)
+    hp, wp = -(-h // 4) * 4, -(-w // LANES) * LANES
+    lines = -(-(hp // 4) // SUBLANES) * SUBLANES
+
+    def pad(plane, *shape):
+        """Zeros after every dim but the first, up to `shape`."""
+        return jnp.pad(plane, [(0, 0)] + [
+            (0, to - at) for at, to in zip(plane.shape[1:], shape)])
+
+    def paired(plane):
+        # (n, hp / 2, wp / 2) chroma is (n, hp / 4, wp) in the same
+        # bytes.  Padded to whole 8-line tiles while still flat, the
+        # reshape compiles to the relayout copy the luma's does; cut at
+        # 1080p's 270 lines it took the compiler 10 s a chunk length
+        flat = pad(plane, hp // 2, wp // 2).reshape(len(plane), -1)
+        return pad(flat, lines * wp).reshape(len(plane), lines, wp)
+
     @jax.named_scope("yuv420_to_rgb")
     def convert(flat):
-        y, u, v = _split_planes(flat, h, w)
-        up = jnp.repeat(jnp.repeat(u, 2, axis=-2), 2, axis=-1)[..., :h, :w]
-        vp = jnp.repeat(jnp.repeat(v, 2, axis=-2), 2, axis=-1)[..., :h, :w]
-        out = _combine(y.astype(jnp.int32), up.astype(jnp.int32),
-                       vp.astype(jnp.int32), jnp)
-        return out.astype(jnp.uint8)
+        lead = flat.shape[:-1]
+        y, u, v = _split_planes(flat.reshape(-1, flat.shape[-1]), h, w)
+        planes = yuv420_planes(pad(y, hp, wp), paired(u), paired(v))
+        # (n, 3, h, w) row-major is the device's own layout of
+        # (n, h, w, 3) uint8: the transpose moves nothing
+        return planes[:, :, :h, :w].transpose(0, 2, 3, 1).reshape(
+            *lead, h, w, 3)
 
     return jax.jit(convert)
 

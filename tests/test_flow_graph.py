@@ -310,3 +310,32 @@ def test_the_ops_programs_hold_no_bfloat16_on_the_v5e(one_v5e_chip, program):
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "f32[4,1080,1920" in hlo
     assert not [ln for ln in hlo.splitlines() if re.search(r"\bbf16\b", ln)]
+
+
+@pytest.mark.parametrize("rows", [16, 17])
+def test_the_wire_conversion_holds_nothing_wider_than_uint8_in_hbm(
+        one_v5e_chip, rows):
+    """The wire conversion compiled for the chip at a 1080p packet, and
+    at the flow cell's chunk of a packet and its halo row: the kernel is
+    there (not its interpreter), no full-frame int32 plane, no
+    temporaries to speak of (0.65-0.80 GB before PR 35), and after the
+    kernel only bitcasts: the planes it writes are the device's layout
+    of (rows, 1080, 1920, 3) uint8, which the Histogram kernel reads as
+    it stands.  A compile is not a chip run.  (Here and not beside the
+    converter's other tests: one file holds the described chip.)"""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from scanner_tpu.kernels.color import _device_converter
+    flat = jax.ShapeDtypeStruct((rows, 1080 * 1920 * 3 // 2), jnp.uint8,
+                                sharding=one_v5e_chip)
+    compiled = _device_converter(1080, 1920).lower(flat).compile()
+    hlo = compiled.as_text()
+    assert f"s32[{rows},1080,1920" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    entry = hlo[hlo.index("ENTRY "):].splitlines()
+    (kernel,) = [i for i, ln in enumerate(entry) if "tpu_custom_call" in ln]
+    after = [re.search(r" ([a-z][a-z-]*)\(", ln).group(1)
+             for ln in entry[kernel + 1:] if " = " in ln]
+    assert after and set(after) == {"bitcast"}, after
+    assert f"u8[{rows},1080,1920,3]" in entry[0]

@@ -39,6 +39,36 @@ def test_converters_bit_exact_all_geometries():
         assert (host == dev).all(), f"device/host mismatch at {h}x{w}"
 
 
+# the studio swing's ends, the chroma ceiling and both ends of a byte
+EXTREMES = np.array([0, 16, 235, 240, 255], np.uint8)
+
+
+@pytest.mark.parametrize("rows", [1, 2, 6, 16, 17])
+def test_device_converter_bit_exact_at_1080p(rows):
+    """The kernel at the benchmark's geometry and every chunk length its
+    cells meet: nine row blocks a frame, the last ragged (56 of 128
+    rows), chroma lines padded 270 -> 272.  Row 0 holds only the
+    extremes, drawn independently in each plane; the rest is noise."""
+    h, w = 1080, 1920
+    rng = np.random.RandomState(rows)
+    flat = rng.randint(0, 256, (rows, yuv420_frame_bytes(h, w)), np.uint8)
+    flat[0] = rng.choice(EXTREMES, flat.shape[1])
+    dev = np.asarray(yuv420_to_rgb_device(flat, h, w))
+    assert dev.shape == (rows, h, w, 3) and dev.dtype == np.uint8
+    for i in range(rows):  # a frame at a time: int32 planes of one only
+        assert (dev[i] == yuv420_to_rgb_host(flat[i], h, w)).all(), i
+
+
+def test_device_converter_keeps_leading_dims():
+    rng = np.random.RandomState(3)
+    flat = rng.randint(0, 256, (2, 3, yuv420_frame_bytes(36, 52)), np.uint8)
+    host = yuv420_to_rgb_host(flat, 36, 52)
+    assert host.shape == (2, 3, 36, 52, 3)
+    assert (np.asarray(yuv420_to_rgb_device(flat, 36, 52)) == host).all()
+    assert (np.asarray(yuv420_to_rgb_device(flat[0, 0], 36, 52))
+            == host[0, 0]).all()
+
+
 def test_yuv_decode_matches_sws_decode(tmp_db, clip):
     """Same frames decoded both ways: planar YUV + our fixed-point
     conversion vs swscale's packed RGB24.  The two conversions differ in
