@@ -21,7 +21,8 @@ per-row (batch=1, non-array) consumer asks for it.
 
 from __future__ import annotations
 
-import time
+import functools
+from types import SimpleNamespace
 from typing import Any, List, Optional, Sequence, Union
 
 import numpy as np
@@ -33,20 +34,18 @@ from ..util import metrics as _mx
 
 Elem = Any
 
-# host<->device traffic as live counters.  h2d has bytes only: the
+# host<->device traffic as live counters, bytes only.  The h2d
 # device_put call returns at dispatch and the copy completes under
 # later compute by design, so host seconds around it time an enqueue
-# (the loader's `load:stage` span shows what the dispatch costs the
-# host); d2h seconds are the full blocking fetch.
+# (the loader's `load:stage` and `load:prestage` spans show what the
+# dispatch costs the host); a blocking d2h fetch is timed where it has
+# a name: the saver's `save:fetch`, a host op's `evaluate:window`.
 _M_H2D_BYTES = _mx.registry().counter(
     "scanner_tpu_h2d_bytes_total",
     "Bytes staged host->device via ColumnBatch.to_device.")
 _M_D2H_BYTES = _mx.registry().counter(
     "scanner_tpu_d2h_bytes_total",
     "Bytes fetched device->host via ColumnBatch.to_host.")
-_M_D2H_SECONDS = _mx.registry().counter(
-    "scanner_tpu_d2h_seconds_total",
-    "Seconds spent blocking on device->host fetches.")
 
 
 def staged_device_put(host: "np.ndarray", device, kind: str,
@@ -74,6 +73,68 @@ def staged_device_put(host: "np.ndarray", device, kind: str,
     _ms.track_array(data, kind,
                     device=lbl if device is not None else None)
     return data
+
+
+@functools.lru_cache(maxsize=None)
+def row_programs(scope: str) -> SimpleNamespace:
+    """The row-axis device programs of the batched data path, each a
+    jitted function traced under `jax.named_scope(scope)`: the device
+    trace's operations then carry the name of the layer that dispatched
+    them (`columnbatch` here, `framecache` in engine/framecache.py;
+    docs/profiling.md "Device side"), where the same operations
+    dispatched eagerly carried none.  The programs are the ones eager
+    indexing compiled: one a (shape, start, length) for a run of rows
+    (`slice`, and `copy`, whose result never shares the operand's
+    buffer), one an (array shape, index length) for rows picked by
+    index (`gather`), one a tuple of shapes for arrays joined end to
+    end (`concat`).  `copy` adds a zero it is handed at run time
+    (`copy(data, start, n, zero)`, a 0-d array of the data's type): a
+    copy alone lowers to nothing, the compiler copies the unchanged
+    parameter itself, and that operation carries no name (the whole
+    block a fill keeps, a twelfth of `hist_dense`'s busy time); the add
+    reads and writes the same bytes once, under the scope."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def scoped(fn, **kw):
+        return jax.jit(jax.named_scope(scope)(fn), **kw)
+
+    def slice_rows(data, start, n):
+        return lax.slice_in_dim(data, start, start + n, axis=0)
+
+    def copy_rows(data, start, n, zero):
+        return slice_rows(data, start, n) + zero
+
+    def gather_rows(data, idx):
+        return data[idx]
+
+    def concat_rows(*parts):
+        return jnp.concatenate(parts, axis=0)
+
+    return SimpleNamespace(
+        slice=scoped(slice_rows, static_argnums=(1, 2)),
+        copy=scoped(copy_rows, static_argnums=(1, 2)),
+        gather=scoped(gather_rows), concat=scoped(concat_rows))
+
+
+def rows_run(data, start: int, n: int):
+    """Rows [start, start + n) of array data or a list: a view of a
+    host array, the array itself where that is all of it, else one
+    program on the device."""
+    if not _is_jax(data):
+        return data[start:start + n]
+    if start == 0 and n == data.shape[0]:
+        return data
+    return row_programs("columnbatch").slice(data, start, n)
+
+
+def rows_at(data, idx: np.ndarray):
+    """Rows of array data picked by index: a numpy copy on the host,
+    one gather program on the device."""
+    if not _is_jax(data):
+        return data[idx]
+    return row_programs("columnbatch").gather(data, idx)
 
 
 def _is_jax(x) -> bool:
@@ -174,7 +235,7 @@ class ColumnBatch:
             else:
                 data = self.data[safe]
         elif _is_jax(self.data):
-            data = self.data[safe]  # on-device gather
+            data = rows_at(self.data, safe)  # on-device gather
         else:
             data = [NullElement() if neg[i] else self.data[int(p)]
                     for i, p in enumerate(safe)]
@@ -191,7 +252,7 @@ class ColumnBatch:
                 or self.rows[p0 + k - 1] != start_row + k - 1:
             return None
         nulls = self.nulls[p0:p0 + k] if self.nulls is not None else None
-        return ColumnBatch(new_rows, self.data[p0:p0 + k], nulls,
+        return ColumnBatch(new_rows, rows_run(self.data, p0, k), nulls,
                            convert=self.convert)
 
     def take_rows(self, rows: np.ndarray,
@@ -294,9 +355,7 @@ class ColumnBatch:
     def to_host(self) -> "ColumnBatch":
         """Materialize device data on host (the single sink-side fetch)."""
         if _is_jax(self.data):
-            t0 = time.time()
             data = np.asarray(self.data)
-            _M_D2H_SECONDS.inc(time.time() - t0)
             _M_D2H_BYTES.inc(data.nbytes)
             return ColumnBatch(self.rows, data, self.nulls,
                                convert=self.convert)
@@ -376,10 +435,10 @@ def concat_batches(parts: List[ColumnBatch]) -> ColumnBatch:
         return ColumnBatch(rows, np.concatenate(datas), nulls,
                            convert=convert)
     if all(_is_jax(d) for d in datas):
-        import jax.numpy as jnp
         if len({(tuple(d.shape[1:]), d.dtype) for d in datas}) == 1:
-            return ColumnBatch(rows, jnp.concatenate(datas), nulls,
-                               convert=convert)
+            return ColumnBatch(
+                rows, row_programs("columnbatch").concat(*datas), nulls,
+                convert=convert)
     # mixed / ragged: fall back to object list
     elems: List[Elem] = []
     for p in parts:

@@ -51,15 +51,47 @@ from ..util import metrics as _mx
 from ..util import tracing as _tracing
 from ..util.log import get_logger
 from ..util.profiler import Profiler
-from .batch import ColumnBatch, concat_batches, is_array_data
+from .batch import (ColumnBatch, concat_batches, is_array_data, rows_at,
+                    rows_run)
 
 _log = get_logger("evaluate")
 
-# per-op live row counts (host seconds around the calls would time
-# asynchronous enqueues; the `evaluate:<op>` spans show the host side)
+# per-op live row counts, and the evaluator's host seconds by op: the
+# `evaluate:<op>` span whole, and inside it what the host spent in the
+# op's calls (for a device op the enqueue) and what it waited for the
+# chip; each recorded with the span of its name at the same clock reads
 _M_OP_ROWS = _mx.registry().counter(
     "scanner_tpu_op_rows_total",
     "Rows evaluated per op (kernel calls, warmup rows included).",
+    labels=["op"])
+_M_OP_SECONDS = _mx.registry().counter(
+    "scanner_tpu_op_seconds_total",
+    "Evaluator seconds inside an op's evaluate:<op> span (a task or "
+    "chunk a span), by op and where it ran: `host` for a host op and "
+    "the builtins (Sample, Space, Slice, Unslice, Output), else the "
+    "chip's label, where the seconds are the host's: the enqueue of "
+    "the op's calls and any wait for the chip among them.",
+    labels=["op", "device"])
+_M_OP_INPUT_SECONDS = _mx.registry().counter(
+    "scanner_tpu_op_input_seconds_total",
+    "Evaluator seconds an op without a stencil spent having its input "
+    "columns where it runs before its first call of a task or chunk "
+    "(staging's dispatch, the wire conversion's dispatch, the rows "
+    "looked up); mirrors the evaluate:inputs span.  A stencilled op's "
+    "are scanner_tpu_stencil_window_seconds_total.",
+    labels=["op"])
+_M_OP_DISPATCH_SECONDS = _mx.registry().counter(
+    "scanner_tpu_op_dispatch_seconds_total",
+    "Evaluator seconds inside a batched op's execute calls (a fused "
+    "chain's program call), a call a span (evaluate:dispatch): for a "
+    "device op the host's enqueue, a first call's compile included; "
+    "for a host op the work itself.",
+    labels=["op"])
+_M_DEVICE_WAIT_SECONDS = _mx.registry().counter(
+    "scanner_tpu_device_wait_seconds_total",
+    "Evaluator seconds blocked until a device op's call had finished "
+    "on the chip (evaluate:device_wait: the wait that times the call "
+    "for the roofline gauges, and the drain after a first call).",
     labels=["op"])
 # a stencilled op's window: what it costs to have the producer's column
 # where the op runs (for a host op behind a device op, the wait for the
@@ -1168,6 +1200,13 @@ class TaskEvaluator:
                     device=self.device if on_chip else None)
             for ki in self.kernels.values():
                 ki.setup(fetch=not skip_fetch_resources)
+        if _cs.enabled() and any(
+                ki.node.effective_device() == DeviceType.TPU
+                for ki in self.kernels.values()):
+            # a device program this evaluator's tasks dispatch outside
+            # an observed call (a conversion, a gather, a cache slice)
+            # may compile: the listeners stand before the first of them
+            _cs.install()
         # whole-pipeline fusion (graph/fusion.py): maximal runs of
         # fusable consecutive device ops execute as ONE jitted program.
         # Non-tail members never dispatch (or materialize an output
@@ -1355,16 +1394,25 @@ class TaskEvaluator:
             ts = plan.streams[n.id]
             if n.name == O.INPUT_OP:
                 store[(n.id, "output")] = source_batches[n.id]
-            elif n.name in (O.SAMPLE_OP, O.SPACE_OP):
-                store[(n.id, "output")] = self._run_sampler(n, jr, plan, store)
-            elif n.name == O.SLICE_OP:
-                store[(n.id, "output")] = self._run_slice(n, jr, plan, store)
-            elif n.name == O.UNSLICE_OP:
-                store[(n.id, "output")] = self._run_unslice(n, jr, plan, store)
-            elif n.name == O.OUTPUT_OP:
-                src = n.input_columns()[0]
-                results[n.id] = store[(src.op.id, src.column)].take_rows(
-                    ts.valid_output_rows)
+            elif n.name in (O.SAMPLE_OP, O.SPACE_OP, O.SLICE_OP,
+                            O.UNSLICE_OP, O.OUTPUT_OP):
+                # the builtins that move rows of a stored column
+                with self._op_span(n.name, len(ts.valid_output_rows),
+                                   "host"):
+                    if n.name == O.OUTPUT_OP:
+                        src = n.input_columns()[0]
+                        results[n.id] = store[
+                            (src.op.id, src.column)].take_rows(
+                                ts.valid_output_rows)
+                    elif n.name == O.SLICE_OP:
+                        store[(n.id, "output")] = self._run_slice(
+                            n, jr, plan, store)
+                    elif n.name == O.UNSLICE_OP:
+                        store[(n.id, "output")] = self._run_unslice(
+                            n, jr, plan, store)
+                    else:
+                        store[(n.id, "output")] = self._run_sampler(
+                            n, jr, plan, store)
             elif n.id in self.chains:
                 outs = self._run_fused(n, jr, plan, store)
                 for col, b in outs.items():
@@ -1389,6 +1437,13 @@ class TaskEvaluator:
                     for key in [k for k in store if k[0] == pid]:
                         del store[key]
         return results
+
+    def _op_span(self, op: str, rows: int, device: str):
+        """The `evaluate:<op>` span of one op over one task or chunk,
+        with its seconds in scanner_tpu_op_seconds_total{op, device}."""
+        return self.profiler.span(
+            "evaluate:" + op, rows=rows, device=device,
+            counter=_M_OP_SECONDS.labels(op=op, device=device))
 
     # -- builtins (vectorized gathers on the batch) ---------------------
 
@@ -1493,10 +1548,14 @@ class TaskEvaluator:
         sten = np.asarray(stencil, np.int64)
         # a stencilled op's window is one span: the columns brought to
         # where the op runs, and the window's rows looked up in them
+        # (evaluate:window); the same of an op without one is
+        # evaluate:inputs
         window = self.profiler.span(
             "evaluate:window", op=n.name, rows=len(compute),
             counter=_M_WINDOW_SECONDS.labels(op=n.name)) \
-            if has_stencil else contextlib.nullcontext()
+            if has_stencil else self.profiler.span(
+                "evaluate:inputs", op=n.name, rows=len(compute),
+                counter=_M_OP_INPUT_SECONDS.labels(op=n.name))
         with window:
             for i, (c, b) in enumerate(zip(in_cols, in_batches)):
                 if is_device_kernel and isinstance(b.data, np.ndarray) \
@@ -1646,9 +1705,10 @@ class TaskEvaluator:
                         q = p[:, 0]
                         if len(q) and np.array_equal(
                                 q, np.arange(q[0], q[0] + len(q))):
-                            args.append(b.data[q[0]:q[0] + len(q)])
+                            args.append(rows_run(b.data, int(q[0]),
+                                                 len(q)))
                         else:
-                            args.append(b.data[q])
+                            args.append(rows_at(b.data, q))
                 else:
                     if has_stencil:
                         args.append([[b.data[int(j)] for j in row]
@@ -1665,10 +1725,12 @@ class TaskEvaluator:
         track_cost = _cs.enabled() and batched_call \
             and n.effective_device() == DeviceType.TPU
         run_secs = run_flops = run_bytes = 0.0
+        dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=n.name)
+        wait_s = _M_DEVICE_WAIT_SECONDS.labels(op=n.name)
         try:
-            with self.profiler.span(
-                    "evaluate:" + n.name, rows=len(compute),
-                    device=ki.dev_label if is_device_kernel else "host"):
+            with self._op_span(
+                    n.name, len(compute),
+                    ki.dev_label if is_device_kernel else "host"):
                 for lo, hi in run_bounds:
                     ki.maybe_reset(int(compute[lo]))
                     ki._last_row = int(compute[hi - 1])
@@ -1724,33 +1786,38 @@ class TaskEvaluator:
                                     "xla.recompile", op=n.name,
                                     device=ki.dev_label)
                             t_call = time.time()
-                            if new_sig:
-                                # first call of a fresh signature: any
-                                # XLA compile inside lands in the
-                                # compile ledger under this (op,
-                                # device, bucket)
-                                with ki._call_lock, _first_call(
-                                        n.name, ki.dev_label,
-                                        len(exec_sel), repr(sig[1:]),
-                                        track_cost):
-                                    res = ki.kernel.execute(*args)
-                                if track_cost:
-                                    # drain this unmeasured call's
-                                    # queued device work so the NEXT
-                                    # (measured) call times only itself
+                            with self.profiler.span(
+                                    "evaluate:dispatch", op=n.name,
+                                    rows=len(exec_sel),
+                                    counter=dispatch_s):
+                                if new_sig:
+                                    # first call of a fresh signature:
+                                    # any XLA compile inside lands in
+                                    # the compile ledger under this
+                                    # (op, device, bucket)
+                                    with ki._call_lock, _first_call(
+                                            n.name, ki.dev_label,
+                                            len(exec_sel), repr(sig[1:]),
+                                            track_cost):
+                                        res = ki.kernel.execute(*args)
+                                else:
+                                    with ki._call_lock:
+                                        res = ki.kernel.execute(*args)
+                            if track_cost:
+                                # a first call's queued device work is
+                                # drained so the NEXT (measured) call
+                                # times only itself; a measured call is
+                                # blocked on, or async dispatch would
+                                # time the enqueue, not the op
+                                with self.profiler.span(
+                                        "evaluate:device_wait",
+                                        op=n.name, counter=wait_s):
                                     res = _cs.block_until_ready(res)
-                            else:
-                                with ki._call_lock:
-                                    res = ki.kernel.execute(*args)
                             if track_cost and not new_sig:
                                 # measured call seconds joined with the
                                 # analytical descriptor; first calls of
                                 # a signature are excluded so compile
-                                # time never reads as inefficiency.
-                                # Block on the result first: async
-                                # dispatch would otherwise time the
-                                # enqueue, not the op
-                                res = _cs.block_until_ready(res)
+                                # time never reads as inefficiency
                                 call_s = time.time() - t_call
                                 desc = _cs.descriptor_for(
                                     ki.kernel, n.name, ki.dev_label,
@@ -1877,27 +1944,31 @@ class TaskEvaluator:
         use_buckets = _bucketing_enabled()
         ladder = bucket_ladder(batch) if use_buckets else None
 
-        # device staging: ONE host->device move for the head column —
-        # the only HBM traffic the whole chain pays on the input side
-        if _device_staging_enabled() and isinstance(in_b.data, np.ndarray) \
-                and in_b.data.dtype != object:
-            in_b = in_b.to_device(fki.device)
-        if in_b.convert is not None:
-            in_b = in_b.converted()
-        store[(in_col.op.id, in_col.column)] = in_b
-
         compute = np.asarray(ts.compute_rows, np.int64)
         out_cols = [c for c, _ in n.spec.output_columns]
         valid_out = np.asarray(ts.valid_output_rows, np.int64)
         valid_set = set(valid_out.tolist())
-
-        # composed window positions per tail compute row (REPEAT_EDGE
-        # at every member level = the staged transitive dilation)
         width = fki.width
-        win_rows = fki.compose_positions(compute, max_in).reshape(
-            len(compute), width)
-        col_pos = in_b.positions(win_rows.reshape(-1)).reshape(
-            win_rows.shape)
+        with self.profiler.span(
+                "evaluate:inputs", op=fki.chain_id, rows=len(compute),
+                counter=_M_OP_INPUT_SECONDS.labels(op=fki.chain_id)):
+            # device staging: ONE host->device move for the head column
+            # — the only HBM traffic the whole chain pays on the input
+            # side
+            if _device_staging_enabled() \
+                    and isinstance(in_b.data, np.ndarray) \
+                    and in_b.data.dtype != object:
+                in_b = in_b.to_device(fki.device)
+            if in_b.convert is not None:
+                in_b = in_b.converted()
+            store[(in_col.op.id, in_col.column)] = in_b
+            # composed window positions per tail compute row
+            # (REPEAT_EDGE at every member level = the staged transitive
+            # dilation)
+            win_rows = fki.compose_positions(compute, max_in).reshape(
+                len(compute), width)
+            col_pos = in_b.positions(win_rows.reshape(-1)).reshape(
+                win_rows.shape)
 
         # null propagation across the whole chain in one step
         null_in = np.zeros(len(compute), bool)
@@ -1961,7 +2032,7 @@ class TaskEvaluator:
             body re-folds the window axes member by member)."""
             p = col_pos[sel].reshape(-1)
             if is_array_data(in_b.data):
-                return in_b.data[p]
+                return rows_at(in_b.data, p)
             # object column: stack per-row host data into one array
             return np.stack([np.asarray(in_b.data[int(j)]) for j in p])
 
@@ -1969,9 +2040,11 @@ class TaskEvaluator:
         # chains are always batched TPU dispatch by construction
         track_cost = _cs.enabled()
         run_secs = run_flops = run_bytes = 0.0
+        dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=fki.chain_id)
+        wait_s = _M_DEVICE_WAIT_SECONDS.labels(op=fki.chain_id)
         try:
-            with self.profiler.span("evaluate:" + fki.chain_id,
-                                    rows=len(compute)):
+            with self._op_span(fki.chain_id, len(compute),
+                               fki.dev_label):
                 i = 0
                 while i < len(compute):
                     j = min(i + batch, len(compute))
@@ -2008,21 +2081,28 @@ class TaskEvaluator:
                                            op=fki.chain_id,
                                            device=fki.dev_label)
                     t_call = time.time()
-                    if new_sig:
-                        # fresh signature: ONE ledger entry for the
-                        # whole chain, members recorded for attribution
-                        with fki._call_lock, _first_call(
-                                fki.chain_id, fki.dev_label,
-                                len(exec_sel), repr(sig[1:]), track_cost,
-                                members=fki.member_names):
-                            res = fki.execute(arr)
-                        if track_cost:
+                    with self.profiler.span(
+                            "evaluate:dispatch", op=fki.chain_id,
+                            rows=len(exec_sel), counter=dispatch_s):
+                        if new_sig:
+                            # fresh signature: ONE ledger entry for the
+                            # whole chain, members recorded for
+                            # attribution
+                            with fki._call_lock, _first_call(
+                                    fki.chain_id, fki.dev_label,
+                                    len(exec_sel), repr(sig[1:]),
+                                    track_cost,
+                                    members=fki.member_names):
+                                res = fki.execute(arr)
+                        else:
+                            with fki._call_lock:
+                                res = fki.execute(arr)
+                    if track_cost:
+                        with self.profiler.span(
+                                "evaluate:device_wait", op=fki.chain_id,
+                                counter=wait_s):
                             res = _cs.block_until_ready(res)
-                    else:
-                        with fki._call_lock:
-                            res = fki.execute(arr)
                     if track_cost and not new_sig:
-                        res = _cs.block_until_ready(res)
                         call_s = time.time() - t_call
                         desc, saved = fki.cost_for(arr.shape, arr.dtype)
                         cls = _cs.record_op_call(

@@ -115,6 +115,22 @@ _M_RUN_SECONDS = _mx.registry().counter(
     "start to last join), drain (last save's end to the last saver's "
     "join; inside pipeline), commit (table commits, megafile).",
     labels=["phase"])
+# the parts of run:prepare and run:commit, each the child span of its
+# name (prepare:analyze, prepare:jobs, commit:tables, commit:sinks,
+# commit:megafile); scanner_tpu_run_seconds_total keeps the phases whole
+_M_RUN_PART_SECONDS = _mx.registry().counter(
+    "scanner_tpu_run_part_seconds_total",
+    "Client-thread seconds of local runs by part of a phase: analyze "
+    "(graph analysis, perf estimate) and jobs (sources resolved, tasks "
+    "cut, output tables created) of prepare; tables (a commit_table a "
+    "sink table, each a whole save of the database's metadata), sinks "
+    "(custom sinks' finished) and megafile of commit.",
+    labels=["part"])
+_M_MEGAFILE_BYTES = _mx.registry().counter(
+    "scanner_tpu_megafile_bytes_total",
+    "Bytes of the table megafile written at the end of local runs: the "
+    "descriptor of every committed table of the database, packed anew "
+    "by each run.")
 _M_EVAL_SETUP_SECONDS = _mx.registry().counter(
     "scanner_tpu_evaluator_setup_seconds_total",
     "Evaluator-thread seconds spent opening evaluators: constructing "
@@ -145,6 +161,31 @@ _M_STAGE_WAIT = _mx.registry().counter(
 _M_WAIT_LOAD, _M_WAIT_EVAL, _M_WAIT_EVAL_OUT, _M_WAIT_SAVE = (
     _M_STAGE_WAIT.labels(stage=st)
     for st in ("load", "evaluate", "evaluate_out", "save"))
+# the load stage's parts beside the decode, each the child span of its
+# name inside `load` (load:open, load:assemble, load:stage,
+# load:prestage); what is left of scanner_tpu_stage_seconds_total
+# {stage="load"} less these and the decode is the stage's self time
+_M_LOAD_PART_SECONDS = _mx.registry().counter(
+    "scanner_tpu_load_part_seconds_total",
+    "Loader seconds by part of the load stage: open (a streaming "
+    "task's feeds made: the frame cache consulted and pinned, the "
+    "decoder handle opened), assemble (a chunk's host assembly: the "
+    "decoded frames stacked into one array), stage (the frame cache's "
+    "assembly: fresh rows staged and offered, resident rows gathered; "
+    "the dispatch, not the copy's completion), prestage (device_put "
+    "of the device-bound columns still on the host).",
+    labels=["part"])
+_M_LOAD_OPEN, _M_LOAD_ASSEMBLE, _M_LOAD_STAGE, _M_LOAD_PRESTAGE = (
+    _M_LOAD_PART_SECONDS.labels(part=p)
+    for p in ("open", "assemble", "stage", "prestage"))
+# the evaluate stage's parts that belong to no op (evaluate:merge,
+# evaluate:prefetch, inside the task's `evaluate` span)
+_M_EVAL_PART_SECONDS = _mx.registry().counter(
+    "scanner_tpu_evaluate_part_seconds_total",
+    "Evaluator seconds by part of the evaluate stage outside every op: "
+    "merge (a streaming task's chunk results joined per sink) and "
+    "prefetch (the sinks' device->host copies started at eval-done).",
+    labels=["part"])
 # the save stage's parts, each recorded with the profiler span of the
 # same name at the same two clock reads: the fetch of a sink's batch
 # (save:fetch) and the encode of a frame column's item (save:encode,
@@ -166,6 +207,16 @@ _M_RAW_FRAME_SECONDS = _mx.registry().counter(
     "Saver seconds spent writing items of frame columns that are not "
     "video (span save:raw): a pickle of each row's array, the item's "
     "build (sizes, checksum, one joined buffer) and the backend write.")
+_M_RAW_FRAME_PART_SECONDS = _mx.registry().counter(
+    "scanner_tpu_raw_frame_part_seconds_total",
+    "Saver seconds by part of save:raw (spans raw:pickle, raw:build, "
+    "raw:write): the pickle of each row's array, the item's build "
+    "(sizes, checksum over every byte, one joined buffer) and the "
+    "backend's write and fsync.",
+    labels=["part"])
+_M_RAW_PICKLE, _M_RAW_BUILD, _M_RAW_WRITE = (
+    _M_RAW_FRAME_PART_SECONDS.labels(part=p)
+    for p in ("pickle", "build", "write"))
 _M_RAW_FRAME_BYTES = _mx.registry().counter(
     "scanner_tpu_raw_frame_bytes_total",
     "Bytes of the items written for frame columns that are not video, "
@@ -175,6 +226,13 @@ _M_ENCODE_SECONDS = _mx.registry().counter(
     "Saver seconds spent encoding frame columns to video (span "
     "save:encode): the encoder's creation, feeds, flush and the take "
     "of its packets, one encoder an item.")
+_M_CONTIGUOUS_SECONDS = _mx.registry().counter(
+    "scanner_tpu_save_contiguous_seconds_total",
+    "Saver seconds spent making a video item's rows contiguous uint8 "
+    "frames, each just before the encoder is fed it: inside "
+    "scanner_tpu_encode_seconds_total and the save:encode span, whose "
+    "`contiguous_s` arg is an item's share (a row fetched from a chip "
+    "comes strided as the chip laid it out).")
 _M_ENCODED_FRAMES = _mx.registry().counter(
     "scanner_tpu_encoded_frames_total",
     "Frames the save stage encoded to video.")
@@ -457,11 +515,17 @@ class LocalExecutor:
     def prepare(self, outputs: Sequence[O.OpNode], perf: PerfParams,
                 cache_mode: CacheMode = CacheMode.Error
                 ) -> Tuple[A.GraphInfo, List[JobContext]]:
-        info = A.analyze(outputs)
-        perf = self._estimate_perf(info, perf)
-        jobs: List[JobContext] = []
-        for j in range(info.num_jobs):
-            jobs.append(self._prepare_job(info, j, perf, cache_mode))
+        prof = self.profiler
+        with prof.span("prepare:analyze",
+                       counter=_M_RUN_PART_SECONDS.labels(part="analyze")):
+            info = A.analyze(outputs)
+            perf = self._estimate_perf(info, perf)
+        with prof.span("prepare:jobs", jobs=info.num_jobs,
+                       counter=_M_RUN_PART_SECONDS.labels(part="jobs")
+                       ) as span:
+            jobs = [self._prepare_job(info, j, perf, cache_mode)
+                    for j in range(info.num_jobs)]
+            span.args["tasks"] = sum(len(job.tasks) for job in jobs)
         return info, jobs
 
     def prepare_readonly(self, outputs: Sequence[O.OpNode], perf: PerfParams
@@ -820,15 +884,29 @@ class LocalExecutor:
             _tr.close_span(self.tracer, root)
         with prof.span("run:commit", level=0,
                        counter=_M_RUN_SECONDS.labels(phase="commit")):
-            for job in jobs:
-                if job.skipped:
-                    continue
-                for desc, _c, _k, _e in job.sink_tables.values():
-                    self.db.commit_table(desc.id)
-                for stream in job.custom_sinks.values():
-                    # durability barrier (reference Sink::finished)
-                    stream.storage.finished(stream, job.jr.output_rows)
-            self.db.write_megafile()
+            ran = [job for job in jobs if not job.skipped]
+            tables = [desc.id for job in ran
+                      for desc, _c, _k, _e in job.sink_tables.values()]
+            with prof.span(
+                    "commit:tables", tables=len(tables),
+                    counter=_M_RUN_PART_SECONDS.labels(part="tables")):
+                for table_id in tables:
+                    self.db.commit_table(table_id)
+            with prof.span(
+                    "commit:sinks",
+                    counter=_M_RUN_PART_SECONDS.labels(part="sinks")):
+                for job in ran:
+                    for stream in job.custom_sinks.values():
+                        # durability barrier (reference Sink::finished)
+                        stream.storage.finished(stream,
+                                                job.jr.output_rows)
+            with prof.span(
+                    "commit:megafile",
+                    counter=_M_RUN_PART_SECONDS.labels(part="megafile")
+                    ) as span:
+                packed, nbytes = self.db.write_megafile()
+                span.args = {"tables": packed, "bytes": nbytes}
+            _M_MEGAFILE_BYTES.inc(nbytes)
         return jobs
 
     @staticmethod
@@ -1356,9 +1434,12 @@ class LocalExecutor:
             else:
                 w.results = self._evaluate_with_fallback(info, te, w,
                                                          fb_tls)
-        # start the sink d2h now: the copy rides under the NEXT task's
-        # evaluation instead of blocking the saver
-        self._prefetch_results(w)
+            # start the sink d2h now: the copy rides under the NEXT
+            # task's evaluation instead of blocking the saver
+            with self.profiler.span(
+                    "evaluate:prefetch",
+                    counter=_M_EVAL_PART_SECONDS.labels(part="prefetch")):
+                self._prefetch_results(w)
         dt = time.time() - t0
         _M_STAGE_SECONDS.labels(stage="evaluate").inc(dt)
         _M_STAGE_TASKS.labels(stage="evaluate").inc()
@@ -1521,23 +1602,32 @@ class LocalExecutor:
                 _M_DECODE_SECONDS.labels(loader=lbl).inc(t1 - t0)
                 self._profiler.add_interval("load:decode", t0, t1,
                                             frames=decoded)
-            if self._plan is None:
-                data = np.stack([self._buf[int(r)] for r in rows_arr]) \
-                    if len(rows_arr) else np.zeros((0,), np.uint8)
-            else:
-                # page-gather assembly: fresh (miss) rows of this chunk
-                # feed page completion and stage once; resident rows
-                # gather from the pinned pages on this task's chip
-                fresh_g = sorted(set(rows_arr.tolist()) & self._miss)
+            # the chunk's host assembly: its decoded frames out of the
+            # row -> frame buffer into one array
+            with self._profiler.span(
+                    "load:assemble", counter=_M_LOAD_ASSEMBLE) as span:
+                if self._plan is None:
+                    fresh_g = rows_arr.tolist()
+                    data = np.stack([self._buf[r] for r in fresh_g]) \
+                        if fresh_g else np.zeros((0,), np.uint8)
+                else:
+                    # page-gather assembly: fresh (miss) rows of this
+                    # chunk feed page completion and stage once;
+                    # resident rows gather from the pinned pages on
+                    # this task's chip
+                    fresh_g = sorted(set(rows_arr.tolist()) & self._miss)
+                    data = (np.stack([self._buf[r] for r in fresh_g])
+                            if fresh_g else np.zeros((0, 1), np.uint8))
+                span.args = {"rows": len(fresh_g), "bytes": data.nbytes}
+            if self._plan is not None:
                 fresh_local = np.asarray(fresh_g, np.int64) \
                     - self._item_start
-                fresh_data = (np.stack([self._buf[r] for r in fresh_g])
-                              if fresh_g else np.zeros((0, 1), np.uint8))
-                with self._profiler.span("load:stage", rows=len(rows_arr),
-                                         fresh=len(fresh_g)):
+                with self._profiler.span(
+                        "load:stage", counter=_M_LOAD_STAGE,
+                        rows=len(rows_arr), fresh=len(fresh_g)):
                     data = self._cache.assemble_rows(
                         self._plan, rows_arr - self._item_start,
-                        fresh_local, fresh_data, hw=self._hw)
+                        fresh_local, data, hw=self._hw)
             keep_from = self._keep_from[self._chunk_i]
             self._chunk_i += 1
             for r in [r for r in self._buf if r < keep_from]:
@@ -1549,14 +1639,19 @@ class LocalExecutor:
         task, decoding incrementally and pre-staging device columns so
         the h2d of chunk k+1 rides under the compute of chunk k."""
         feeds: Dict[int, LocalExecutor._VideoFeed] = {}
-        for nid in w.chunk_plans[0].source_rows:
-            si = w.job.source_info[nid]
-            if si.get("is_video") and "custom" not in si:
-                fmt = ("yuv420" if self._yuv_device_wire(info, nid)
-                       else "rgb24")
-                feeds[nid] = self._VideoFeed(
-                    self, w, tls, nid, si, w.chunk_plans, fmt,
-                    use_cache=self._cache_eligible(info, nid))
+        t0 = time.time()
+        with self.profiler.span("load", level=0, task=w.task_idx,
+                                job=w.job.job_idx), \
+                self.profiler.span("load:open", counter=_M_LOAD_OPEN):
+            for nid in w.chunk_plans[0].source_rows:
+                si = w.job.source_info[nid]
+                if si.get("is_video") and "custom" not in si:
+                    fmt = ("yuv420" if self._yuv_device_wire(info, nid)
+                           else "rgb24")
+                    feeds[nid] = self._VideoFeed(
+                        self, w, tls, nid, si, w.chunk_plans, fmt,
+                        use_cache=self._cache_eligible(info, nid))
+        _M_STAGE_SECONDS.labels(stage="load").inc(time.time() - t0)
         for plan in w.chunk_plans:
             elements: Dict[int, ColumnBatch] = {}
             t0 = time.time()
@@ -1624,7 +1719,11 @@ class LocalExecutor:
                 parts.setdefault(sid, []).append(b)
             n += 1
         self.profiler.count("stream_chunks", n)
-        return {sid: concat_batches(lst) for sid, lst in parts.items()}
+        with self.profiler.span(
+                "evaluate:merge", chunks=n,
+                counter=_M_EVAL_PART_SECONDS.labels(part="merge")):
+            return {sid: concat_batches(lst)
+                    for sid, lst in parts.items()}
 
     def _queued_chunks(self, w: TaskItem, stop=None):
         """Evaluator-side: a streaming task's chunks as they arrive over
@@ -1819,7 +1918,8 @@ class LocalExecutor:
         if not _device_staging_enabled():
             return
         cols = w.elements if elements is None else elements
-        with self.profiler.span("load:stage", task=w.task_idx):
+        with self.profiler.span("load:prestage", task=w.task_idx,
+                                counter=_M_LOAD_PRESTAGE):
             for nid, b in cols.items():
                 if self._column_device_bound(info, nid) \
                         and isinstance(b.data, np.ndarray) \
@@ -2016,8 +2116,8 @@ class LocalExecutor:
         if fmt == "yuv420" and not (hw and hw[0]):
             return None  # no geometry for the convert mark: bypass
         try:
-            with self.profiler.span("load:stage", rows=len(rows_l),
-                                    fresh=len(miss)):
+            with self.profiler.span("load:stage", counter=_M_LOAD_STAGE,
+                                    rows=len(rows_l), fresh=len(miss)):
                 data = cache.assemble(plan, miss, frames, hw=hw)
         except _fc.CacheBypass:
             # falling back here re-decodes the miss rows on the direct
@@ -2117,18 +2217,23 @@ class LocalExecutor:
                     # backend's write (span save:raw; one contiguous buffer
                     # a task would pay the write alone)
                     import pickle
-                    with self.profiler.span(
-                            "save:raw", counter=_M_RAW_FRAME_SECONDS,
-                            task=w.task_idx, rows=len(rows)):
-                        item = IT.build_item(
-                            [e if isinstance(e, NullElement)
-                             else pickle.dumps(
-                                 np.asarray(e),
-                                 protocol=pickle.HIGHEST_PROTOCOL)
-                             for e in rows])
-                        self.db.backend.write(
-                            md.column_item_path(desc.id, col_name,
-                                                item_idx), item)
+                    span = self.profiler.span
+                    with span("save:raw", counter=_M_RAW_FRAME_SECONDS,
+                              task=w.task_idx, rows=len(rows)):
+                        with span("raw:pickle", counter=_M_RAW_PICKLE):
+                            blobs = [e if isinstance(e, NullElement)
+                                     else pickle.dumps(
+                                         np.asarray(e),
+                                         protocol=pickle.HIGHEST_PROTOCOL)
+                                     for e in rows]
+                        with span("raw:build", counter=_M_RAW_BUILD):
+                            item = IT.build_item(blobs)
+                            del blobs
+                        with span("raw:write", counter=_M_RAW_WRITE,
+                                  bytes=len(item)):
+                            self.db.backend.write(
+                                md.column_item_path(desc.id, col_name,
+                                                    item_idx), item)
                     _M_RAW_FRAME_BYTES.inc(len(item))
             else:
                 blobs = []
@@ -2237,13 +2342,27 @@ class LocalExecutor:
         try:
             with self.profiler.span("save:encode",
                                     counter=_M_ENCODE_SECONDS,
-                                    item=item_idx, frames=len(frames)):
+                                    item=item_idx, frames=len(frames)
+                                    ) as span:
                 enc = Encoder(w_, h, fps=job.fps or 30.0, codec="libx264",
                               bitrate=int(enc_opts.get("bitrate", 0)),
                               crf=int(enc_opts.get("crf", 20)),
                               keyint=keyint)
+                # a row fetched from a chip comes strided as the chip
+                # laid it out, and the encoder wants it contiguous: a
+                # copy of every frame, made just before its feed so
+                # that the encoder reads it warm (all of an item's
+                # copies first cost a saver 3 ms a row more).  Timed
+                # frame by frame into one number an item: it has a
+                # counter and no span of its own
+                copying = 0.0
                 for f in frames:
+                    t0 = time.time()
+                    f = np.ascontiguousarray(f)
+                    copying += time.time() - t0
                     enc.feed(f)
+                span.args["contiguous_s"] = round(copying, 6)
+                _M_CONTIGUOUS_SECONDS.inc(copying)
                 enc.flush()
                 data, sizes, keys, pts, dts = enc.take_packets()
             _M_ENCODED_FRAMES.inc(len(frames))

@@ -75,7 +75,6 @@ FRAMECACHE_SERIES = (
     "scanner_tpu_framecache_misses_total",
     "scanner_tpu_framecache_inserts_total",
     "scanner_tpu_framecache_evictions_total",
-    "scanner_tpu_framecache_pinned_bytes",
     "scanner_tpu_framecache_live_bytes",
     "scanner_tpu_framecache_capacity_bytes",
     "scanner_tpu_framecache_pressure_shrinks_total",
@@ -104,11 +103,6 @@ _M_EVICTIONS = _mx.registry().counter(
     "scanner_tpu_framecache_evictions_total",
     "Frame-cache pages evicted (LRU capacity eviction or pressure "
     "shrink), per device.",
-    labels=["device"])
-_M_PINNED = _mx.registry().gauge(
-    "scanner_tpu_framecache_pinned_bytes",
-    "Bytes of frame-cache pages currently pinned by in-flight tasks "
-    "(ineligible for eviction), per device.",
     labels=["device"])
 _M_LIVE = _mx.registry().gauge(
     "scanner_tpu_framecache_live_bytes",
@@ -249,6 +243,14 @@ def _runs(seq: List[int]):
         i = j
 
 
+def _programs():
+    """The device programs the cache dispatches — a page's slice, the
+    row gather, the concatenation, the fill's copy, a page's join —
+    each under the scope `framecache` (engine/batch.py row_programs)."""
+    from .batch import row_programs
+    return row_programs("framecache")
+
+
 class CacheBypass(Exception):
     """The cache cannot serve this request (mixed page geometry after a
     table rewrite mid-flight, jax unavailable); callers fall back to the
@@ -365,8 +367,6 @@ class FrameCache:
         self._gauged.add(dev)
         _M_LIVE.labels(device=dev).set_function(
             lambda d=dev: float(self._live.get(d, 0)))
-        _M_PINNED.labels(device=dev).set_function(
-            lambda d=dev: float(self._pinned.get(d, 0)))
         _M_CAPACITY.labels(device=dev).set_function(
             lambda d=dev: float(self._capacity(d)))
 
@@ -491,15 +491,16 @@ class FrameCache:
         # them would stall every other loader's cache consultation.
         # Copying out of the task's block matters: retaining the block
         # itself would pin the whole task batch in HBM until the page
-        # completes, and jnp.array forces a distinct buffer (a
-        # full-range slice would alias the block).
-        import jax.numpy as jnp
+        # completes, and the copy program's result is a distinct buffer
+        # (a full-range slice would alias the block).
+        copy = _programs().copy
         staged: List[Tuple[Tuple, int, int, int,
                            Dict[int, Tuple[Any, int]]]] = []
         for fkey, pidx, start, plen, sel in claims:
             m: Dict[int, Tuple[Any, int]] = {}
             for lo, hi in _runs(sel):
-                frag = jnp.array(block[sel[lo]:sel[hi - 1] + 1])
+                frag = copy(block, sel[lo], sel[hi - 1] + 1 - sel[lo],
+                            np.zeros((), block.dtype))
                 _ms.track_array(
                     frag, "cache",
                     device=plan.dev if plan.device is not None else None)
@@ -566,7 +567,7 @@ class FrameCache:
         consecutive offsets in one block become a single slice) and
         insert it, evicting LRU unpinned pages past the capacity
         target."""
-        import jax.numpy as jnp
+        programs = _programs()
         key = (plan.dev,) + plan.skey + (pidx,)
         try:
             if _faults.ACTIVE:
@@ -590,7 +591,7 @@ class FrameCache:
                 if off == 0 and j - i == int(frag.shape[0]):
                     parts.append(frag)  # whole fragment, reuse as-is
                 else:
-                    parts.append(frag[off:off + (j - i)])
+                    parts.append(programs.slice(frag, off, j - i))
                 i = j
             if len(parts) == 1 and parts[0] is buf[rows[0]][0]:
                 # single whole fragment: already pool-owned and
@@ -598,7 +599,7 @@ class FrameCache:
                 data = parts[0]
             else:
                 data = parts[0] if len(parts) == 1 \
-                    else jnp.concatenate(parts, axis=0)
+                    else programs.concat(*parts)
                 _ms.track_array(data, "cache",
                                 device=plan.dev
                                 if plan.device is not None else None)
@@ -651,7 +652,7 @@ class FrameCache:
     def _assemble(self, plan: Plan, rows: np.ndarray,
                   fresh_rows: np.ndarray, fresh_data: np.ndarray,
                   hw: Optional[Tuple[int, int]] = None) -> Any:
-        import jax.numpy as jnp
+        programs = _programs()
         fresh_rows = np.asarray(fresh_rows, np.int64)
         pf = plan.page_frames
         # classify each requested row: resident page (hit at plan time
@@ -695,11 +696,13 @@ class FrameCache:
             seg_rows = rows[lo:hi]
             if page is not None:
                 local = seg_rows - page.start
-                if len(local) > 1 and bool((np.diff(local) == 1).all()):
-                    parts.append(page.data[int(local[0]):
-                                           int(local[-1]) + 1])
+                if len(local) == page.n:
+                    parts.append(page.data)  # the whole page, as it is
+                elif len(local) > 1 and bool((np.diff(local) == 1).all()):
+                    parts.append(programs.slice(
+                        page.data, int(local[0]), len(local)))
                 else:
-                    parts.append(page.data[jnp.asarray(local)])
+                    parts.append(programs.gather(page.data, local))
             else:
                 pos = np.searchsorted(fresh_rows, seg_rows)
                 if (pos >= len(fresh_rows)).any() or \
@@ -718,11 +721,12 @@ class FrameCache:
                 # never a second h2d for rows the task already shipped
                 self._offer_block(plan, seg_rows, staged, hw)
         if not parts:
+            import jax.numpy as jnp
             return jnp.zeros((0,) + tuple(fresh_data.shape[1:]),
                              fresh_data.dtype)
         if len(parts) == 1:
             return parts[0]
-        return jnp.concatenate(parts, axis=0)
+        return programs.concat(*parts)
 
     # -- pinning / eviction ---------------------------------------------
 

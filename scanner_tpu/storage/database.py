@@ -12,7 +12,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Sequence, Union
+from typing import (Dict, Iterator, List, Optional, Sequence, Tuple,
+                    Union)
 
 from ..common import StorageException
 from . import items, metadata as md
@@ -184,10 +185,11 @@ class Database:
 
     # -- megafile (all table descriptors in one blob) -----------------------
 
-    def write_megafile(self) -> None:
+    def write_megafile(self) -> Tuple[int, int]:
         """Pack every committed table descriptor into one file so cluster
         start-up does one large read instead of N small ones (reference
-        write_table_megafile, metadata.cpp)."""
+        write_table_megafile, metadata.cpp).  Returns the tables packed
+        and the file's bytes."""
         with self._lock:
             blobs = {}
             for name, tid in self.meta.tables.items():
@@ -197,7 +199,9 @@ class Database:
                     blobs[str(tid)] = self.table_descriptor(tid).to_dict()
                 except StorageException:
                     continue
-            self.backend.write(md.megafile_path(), md.pack(blobs))
+            packed = md.pack(blobs)
+            self.backend.write(md.megafile_path(), packed)
+            return len(blobs), len(packed)
 
     def load_megafile(self) -> None:
         with self._lock:

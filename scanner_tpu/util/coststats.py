@@ -53,6 +53,7 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from . import metrics as _mx
+from . import profiler as _profiler
 from . import tracing as _tracing
 from .log import get_logger
 
@@ -73,12 +74,24 @@ _M_COMPILE_SECONDS = _mx.registry().counter(
     "persistent-cache retrieval time on hits) per op and device — the "
     "compile bill the recompile counter only counted.",
     labels=["op", "device"])
-_M_COMPILE_EXEC_BYTES = _mx.registry().counter(
-    "scanner_tpu_compile_executable_bytes_total",
-    "Generated-code bytes of executables minted at observed compiles, "
-    "per op and device (0 when the backend reports no code size) — "
-    "the executable footprint the bucket ladder bounds.",
-    labels=["op", "device"])
+# a compile that no observe_compiles block was open for on its thread
+# (a wire conversion, a window gather, a frame-cache slice first met
+# inside a task): series of their own, so scanner_tpu_compile_total
+# keeps counting what the dispatch sites observe and nothing else
+_M_STRAY_COMPILES = _mx.registry().counter(
+    "scanner_tpu_stray_compile_total",
+    "XLA backend compiles that fired on a thread with no "
+    "observe_compiles block open, by the innermost profiler span open "
+    "on that thread (`site`; `none` outside every span) and "
+    "persistent-compilation-cache outcome.",
+    labels=["site", "cache"])
+_M_STRAY_COMPILE_SECONDS = _mx.registry().counter(
+    "scanner_tpu_stray_compile_seconds_total",
+    "Wall seconds inside the compiles that "
+    "scanner_tpu_stray_compile_total counts (cache retrieval time on "
+    "hits included), by site; each is also a `compile` interval of "
+    "the job's profile.",
+    labels=["site"])
 _M_OP_FLOPS = _mx.registry().gauge(
     "scanner_tpu_op_achieved_flops",
     "Achieved FLOP/s per (op, device, bucket): analytical FLOPs from "
@@ -111,7 +124,8 @@ _M_OP_BOUND = _mx.registry().gauge(
 EFFICIENCY_SERIES = (
     "scanner_tpu_compile_total",
     "scanner_tpu_compile_seconds_total",
-    "scanner_tpu_compile_executable_bytes_total",
+    "scanner_tpu_stray_compile_total",
+    "scanner_tpu_stray_compile_seconds_total",
     "scanner_tpu_op_achieved_flops",
     "scanner_tpu_op_achieved_bandwidth_bytes",
     "scanner_tpu_op_efficiency_ratio",
@@ -306,6 +320,7 @@ def classify(device_label: str, flops: Optional[float],
 _EV_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_OUTCOME = {_EV_CACHE_HIT: "hit", _EV_CACHE_MISS: "miss"}
 
 _tls = threading.local()
 
@@ -365,23 +380,44 @@ class _CompileCtx:
 
 
 def _on_duration(event: str, duration: float, **_kw: Any) -> None:
-    ctx = getattr(_tls, "ctx", None)
-    if ctx is None or event != _EV_BACKEND_COMPILE:
+    if event != _EV_BACKEND_COMPILE:
         return
+    ctx = getattr(_tls, "ctx", None)
     # the cache hit/miss event for this compile fired just before the
     # duration lands (observed ordering of jax's compile path); consume
-    ctx.compiles.append((float(duration), ctx.pending_cache or "uncached"))
-    ctx.pending_cache = None
+    if ctx is not None:
+        ctx.compiles.append((float(duration),
+                             ctx.pending_cache or "uncached"))
+        ctx.pending_cache = None
+        return
+    cache, _tls.stray_cache = \
+        getattr(_tls, "stray_cache", None) or "uncached", None
+    _record_stray(float(duration), cache)
 
 
 def _on_event(event: str, **_kw: Any) -> None:
-    ctx = getattr(_tls, "ctx", None)
-    if ctx is None:
+    cache = _CACHE_OUTCOME.get(event)
+    if cache is None:
         return
-    if event == _EV_CACHE_HIT:
-        ctx.pending_cache = "hit"
-    elif event == _EV_CACHE_MISS:
-        ctx.pending_cache = "miss"
+    ctx = getattr(_tls, "ctx", None)
+    if ctx is not None:
+        ctx.pending_cache = cache
+    else:
+        _tls.stray_cache = cache
+
+
+def _record_stray(seconds: float, cache: str) -> None:
+    """A compile nobody observed: charged to the innermost profiler
+    span open on this thread, and an interval `compile` of that span's
+    profile that ends now (the event fires as the compile returns)."""
+    span = _profiler.current_span()
+    site = span.name if span is not None else "none"
+    _M_STRAY_COMPILES.labels(site=site, cache=cache).inc()
+    _M_STRAY_COMPILE_SECONDS.labels(site=site).inc(seconds)
+    if span is not None:
+        end = time.time()
+        span.prof.add_interval("compile", end - seconds, end,
+                               site=site, cache=cache)
 
 
 _install_lock = threading.Lock()
@@ -536,9 +572,6 @@ def _record_compiles(ctx: _CompileCtx) -> None:
     for secs, c in ctx.compiles:
         _M_COMPILES.labels(op=ctx.op, device=ctx.device, cache=c).inc()
     _M_COMPILE_SECONDS.labels(op=ctx.op, device=ctx.device).inc(total_s)
-    if ctx.exec_bytes:
-        _M_COMPILE_EXEC_BYTES.labels(op=ctx.op, device=ctx.device).inc(
-            ctx.exec_bytes)
     # the compile lands on the span that paid for it (warm-up runs
     # outside any trace; dispatch-site compiles pin to the task's op
     # span next to the existing xla.recompile event)

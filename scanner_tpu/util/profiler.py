@@ -27,6 +27,16 @@ _M_EVENTS = _mx.registry().counter(
     labels=["event"])
 
 
+# the innermost open span of each thread: what a compile that nobody
+# observes is charged to (util/coststats.py names it as the `site`)
+_open = threading.local()
+
+
+def current_span() -> Optional["_Span"]:
+    """The innermost `Profiler.span` block open on this thread."""
+    return getattr(_open, "span", None)
+
+
 @dataclass
 class Interval:
     name: str
@@ -164,7 +174,7 @@ _NULL_SPAN = _NullSpan()
 
 class _Span:
     __slots__ = ("prof", "name", "args", "counter", "keep", "start",
-                 "_trace")
+                 "_trace", "_outer")
 
     def __init__(self, prof: Profiler, name: str, args, counter=None,
                  keep: bool = True):
@@ -175,6 +185,8 @@ class _Span:
         self.keep = keep
 
     def __enter__(self):
+        self._outer = getattr(_open, "span", None)
+        _open.span = self
         self.start = time.time()
         # hot paths are instrumented ONCE: when a trace context is
         # active on this thread (util/tracing.py), the same with-block
@@ -186,6 +198,7 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         end = time.time()
+        _open.span = self._outer
         if self.counter is not None:
             self.counter.inc(end - self.start)
         if self.keep and self.prof._room():
