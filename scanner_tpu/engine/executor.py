@@ -17,6 +17,7 @@ re-derived per task), so any instance may take any task.
 
 from __future__ import annotations
 
+import bisect
 import queue
 import struct
 import threading
@@ -169,15 +170,25 @@ _M_LOAD_PART_SECONDS = _mx.registry().counter(
     "scanner_tpu_load_part_seconds_total",
     "Loader seconds by part of the load stage: open (a streaming "
     "task's feeds made: the frame cache consulted and pinned, the "
-    "decoder handle opened), assemble (a chunk's host assembly: the "
-    "decoded frames stacked into one array), stage (the frame cache's "
-    "assembly: fresh rows staged and offered, resident rows gathered; "
-    "the dispatch, not the copy's completion), prestage (device_put "
-    "of the device-bound columns still on the host).",
+    "decoder handle opened), assemble (a chunk's host assembly beside "
+    "the decode that fills it: the array allocated, the rows held from "
+    "an earlier chunk copied in, a row moved to its slot), stage (the "
+    "frame cache's assembly: fresh rows staged and offered, resident "
+    "rows gathered; the dispatch, not the copy's completion), prestage "
+    "(device_put of the device-bound columns still on the host).",
     labels=["part"])
 _M_LOAD_OPEN, _M_LOAD_ASSEMBLE, _M_LOAD_STAGE, _M_LOAD_PRESTAGE = (
     _M_LOAD_PART_SECONDS.labels(part=p)
     for p in ("open", "assemble", "stage", "prestage"))
+_M_LOAD_ASSEMBLED = _mx.registry().counter(
+    "scanner_tpu_load_assembled_rows_total",
+    "Decoded rows of streaming chunks by how each came to lie in the "
+    "array its chunk is staged from: direct (the codec wrote it into "
+    "its slot), carried (copied from an earlier chunk's array: a "
+    "stencil's back-reach), moved (decoded off its slot and copied to "
+    "it: an open-GOP head delivered late, a chunk that skips rows a "
+    "later one wants).",
+    labels=["how"])
 # the evaluate stage's parts that belong to no op (evaluate:merge,
 # evaluate:prefetch, inside the task's `evaluate` span)
 _M_EVAL_PART_SECONDS = _mx.registry().counter(
@@ -1499,12 +1510,13 @@ class LocalExecutor:
 
     class _VideoFeed:
         """Incremental frame supply for one video source node of one
-        streaming task: per-item decoder sessions
-        (DecoderAutomata.stream_frames) chained in row order, a small
-        row->frame buffer, and retention driven by the later chunks'
-        minimum row so stencil back-reach is served from memory instead
-        of a per-chunk keyframe re-decode (the reference's element
-        cache, evaluate_worker.h:207-218)."""
+        streaming task: one decode session over the task's item
+        (video.automata.StreamSession) that writes each chunk's frames
+        into the array the chunk is staged from, and retention of the
+        rows later chunks reach back to, driven by their minimum row, so
+        stencil back-reach is served from memory instead of a per-chunk
+        keyframe re-decode (the reference's element cache,
+        evaluate_worker.h:207-218)."""
 
         def __init__(self, ex: "LocalExecutor", w: TaskItem, tls,
                      node_id: int, si, plans: List[A.TaskPlan],
@@ -1526,10 +1538,14 @@ class LocalExecutor:
             self._keep_from = list(reversed(suffix))  # per chunk index
             self._chunk_i = 0
             self._profiler = ex.profiler
-            self._buf: Dict[int, Any] = {}
+            # row -> frame of the rows a later chunk still needs: its
+            # row of the chunk array it was decoded into (dropped as
+            # _keep_from says, the array with it), or memory of its
+            # own for a row that was not decoded into its slot
+            self._held: Dict[int, np.ndarray] = {}
 
-            # decode in slices matched to the chunk row count so peak
-            # scratch/buffer is ~one work packet, not a fixed constant
+            # feed the codec packets in slices matched to the chunk row
+            # count: a call fills about one work packet
             wp_est = max(4, max(len(p.source_rows[node_id])
                                 for p in plans))
 
@@ -1567,71 +1583,104 @@ class LocalExecutor:
                 self._miss = set((plan.miss_rows
                                   + item_start).tolist())
                 decode_rows = plan.miss_rows + item_start
+            from ..video.automata import StreamSession
+            self._session = StreamSession(
+                auto, (decode_rows - item_start).tolist(),
+                packets_per_call=wp_est)
 
-            def gen():
-                for rr, fr in auto.stream_frames(
-                        (decode_rows - item_start).tolist(),
-                        packets_per_call=wp_est,
-                        max_frames_per_yield=wp_est):
-                    yield rr + item_start, fr
+        def _decode_into(self, out: np.ndarray) -> List[int]:
+            """The session's next len(out) rows decoded into `out`;
+            returns them as table rows, out[i] holding the i-th."""
+            lbl = threading.current_thread().name
+            codec0 = self._auto.codec_frames
+            with self._profiler.span(
+                    "load:decode", frames=len(out),
+                    counter=_M_DECODE_SECONDS.labels(loader=lbl)):
+                got = self._session.decode_into(out)
+            _M_DECODED.labels(loader=lbl).inc(len(got))
+            _M_CODEC_FRAMES.labels(loader=lbl).inc(
+                self._auto.codec_frames - codec0)
+            return (got + self._item_start).tolist()
 
-            self._gen = gen() if len(decode_rows) else iter(())
+        def _fill(self, fresh: List[int]) -> np.ndarray:
+            """The chunk's host assembly: its array, slot i row
+            fresh[i]'s (strictly ascending, as a ColumnBatch's rows
+            are).  A row held from an earlier chunk is copied in, the
+            others are decoded where they lie, in runs of consecutive
+            slots; each is counted by how it came there."""
+            with self._profiler.span("load:assemble",
+                                     counter=_M_LOAD_ASSEMBLE) as span:
+                data = np.empty((len(fresh),) + self._auto.frame_shape,
+                                np.uint8)
+                # the interval keeps this dict, so its counts read as
+                # they stand when the chunk is whole
+                span.args = how = dict(rows=len(fresh), bytes=data.nbytes,
+                                       direct=0, carried=0, moved=0)
+                lack: Dict[int, int] = {}  # row -> slot, ascending
+                for i, r in enumerate(fresh):
+                    held = self._held.get(r)
+                    if held is None:
+                        lack[r] = i
+                    else:
+                        data[i] = held
+                        how["carried"] += 1
+            while lack:
+                i = next(iter(lack.values()))
+                n = 1
+                while i + n < len(fresh) and fresh[i + n] in lack:
+                    n += 1
+                got = self._decode_into(data[i:i + n])
+                m = 0
+                while m < len(got) and got[m] == fresh[i + m]:
+                    del lack[got[m]]
+                    m += 1
+                how["direct"] += m
+                if m == len(got):
+                    continue
+                # from here on other rows than the slots', or in another
+                # order (an open-GOP head that the retry delivers late;
+                # a chunk that skips rows a later one wants): out of
+                # the slots, each to its own or to wait for its chunk
+                with self._profiler.span("load:assemble",
+                                         rows=len(got) - m,
+                                         counter=_M_LOAD_ASSEMBLE):
+                    for r, f in zip(got[m:],
+                                    data[i + m:i + len(got)].copy()):
+                        if r in lack:
+                            data[lack.pop(r)] = f
+                            how["moved"] += 1
+                        else:
+                            self._held[r] = f
+            for h in ("direct", "carried", "moved"):
+                if how[h]:
+                    _M_LOAD_ASSEMBLED.labels(how=h).inc(how[h])
+            return data
 
         def batch_for(self, rows: Sequence[int]) -> ColumnBatch:
             rows_arr = np.asarray(rows, np.int64)
             if self._plan is None:
-                need = set(rows_arr.tolist()) - self._buf.keys()
+                fresh = rows_arr.tolist()
             else:
-                need = (set(rows_arr.tolist()) & self._miss) \
-                    - self._buf.keys()
-            t0 = time.time()
-            decoded = 0
-            codec0 = self._auto.codec_frames
-            while need:
-                rr, fr = next(self._gen)  # StopIteration = decode bug
-                for r, f in zip(rr.tolist(), fr):
-                    self._buf[r] = f
-                decoded += len(fr)
-                need -= set(rr.tolist())
-            if decoded:
-                t1 = time.time()
-                lbl = threading.current_thread().name
-                _M_DECODED.labels(loader=lbl).inc(decoded)
-                _M_CODEC_FRAMES.labels(loader=lbl).inc(
-                    self._auto.codec_frames - codec0)
-                _M_DECODE_SECONDS.labels(loader=lbl).inc(t1 - t0)
-                self._profiler.add_interval("load:decode", t0, t1,
-                                            frames=decoded)
-            # the chunk's host assembly: its decoded frames out of the
-            # row -> frame buffer into one array
-            with self._profiler.span(
-                    "load:assemble", counter=_M_LOAD_ASSEMBLE) as span:
-                if self._plan is None:
-                    fresh_g = rows_arr.tolist()
-                    data = np.stack([self._buf[r] for r in fresh_g]) \
-                        if fresh_g else np.zeros((0,), np.uint8)
-                else:
-                    # page-gather assembly: fresh (miss) rows of this
-                    # chunk feed page completion and stage once;
-                    # resident rows gather from the pinned pages on
-                    # this task's chip
-                    fresh_g = sorted(set(rows_arr.tolist()) & self._miss)
-                    data = (np.stack([self._buf[r] for r in fresh_g])
-                            if fresh_g else np.zeros((0, 1), np.uint8))
-                span.args = {"rows": len(fresh_g), "bytes": data.nbytes}
+                # page-gather assembly: fresh (miss) rows of this chunk
+                # feed page completion and stage once; resident rows
+                # gather from the pinned pages on this task's chip
+                fresh = sorted(set(rows_arr.tolist()) & self._miss)
+            data = self._fill(fresh)
+            keep_from = self._keep_from[self._chunk_i]
+            self._chunk_i += 1
+            for r in [r for r in self._held if r < keep_from]:
+                del self._held[r]
+            for i in range(bisect.bisect_left(fresh, keep_from), len(fresh)):
+                self._held[fresh[i]] = data[i]
             if self._plan is not None:
-                fresh_local = np.asarray(fresh_g, np.int64) \
+                fresh_local = np.asarray(fresh, np.int64) \
                     - self._item_start
                 with self._profiler.span(
                         "load:stage", counter=_M_LOAD_STAGE,
-                        rows=len(rows_arr), fresh=len(fresh_g)):
+                        rows=len(rows_arr), fresh=len(fresh)):
                     data = self._cache.assemble_rows(
                         self._plan, rows_arr - self._item_start,
                         fresh_local, data, hw=self._hw)
-            keep_from = self._keep_from[self._chunk_i]
-            self._chunk_i += 1
-            for r in [r for r in self._buf if r < keep_from]:
-                del self._buf[r]
             return ColumnBatch(rows_arr, data, convert=self.convert)
 
     def _iter_chunk_items(self, info: A.GraphInfo, w: TaskItem, tls):

@@ -120,9 +120,11 @@ class DecoderAutomata:
         self.output_format = output_format
         self.decoder = Decoder(vd.codec, vd.extradata, vd.width, vd.height,
                                n_threads, output_format=output_format)
-        # reused decode scratch (grown geometrically) — avoids a fresh
-        # multi-MB allocation per decode run (reference keeps pooled
-        # buffers for the same reason, util/memory.cpp BlockAllocator)
+        # get_frames' scratch for a request that spans several runs or
+        # repeats rows (grown geometrically, reused across calls; the
+        # reference pools buffers for the same reason, util/memory.cpp
+        # BlockAllocator).  Streaming decodes (StreamSession) and the
+        # one-run fast path write where the caller says and use none.
         self._scratch = np.empty(0, np.uint8)
 
     @property
@@ -131,6 +133,14 @@ class DecoderAutomata:
         if self.output_format == "yuv420":
             return yuv420_frame_bytes(self.vd.height, self.vd.width)
         return self.vd.height * self.vd.width * 3
+
+    @property
+    def frame_shape(self) -> Tuple[int, ...]:
+        """Shape of one delivered frame: (h, w, 3) for "rgb24", the
+        planar row (frame_bytes,) for "yuv420"."""
+        if self.output_format == "rgb24":
+            return (self.vd.height, self.vd.width, 3)
+        return (self.frame_bytes,)
 
     @property
     def codec_frames(self) -> int:
@@ -214,82 +224,16 @@ class DecoderAutomata:
     def stream_frames(self, rows: Sequence[int], packets_per_call: int = 16,
                       max_frames_per_yield: int = 16):
         """Incrementally decode ascending unique display rows, yielding
-        ``(row_array, frames_array)`` slices as the codec emits them.
-
-        One decode session per keyframe run: packets are fed in slices of
-        ``packets_per_call`` through repeated bounded
-        ``decode_run_pts_stream`` calls WITHOUT resetting the codec (the
-        C layer stops — does not error — at ``max_frames_per_yield``
-        matched frames and reports the packets it consumed, so the
-        output buffer is a work packet, not a packet run plus a
-        reorder-delay margin).  Peak memory is one yield slice.  This is
-        the work-packet streaming loader's decode primitive (reference
-        element cache + feeder threads, evaluate_worker.h:207-218 /
-        decoder_automata.cpp).  Frames arrive in display order; yields
-        are disjoint and cover exactly `rows`.  Open-GOP / false-keyframe
-        retries restart the run from an earlier keyframe for the
-        still-undelivered tail only.
-        """
-        rows_arr = np.unique(np.asarray(list(rows), np.int64))
-        if len(rows_arr) == 0:
-            return
-        frame_bytes = self.frame_bytes
-        shape_tail = ((self.vd.height, self.vd.width, 3)
-                      if self.output_format == "rgb24" else (frame_bytes,))
-        pts_all = np.asarray(self.vd.sample_pts, np.int64)
-        empty_sizes = np.zeros(0, np.uint64)
-        empty_pts = np.zeros(0, np.int64)
-        for run in self.index.plan(rows_arr):
-            out_disp = np.asarray(run.out_disp, np.int64)
-            start = run.start_dec
-            while True:  # open-GOP / false-keyframe retry loop
-                rem_rows = out_disp
-                rem_pts = pts_all[self.index.dec_of_disp[rem_rows]]
-                self.decoder.reset()
-                pos = start
-                while len(rem_rows):
-                    if pos <= run.end_dec:
-                        end = min(pos + packets_per_call - 1, run.end_dec)
-                        data, sizes = self._read_packets(pos, end)
-                        pkt_pts = pts_all[pos:end + 1]
-                    else:
-                        # flush-only continuation: harvest codec backlog
-                        data, sizes, pkt_pts = b"", empty_sizes, empty_pts
-                        end = pos - 1
-                    buf = self._scratch_buf(
-                        max_frames_per_yield * frame_bytes)
-                    n, oh, ow, deliv, consumed = \
-                        self.decoder.decode_run_pts_stream(
-                            data, sizes, pkt_pts, rem_pts,
-                            buf[:max_frames_per_yield * frame_bytes],
-                            max_frames=max_frames_per_yield,
-                            flush=(end >= run.end_dec))
-                    if n and (oh, ow) != (self.vd.height, self.vd.width):
-                        raise ScannerException(
-                            f"decoded geometry {oh}x{ow} != descriptor "
-                            f"{self.vd.height}x{self.vd.width}")
-                    if n:
-                        got = buf[:n * frame_bytes].reshape(
-                            (n,) + shape_tail).copy()
-                        yield rem_rows[deliv], got
-                    rem_rows = rem_rows[~deliv]
-                    rem_pts = rem_pts[~deliv]
-                    pos += consumed
-                    if pos > run.end_dec and n == 0 and consumed == 0:
-                        break  # flushed dry; tail undeliverable here
-                if not len(rem_rows):
-                    break
-                # leading open-GOP frames (or a false keyframe): retry the
-                # undelivered tail from one keyframe earlier
-                out_disp = rem_rows
-                ki = int(np.searchsorted(self.index.kf_decs, start,
-                                         side="right")) - 1
-                if ki <= 0 or start <= 0:
-                    raise ScannerException(
-                        f"frames with pts {rem_pts[:5].tolist()} not "
-                        f"delivered (run {start}..{run.end_dec}; stream "
-                        f"damaged or index stale)")
-                start = int(self.index.kf_decs[ki - 1])
+        ``(row_array, frames_array)`` slices of at most
+        ``max_frames_per_yield`` frames: a StreamSession driven into a
+        fresh array a yield, so every yield owns its memory.  Yields are
+        disjoint and cover exactly `rows`; peak memory is one yield."""
+        session = StreamSession(self, rows, packets_per_call)
+        while session.remaining:
+            out = np.empty(
+                (min(max_frames_per_yield, session.remaining),)
+                + self.frame_shape, np.uint8)
+            yield session.decode_into(out), out
 
     def get_frames(self, rows: Sequence[int]) -> np.ndarray:
         """Decode exactly the given display-order frame indices.
@@ -300,11 +244,8 @@ class DecoderAutomata:
         (len(rows), frame_bytes) planar I420 rows for "yuv420".
         """
         rows_arr = np.asarray(list(rows), np.int64)
-        h, w = self.vd.height, self.vd.width
         frame_bytes = self.frame_bytes
-        shape = ((len(rows_arr), h, w, 3)
-                 if self.output_format == "rgb24"
-                 else (len(rows_arr), frame_bytes))
+        shape = (len(rows_arr),) + self.frame_shape
         if len(rows_arr) == 0:
             return np.zeros(shape, np.uint8)
         runs = self.index.plan(rows_arr)
@@ -330,3 +271,113 @@ class DecoderAutomata:
                 for pos in positions.get(int(d), ()):
                     result[pos] = out[i]
         return result
+
+
+class StreamSession:
+    """One incremental decode of ascending unique display rows of one
+    automaton, driven by the caller's destination: each
+    ``decode_into(out)`` writes the next ``len(out)`` rows' frames where
+    the caller will use them.  This is the work-packet streaming
+    loader's decode primitive (reference element cache + feeder threads,
+    evaluate_worker.h:207-218 / decoder_automata.cpp).
+
+    One codec session per keyframe run: packets are fed in slices of
+    ``packets_per_call`` through repeated bounded
+    ``decode_run_pts_stream`` calls WITHOUT resetting the codec (the C
+    layer stops — does not error — once the slice is full, reports the
+    packets it consumed and keeps its backlog for the next call), so
+    the destination is a work packet, not a packet run plus a
+    reorder-delay margin.  Frames arrive in display order.  Open-GOP /
+    false-keyframe retries restart the run from an earlier keyframe for
+    the still-undelivered rows only, so those rows come after later
+    ones: the rows returned say what each slot holds.
+    """
+
+    _NO_SIZES = np.zeros(0, np.uint64)
+    _NO_PTS = np.zeros(0, np.int64)
+
+    def __init__(self, auto: DecoderAutomata, rows: Sequence[int],
+                 packets_per_call: int = 16):
+        rows_arr = np.unique(np.asarray(list(rows), np.int64))
+        self._auto = auto
+        self._packets_per_call = packets_per_call
+        self._pts_all = np.asarray(auto.vd.sample_pts, np.int64)
+        self._runs = iter(auto.index.plan(rows_arr))
+        # rows no decode_into has delivered yet, over all runs
+        self.remaining = len(rows_arr)
+        # the open run: its undelivered rows and their timestamps, its
+        # last packet, the keyframe packet this attempt started from
+        # and the next packet to feed
+        self._rows = self._pts = self._NO_PTS
+        self._end_dec = self._start = self._pos = -1
+
+    def _open(self, start: int) -> None:
+        """(Re)start the open run's decode at keyframe packet `start`."""
+        self._auto.decoder.reset()
+        self._start = self._pos = start
+
+    def decode_into(self, out: np.ndarray) -> np.ndarray:
+        """Decode the next ``min(len(out), remaining)`` rows into `out`
+        (uint8, C-contiguous, ``(k,) + frame_shape`` or any shape of
+        ``frame_bytes`` a row); nothing past them is written.  Returns
+        their rows: ``out[i]`` holds row ``result[i]``, ascending but for
+        rows an open-GOP retry delivered late."""
+        auto = self._auto
+        frame_bytes = auto.frame_bytes
+        if out.dtype != np.uint8 or not out.flags["C_CONTIGUOUS"] \
+                or out.nbytes != len(out) * frame_bytes:
+            raise ScannerException(
+                f"decode destination {out.dtype}{out.shape} is not "
+                f"C-contiguous uint8 rows of {frame_bytes} bytes")
+        if len(out) and not self.remaining:
+            raise ScannerException(
+                "decode asked for rows past the end of its session")
+        k = min(len(out), self.remaining)
+        flat = out.reshape(-1)
+        got: List[np.ndarray] = []
+        done = 0
+        while done < k:
+            if not len(self._rows):
+                run = next(self._runs)
+                self._rows = np.asarray(run.out_disp, np.int64)
+                self._pts = self._pts_all[auto.index.dec_of_disp[self._rows]]
+                self._end_dec = run.end_dec
+                self._open(run.start_dec)
+            if self._pos <= self._end_dec:
+                end = min(self._pos + self._packets_per_call - 1,
+                          self._end_dec)
+                data, sizes = auto._read_packets(self._pos, end)
+                pkt_pts = self._pts_all[self._pos:end + 1]
+            else:
+                # flush-only continuation: harvest codec backlog
+                data, sizes, pkt_pts = b"", self._NO_SIZES, self._NO_PTS
+                end = self._pos - 1
+            n, oh, ow, deliv, consumed = \
+                auto.decoder.decode_run_pts_stream(
+                    data, sizes, pkt_pts, self._pts,
+                    flat[done * frame_bytes:k * frame_bytes],
+                    max_frames=k - done, flush=(end >= self._end_dec))
+            if n and (oh, ow) != (auto.vd.height, auto.vd.width):
+                raise ScannerException(
+                    f"decoded geometry {oh}x{ow} != descriptor "
+                    f"{auto.vd.height}x{auto.vd.width}")
+            if n:
+                got.append(self._rows[deliv])
+                done += n
+                self.remaining -= n
+            self._rows = self._rows[~deliv]
+            self._pts = self._pts[~deliv]
+            self._pos += consumed
+            if self._pos > self._end_dec and n == 0 and consumed == 0:
+                # flushed dry with rows undelivered: leading open-GOP
+                # frames (or a false keyframe); retry them from one
+                # keyframe earlier
+                ki = int(np.searchsorted(auto.index.kf_decs, self._start,
+                                         side="right")) - 1
+                if ki <= 0 or self._start <= 0:
+                    raise ScannerException(
+                        f"frames with pts {self._pts[:5].tolist()} not "
+                        f"delivered (run {self._start}..{self._end_dec}; "
+                        f"stream damaged or index stale)")
+                self._open(int(auto.index.kf_decs[ki - 1]))
+        return np.concatenate(got) if got else self._NO_PTS
