@@ -8,7 +8,8 @@ An op's registration declares how the engine schedules it:
   stencil=[...]     each output row sees a window of input rows
                     (REPEAT_EDGE at the boundaries)
   bounded_state=W   stateful with warmup W: the engine replays W rows
-                    before each requested range so state is hot
+                    before each task's own from a reset state, so a
+                    task stands alone (any order, any loader, any chip)
   unbounded_state   stateful with no bounded warmup: rows replay from the
                     start of the stream/slice group
   device=...        DeviceType.TPU kernels get their inputs staged onto
@@ -27,6 +28,7 @@ import numpy as np
 from scanner_tpu import (CacheMode, Client, DeviceType, FrameType, Kernel,
                          NamedStream, NamedVideoStream, PerfParams,
                          register_op)
+import scanner_tpu.kernels  # noqa: F401  (registers BackgroundSubtraction)
 
 
 @register_op(device=DeviceType.TPU, batch=16)
@@ -66,6 +68,13 @@ class RunningMax(Kernel):
         return struct.pack("=d", self.cur)
 
 
+# Upstream's own bounded-state example in this tutorial is a
+# BackgroundSubtraction op (bounded_state=60): a running-average image,
+# reset "when the kernel switches to a new part of the stream".  It is
+# in the stdlib here (kernels/imgproc.py) as a device op whose average
+# lives on the chip between packets; main() runs it beside RunningMax.
+
+
 @register_op(unbounded_state=True)
 class FrameCounter(Kernel):
     """unbounded state: the engine replays from row 0 (or the slice
@@ -95,23 +104,29 @@ def main():
         smoothed = sc.ops.TemporalAverage(frame=frames)
         rmax = sc.ops.RunningMax(bright=bright)
         count = sc.ops.FrameCounter(ignore=frames)
+        moving = sc.ops.BackgroundSubtraction(frame=frames, alpha=0.05,
+                                              threshold=0.05)
 
         outs = [NamedStream(sc, n) for n in
-                ("attrs_bright", "attrs_smooth", "attrs_max", "attrs_n")]
+                ("attrs_bright", "attrs_smooth", "attrs_max", "attrs_n",
+                 "attrs_moving")]
         sc.run([sc.io.Output(bright, [outs[0]]),
                 sc.io.Output(smoothed, [outs[1]]),
                 sc.io.Output(rmax, [outs[2]]),
-                sc.io.Output(count, [outs[3]])],
+                sc.io.Output(count, [outs[3]]),
+                sc.io.Output(moving, [outs[4]])],
                PerfParams.estimate(), cache_mode=CacheMode.Overwrite)
 
         b = list(outs[0].load())
         m = [struct.unpack("=d", x)[0] for x in outs[2].load()]
         n = [struct.unpack("=q", x)[0] for x in outs[3].load()]
         sm = next(iter(outs[1].load()))
+        fg = [struct.unpack("=q", x)[0] for x in outs[4].load()]
         print(f"{len(b)} frames: brightness[0]={b[0]:.1f}, "
               f"running max[-1]={m[-1]:.1f}, count[-1]={n[-1]}, "
-              f"smoothed frame shape={sm.shape}")
-        assert n[-1] == len(b)
+              f"smoothed frame shape={sm.shape}, most foreground "
+              f"pixels in a frame={max(fg)}")
+        assert n[-1] == len(b) == len(fg) and fg[0] == 0
         assert abs(m[-1] - max(b)) < 1e-6
     finally:
         sc.stop()

@@ -143,6 +143,22 @@ _M_OP_PAD_ROWS = _mx.registry().counter(
     "chunks up to a bucket shape (padding waste; the price of never "
     "re-tracing), per op and assigned device.",
     labels=["op", "device"])
+# a stateful op's price: the resets the engine fires on its kernel, and
+# the rows it computes to make state that no consumer asked for
+_M_STATE_RESETS = _mx.registry().counter(
+    "scanner_tpu_state_resets_total",
+    "Resets the engine fired on a stateful kernel inside a stream: at "
+    "the first compute row of a task that stands alone (bounded state "
+    "with a warm-up) and at every row discontinuity.  The reset that "
+    "goes with a stream's binding (new_stream, every kernel) is not "
+    "among them.",
+    labels=["op"])
+_M_STATE_WARMUP_ROWS = _mx.registry().counter(
+    "scanner_tpu_state_warmup_rows_total",
+    "Rows an op computed that no consumer asked for: a task's or "
+    "chunk's compute rows less its valid output rows (a bounded-state "
+    "op's warm-up, an unbounded one's replay from row 0).",
+    labels=["op"])
 _M_OP_PRECOMPILE = _mx.registry().gauge(
     "scanner_tpu_op_precompile_seconds",
     "Seconds the setup-time warm-up spent precompiling this device "
@@ -456,6 +472,17 @@ def _strip_pad(res, k: int, n_out: int):
         return res  # malformed result: let emit_result raise its error
 
 
+def _state_call_lengths(cap: int, warmup: int, wp: int) -> List[int]:
+    """The call lengths of a bounded-state kernel over contiguous tasks
+    cut into `wp`-row chunks: a task's first chunk computes its warm-up
+    and its own rows in calls of `cap`, then a tail; where the warm-up
+    is clipped at row 0 the tail is another.  (Sampled rows make other
+    lengths; those compile at their first call.)"""
+    runs = {warmup + wp} | {cs + wp for cs in range(0, warmup, wp)}
+    return sorted({cap} | {r % cap for r in runs if r % cap},
+                  reverse=True)
+
+
 class StateCarryMiss(Exception):
     """A carry plan's premise failed: the kernel instance's state is not
     positioned at the plan's watermark (task reordering, a failed
@@ -490,6 +517,8 @@ class KernelInstance:
         self.dev_label = device_label(device)
         self._cur_stream: Tuple[int, int] = (-1, -1)  # (job, slice group)
         self._last_row: Optional[int] = None
+        # resets fired so far (reset_state): an op span reads its own
+        self.resets = 0
         self._did_setup = False
         # input (shape, dtype) signatures already executed (XLA recompile
         # proxy — dtype included: equal shapes with different dtypes are
@@ -546,12 +575,24 @@ class KernelInstance:
         self._cur_stream = key
         self._last_row = None
 
+    def reset_state(self) -> None:
+        """A reset the engine fires on this stateful kernel inside a
+        stream (counted; a device kernel's under `evaluate:reset`, since
+        dropping its state frees device memory)."""
+        if self.device is not None:
+            with self.profiler.span("evaluate:reset", op=self.node.name):
+                self.kernel.reset()
+        else:
+            self.kernel.reset()
+        self.resets += 1
+        _M_STATE_RESETS.labels(op=self.node.name).inc()
+
     def maybe_reset(self, row: int) -> None:
         """Reset state at row discontinuities (the reference kernel checks
         element indices itself, test_ops.cpp:183-189; we centralize it)."""
         if self._last_row is not None and row != self._last_row + 1 \
                 and self.spec.is_stateful:
-            self.kernel.reset()
+            self.reset_state()
         self._last_row = row
 
     # -- bucket-ladder warm-up (precompile) ----------------------------
@@ -637,6 +678,11 @@ class KernelInstance:
                                     device=self.dev_label).set(
                 time.time() - t0)
         finally:
+            if self.spec.is_stateful:
+                # the example rows went through the state: drop it before
+                # a task can see it
+                with self._call_lock:
+                    self.kernel.reset()
             _M_WARMING.dec()
             with self._warm_lock:
                 self._warm_state = "done"
@@ -1072,14 +1118,15 @@ def graph_key(info: A.GraphInfo) -> Optional[Tuple]:
     decides fusion, and its input edges by position and column.  Node
     ids, tables, samplers and their arguments, per-stream args and
     output names are not in it.  None: the evaluators of this graph are
-    not to be kept — an init arg that cannot be keyed by value, or a
-    stateful op (its kernels' state is positioned by the run's task
-    order; see run_pipeline)."""
+    not to be kept — an init arg that cannot be keyed by value, or an
+    unbounded-state op (its kernels' state is positioned by the run's
+    task order; see run_pipeline).  A bounded-state kernel is kept: the
+    next run's first task binds its stream anew, which resets it."""
     pos = {n.id: i for i, n in enumerate(info.ops)}
     key = []
     try:
         for n in info.ops:
-            if n.spec is not None and n.spec.is_stateful:
+            if n.spec is not None and n.spec.unbounded_state:
                 return None
             key.append((
                 n.name,
@@ -1254,10 +1301,14 @@ class TaskEvaluator:
         with _LIVE_LOCK:
             _LIVE_EVALUATORS.add(self)
 
-    def _warm_targets(self, precompile: Tuple[int, int, int]
+    def _warm_targets(self, precompile: Tuple[int, int, int],
+                      rewarm: bool = False
                       ) -> List[Tuple[Any, List[int]]]:
         """The warm-up-eligible kernels and their ladders (shared by
-        the constructor warm-up and rewarm)."""
+        the constructor warm-up and rewarm).  A bounded-state kernel
+        whose tasks stand alone is called at exact lengths, so its
+        "ladder" is the lengths its tasks produce; a `rewarm` leaves it
+        out: mid-run its example rows would go through a task's state."""
         _h, _w, wp = precompile
         targets: List[Tuple[Any, List[int]]] = []
         for ki in self.kernels.values():
@@ -1266,7 +1317,7 @@ class TaskEvaluator:
                 continue  # fused members warm as one chain, below
             if n.effective_device() != DeviceType.TPU \
                     or n.effective_batch() <= 1 \
-                    or ki.spec.is_stateful or ki.spec.variadic \
+                    or not n.stands_alone() or ki.spec.variadic \
                     or not _source_geometry_inputs(n):
                 continue
             # same per-call cap derivation as _run_kernel
@@ -1274,6 +1325,11 @@ class TaskEvaluator:
                 cap = max(1, min(n.effective_batch(), int(wp)))
             else:
                 cap = max(1, n.effective_batch())
+            if ki.spec.is_stateful:
+                if not rewarm:
+                    targets.append((ki, _state_call_lengths(
+                        cap, n.bounded_warmup(), int(wp or cap))))
+                continue
             sten = n.effective_stencil()
             if sten != [0] and wp:
                 reach = max(sten) - min(sten)
@@ -1315,7 +1371,7 @@ class TaskEvaluator:
                 or not _bucketing_enabled():
             return 0
         claimed: List[Tuple[Any, List[int]]] = []
-        for ki, ladder in self._warm_targets(hint):
+        for ki, ladder in self._warm_targets(hint, rewarm=True):
             with ki._warm_lock:
                 if ki._warm_state in ("idle", "done"):
                     ki._warm_state = "pending"
@@ -1727,12 +1783,23 @@ class TaskEvaluator:
         run_secs = run_flops = run_bytes = 0.0
         dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=n.name)
         wait_s = _M_DEVICE_WAIT_SECONDS.labels(op=n.name)
+        # a bounded-state task with a warm-up stands alone: its plan
+        # begins with the rows that make its state, so the kernel is
+        # reset at its first compute row whatever ran before (a later
+        # chunk of a streaming task goes on from the chunk before it)
+        fresh_task = n.spec.is_stateful and n.stands_alone() \
+            and not plan.resumes
+        resets0 = ki.resets
+        warm_rows = len(compute) - len(valid_out)
         try:
             with self._op_span(
                     n.name, len(compute),
-                    ki.dev_label if is_device_kernel else "host"):
+                    ki.dev_label if is_device_kernel else "host") as span:
                 for lo, hi in run_bounds:
-                    ki.maybe_reset(int(compute[lo]))
+                    if lo == 0 and fresh_task:
+                        ki.reset_state()
+                    else:
+                        ki.maybe_reset(int(compute[lo]))
                     ki._last_row = int(compute[hi - 1])
                     i = lo
                     while i < hi:
@@ -1846,6 +1913,9 @@ class TaskEvaluator:
                                 res = ki.kernel.execute(*row_args)
                             emit_result(compute[live], _single(res, n, out_cols))
                         i = j
+                if n.spec.is_stateful:
+                    span.args.update(warmup_rows=warm_rows,
+                                     resets=ki.resets - resets0)
                 if run_secs > 0:
                     cls = _cs.classify(ki.dev_label, run_flops or None,
                                        run_bytes, run_secs)
@@ -1879,6 +1949,8 @@ class TaskEvaluator:
                              detail=f"op {n.name} on {ki.dev_label}")
             raise
         _M_OP_ROWS.labels(op=n.name).inc(len(compute))
+        if warm_rows or n.spec.is_stateful:
+            _M_STATE_WARMUP_ROWS.labels(op=n.name).inc(warm_rows)
 
         # assemble output columns in row order; null-propagated rows (rare)
         # interleave with kernel results, so columns containing them fall

@@ -1015,15 +1015,16 @@ class LocalExecutor:
         # evaluator task t+1 before t and every inversion costs a
         # StateCarryMiss reload+recompute — per-task decode parallelism
         # stays available via decoder_threads.
-        # ANY stateful op serializes the same way, chained or not: a
-        # bounded-state kernel's maybe_reset only fires on row
-        # DISCONTINUITY, so an inverted first task (fresh instance,
-        # _last_row still None) would run on virgin state with no reset
-        # and no carry-miss to catch it — order is correctness here,
-        # not a perf knob.
-        stateful = any(n.spec is not None and n.spec.is_stateful
-                       for n in info.ops)
-        serialize = bool(self._chains) or stateful
+        # An unbounded-state op serializes the same way, chained or
+        # not, and so does a bounded-state one with a warm-up of 0 (its
+        # tasks continue one another): their kernels are reset only on
+        # a row DISCONTINUITY, so order is correctness there, not a
+        # perf knob.  A bounded-state task with a warm-up begins with
+        # the rows that make its state and is reset at its first
+        # compute row (evaluate.py _run_kernel): it stands alone, and
+        # the run keeps its loaders and instances.
+        ordered = any(not n.stands_alone() for n in info.ops)
+        serialize = bool(self._chains) or ordered
         n_evals = 1 if serialize else self.pipeline_instances
         # Device-affine routing: when instances own distinct chips, each
         # gets its OWN queue and the loader assigns each task to the
@@ -1876,14 +1877,26 @@ class LocalExecutor:
                 # chunk k+1 rides under the compute of chunk k.
                 plans = []
                 cur = dict(carry) if carry else None
+                # the task's chunks run in turn on one evaluator: a
+                # bounded-state kernel goes on from the chunk before, so
+                # only the rows it has not computed yet are planned (the
+                # task's first chunk carries the warm-up)
+                bounded = [n.id for n in info.ops
+                           if n.bounded_warmup() is not None]
+                computed: Dict[int, int] = {}
                 for cs in range(start, end, wp):
                     p = A.derive_task_streams(
                         info, w.job.jr, (cs, min(cs + wp, end)),
                         job_idx=w.job.job_idx, task_idx=w.task_idx,
-                        carry=cur)
+                        carry=cur,
+                        computed=computed if cs > start else None)
                     if p.carry_watermarks:
                         cur = dict(cur or {})
                         cur.update(p.carry_watermarks)
+                    for nid in bounded:
+                        rows = p.streams[nid].compute_rows
+                        if len(rows):
+                            computed[nid] = int(rows[-1])
                     plans.append(p)
                 # a video source whose rows span multiple table items
                 # keeps the whole-task path: per-item geometry may

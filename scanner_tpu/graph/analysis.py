@@ -318,13 +318,22 @@ class TaskPlan:
     # chain may start its recompute after this watermark
     carry_watermarks: Dict[Tuple[int, int], int] = field(
         default_factory=dict)
+    # a later chunk of a streaming task (derived with `computed`): its
+    # bounded-state kernels go on from the chunk before, no reset
+    resumes: bool = False
 
 
 def derive_task_streams(info: GraphInfo, jr: JobRows,
                         output_range: Tuple[int, int],
                         job_idx: int = 0, task_idx: int = 0,
-                        carry: Optional[Dict[Tuple[int, int], int]] = None
+                        carry: Optional[Dict[Tuple[int, int], int]] = None,
+                        computed: Optional[Dict[int, int]] = None
                         ) -> TaskPlan:
+    """`computed`: this range is a later chunk of one task, whose earlier
+    chunks ran on the same kernel instances: bounded-state node id -> the
+    last row its kernel has computed.  Warm-up rows up to there are not
+    computed again, so the task's chunks together compute what the task
+    derived whole would (one warm-up a run of rows, not one a chunk)."""
     out_rows = np.arange(output_range[0], output_range[1], dtype=np.int64)
 
     required_out: Dict[int, set] = {n.id: set() for n in info.ops}
@@ -404,9 +413,16 @@ def derive_task_streams(info: GraphInfo, jr: JobRows,
                   or n.warmup is not None):
                 warmup = n.warmup if n.warmup is not None \
                     else n.spec.bounded_state
+                done = (computed or {}).get(n.id, -1)
+                if len(downstream) and int(downstream[0]) <= done:
+                    # a row the kernel has passed is asked for again (a
+                    # consumer's stencil reaching back over the chunk's
+                    # edge): the state cannot re-emit it, so this chunk
+                    # replays its warm-up like a task's first
+                    done = -1
                 for r in downstream.tolist():
                     for i in range(warmup + 1):
-                        if r - i >= 0:
+                        if r - i > done:
                             cur.add(r - i)
             compute = np.asarray(sorted(cur), np.int64)
             stencil = n.effective_stencil()
@@ -442,4 +458,5 @@ def derive_task_streams(info: GraphInfo, jr: JobRows,
     return TaskPlan(job_idx=job_idx, task_idx=task_idx,
                     output_range=output_range, streams=streams,
                     source_rows=source_rows, slice_group=slice_group,
-                    carry_watermarks=watermarks)
+                    carry_watermarks=watermarks,
+                    resumes=computed is not None)

@@ -502,6 +502,28 @@ class OpNode:
             return self.spec.device
         return DeviceType.CPU
 
+    def bounded_warmup(self) -> Optional[int]:
+        """The warm-up of a bounded-state node (the node's own
+        `bounded_state=` over the registration's); None for any other.
+        With a warm-up of one row or more a task of the node stands
+        alone: its plan begins with the rows that make its state, so the
+        engine resets the kernel at the task's first compute row and the
+        task may run anywhere, in any order.  A warm-up of 0 says that a
+        task's rows continue the last task's: such a node, like an
+        unbounded one, needs its tasks in order on one instance."""
+        if self.spec is None or self.spec.unbounded_state \
+                or self.spec.bounded_state is None:
+            return None
+        return int(self.spec.bounded_state if self.warmup is None
+                   else self.warmup)
+
+    def stands_alone(self) -> bool:
+        """Stateless, or bounded state with a warm-up: no task of this
+        node depends on what its kernel ran before."""
+        if self.spec is None or not self.spec.is_stateful:
+            return True
+        return (self.bounded_warmup() or 0) >= 1
+
     def __getitem__(self, column: str) -> OpColumn:
         for c in self.outputs:
             if c.column == column:
