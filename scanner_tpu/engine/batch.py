@@ -87,7 +87,15 @@ def row_programs(scope: str) -> SimpleNamespace:
     (`slice`, and `copy`, whose result never shares the operand's
     buffer), one an (array shape, index length) for rows picked by
     index (`gather`), one a tuple of shapes for arrays joined end to
-    end (`concat`).  `copy` adds a zero it is handed at run time
+    end (`concat`), and one a (shape, merged shape) for a sink batch
+    laid out row-major before its copy to the host (`rowmajor`:
+    `data.reshape(merged_row_shape)`, whose result the chip keeps
+    row-major and dense; `ColumnBatch.prefetch_host`).  It is written
+    as the two transpositions the compiler makes of that reshape of a
+    planar array (the merged dimensions brought to the front, where
+    merging them moves nothing, and back): as a bare reshape the first
+    of them is a copy of the parameter, which carries the parameter's
+    name and no scope.  `copy` adds a zero it is handed at run time
     (`copy(data, start, n, zero)`, a 0-d array of the data's type): a
     copy alone lowers to nothing, the compiler copies the unchanged
     parameter itself, and that operation carries no name (the whole
@@ -112,10 +120,18 @@ def row_programs(scope: str) -> SimpleNamespace:
     def concat_rows(*parts):
         return jnp.concatenate(parts, axis=0)
 
+    def rowmajor_rows(data, shape):
+        lead = len(shape) - 1
+        front = jnp.transpose(
+            data, tuple(range(lead, data.ndim)) + tuple(range(lead)))
+        return jnp.transpose(front.reshape(shape[-1:] + shape[:-1]),
+                             tuple(range(1, lead + 1)) + (0,))
+
     return SimpleNamespace(
         slice=scoped(slice_rows, static_argnums=(1, 2)),
         copy=scoped(copy_rows, static_argnums=(1, 2)),
-        gather=scoped(gather_rows), concat=scoped(concat_rows))
+        gather=scoped(gather_rows), concat=scoped(concat_rows),
+        rowmajor=scoped(rowmajor_rows, static_argnums=(1,)))
 
 
 def rows_run(data, start: int, n: int):
@@ -137,6 +153,52 @@ def rows_at(data, idx: np.ndarray):
     return row_programs("columnbatch").gather(data, idx)
 
 
+# the chip keeps a minor dimension at least this long row-major and
+# dense (a vector register's lanes); a shorter one it lays out as planes
+_MINOR_LEN = 128
+
+
+def merged_row_shape(shape: tuple) -> tuple:
+    """`shape` with its trailing dimensions merged until the minor one
+    is long (the row axis stays): (n, 1080, 1920, 3) -> (n, 1080, 5760).
+    The same elements in the same row-major order, so the host's
+    reshape back is a view."""
+    dims = list(shape)
+    while len(dims) > 2 and dims[-1] < _MINOR_LEN:
+        dims[-2:] = [dims[-2] * dims[-1]]
+    return tuple(dims)
+
+
+def off_row_major(data) -> bool:
+    """True where a device array's own layout is not row-major: a
+    frame column on the chip is planar (minor-to-major W, H, C, N: the
+    compiler keeps no minor dimension of 3), and `np.asarray` of it
+    keeps that order in its strides.  The one condition of the sink
+    relayout; a CPU backend always reads row-major."""
+    layout = data.format.layout
+    return layout is not None and \
+        tuple(layout.major_to_minor) != tuple(range(data.ndim))
+
+
+def _relaid_shape(data) -> Optional[tuple]:
+    """The shape a device array is relaid to before its copy to the
+    host, None where it goes as it is: laid out row-major already, or
+    with no trailing dimensions to merge."""
+    if not off_row_major(data):
+        return None
+    merged = merged_row_shape(data.shape)
+    return None if merged == tuple(data.shape) else merged
+
+
+def _row_major(data):
+    """`data` as the array whose copy to the host arrives row-major:
+    itself, or one `rowmajor` program's result."""
+    merged = _relaid_shape(data)
+    if merged is None:
+        return data
+    return row_programs("columnbatch").rowmajor(data, merged)
+
+
 def _is_jax(x) -> bool:
     # cheap structural check that avoids importing jax for pure-host runs
     return type(x).__module__.startswith("jax")
@@ -156,9 +218,16 @@ class ColumnBatch:
     transforms (take/relabel/concat) preserve the mark — builtin gathers
     never look inside a frame — and any per-row host materialization
     converts transparently so no consumer can observe raw YUV bytes.
+
+    ``_row_shape`` marks device data that `prefetch_host` laid out
+    row-major for its copy to the host: `data` then holds each row with
+    its trailing dimensions merged (`merged_row_shape`) and
+    ``_row_shape`` is the row's own shape, which `to_host` gives back.
+    Evaluation is done with such a batch: `to_host` is all it is for.
     """
 
-    __slots__ = ("rows", "data", "nulls", "convert", "_row_pos")
+    __slots__ = ("rows", "data", "nulls", "convert", "_row_pos",
+                 "_row_shape")
 
     def __init__(self, rows: np.ndarray, data,
                  nulls: Optional[np.ndarray] = None,
@@ -168,6 +237,7 @@ class ColumnBatch:
         self.nulls = nulls if nulls is None or nulls.any() else None
         self.convert = convert
         self._row_pos = None
+        self._row_shape = None
         if not is_array_data(data) and len(data) != len(self.rows):
             raise ValueError(
                 f"ColumnBatch: {len(data)} elements for {len(self.rows)} rows")
@@ -339,8 +409,22 @@ class ColumnBatch:
         instead of serializing inside the saver (PERF.md §3).  The
         later to_host() then finds the transfer done (or in flight) and
         returns quickly.  No-op for host data; best-effort on jax
-        versions without copy_to_host_async."""
+        versions without copy_to_host_async.
+
+        A batch the chip does not hold row-major is laid out so first,
+        by one device program, and keeps that array in place of the
+        planar one (which is released as soon as the program has run):
+        the host then receives its bytes in the order every consumer
+        wants, where a saver paid an element-by-element copy of each
+        planar frame (PERF.md §6, PR 39).  Decided from the array's
+        own layout; a convert-marked batch ships its wire as it is.
+        The caller is done with the batch but for its to_host()."""
         if _is_jax(self.data):
+            if self.convert is None and self._row_shape is None:
+                relaid = _row_major(self.data)
+                if relaid is not self.data:
+                    self._row_shape = tuple(self.data.shape[1:])
+                    self.data = relaid
             # the sink batch sits in device memory until the saver's
             # fetch: account it so pre-fetch HBM pressure has an owner
             _ms.track_array(self.data, "sink")
@@ -352,10 +436,31 @@ class ColumnBatch:
                     pass
         return self
 
+    @property
+    def sink_layout(self) -> Optional[str]:
+        """How this batch's rows reach the host: "relaid" (laid out
+        row-major on the device first: by `prefetch_host` already, or
+        by the `to_host` to come), "asis" (fetched in the layout they
+        have), None for host data."""
+        if not _is_jax(self.data):
+            return None
+        relaid = self._row_shape is not None or (
+            self.convert is None and _relaid_shape(self.data) is not None)
+        return "relaid" if relaid else "asis"
+
     def to_host(self) -> "ColumnBatch":
-        """Materialize device data on host (the single sink-side fetch)."""
+        """Materialize device data on host (the single sink-side fetch):
+        a C-contiguous array whose rows are contiguous views.  Where
+        nothing was prefetched the row-major layout is made here, into
+        an array that lives for the copy alone: this batch is left as
+        it is (a host op's input may feed a device op next)."""
         if _is_jax(self.data):
-            data = np.asarray(self.data)
+            src, row_shape = self.data, self._row_shape
+            if row_shape is None:
+                row_shape = tuple(src.shape[1:])
+                if self.convert is None:
+                    src = _row_major(src)
+            data = np.asarray(src).reshape((len(src),) + row_shape)
             _M_D2H_BYTES.inc(data.nbytes)
             return ColumnBatch(self.rows, data, self.nulls,
                                convert=self.convert)

@@ -210,6 +210,15 @@ _M_SINK_FETCH_BYTES = _mx.registry().counter(
     "scanner_tpu_sink_fetch_bytes_total",
     "Bytes of sinks' batches brought from a device to the host by the "
     "save stage (a batch already on the host counts nothing).")
+_M_SINK_ROWS = _mx.registry().counter(
+    "scanner_tpu_sink_rows_total",
+    "Rows of sinks' batches that were on a device when evaluation was "
+    "done, by how they reach the host: relaid (the chip held them off "
+    "row-major, planar frames, and one device program laid them out "
+    "row-major before the copy), asis (fetched in the layout they had: "
+    "row-major already, or a wire format).  A batch already on the "
+    "host counts nothing.",
+    labels=["layout"])
 # a frame column that is not uint8 RGB (float32 flow fields) is not
 # video: its rows are pickled one by one into a blob item (save:raw,
 # inside save:write)
@@ -1450,8 +1459,9 @@ class LocalExecutor:
             # task's evaluation instead of blocking the saver
             with self.profiler.span(
                     "evaluate:prefetch",
-                    counter=_M_EVAL_PART_SECONDS.labels(part="prefetch")):
-                self._prefetch_results(w)
+                    counter=_M_EVAL_PART_SECONDS.labels(part="prefetch"),
+                    task=w.task_idx) as span:
+                span.args.update(self._prefetch_results(w))
         dt = time.time() - t0
         _M_STAGE_SECONDS.labels(stage="evaluate").inc(dt)
         _M_STAGE_TASKS.labels(stage="evaluate").inc()
@@ -2274,7 +2284,11 @@ class LocalExecutor:
                     # the reference stores these as RAW-format video
                     # columns; here the column degrades to pickled arrays.
                     # A row pays its bytes (16,588,800 for a 1080p flow
-                    # field) three times on this thread: the pickle's copy,
+                    # field) three times on this thread: the pickle's copy
+                    # (one memcpy: a row fetched from a device is a
+                    # contiguous view, ColumnBatch.prefetch_host; a
+                    # strided one a host kernel hands over is copied
+                    # element by element, under the interpreter lock),
                     # the item's checksum and the item's join, then the
                     # backend's write (span save:raw; one contiguous buffer
                     # a task would pay the write alone)
@@ -2327,16 +2341,33 @@ class LocalExecutor:
         return os.environ.get("SCANNER_TPU_ASYNC_SINK_FETCH", "1") \
             not in ("0", "false")
 
-    def _prefetch_results(self, w: TaskItem) -> None:
+    @staticmethod
+    def _count_sink_rows(batches) -> Dict[str, int]:
+        """Device sink batches' rows by how they reach the host
+        (`ColumnBatch.sink_layout`), counted once a batch: where its
+        copy is started, else where it is fetched."""
+        counts = {"relaid": 0, "asis": 0}
+        for b in batches:
+            layout = b.sink_layout if isinstance(b, ColumnBatch) else None
+            if layout is not None:
+                counts[layout] += len(b)
+                _M_SINK_ROWS.labels(layout=layout).inc(len(b))
+        return counts
+
+    def _prefetch_results(self, w: TaskItem) -> Dict[str, int]:
         """Kick off the async device->host copy of every sink batch the
         moment evaluation finishes — hung off the TaskItem before it
         enters save_q, so task k's d2h latency rides under task
-        k+1's evaluation instead of serializing inside the saver."""
+        k+1's evaluation instead of serializing inside the saver.  A
+        batch the chip holds off row-major is laid out row-major first
+        (`ColumnBatch.prefetch_host`).  Returns the rows by layout, the
+        `evaluate:prefetch` span's args."""
         if not w.results or not self._async_sink_fetch_enabled():
-            return
+            return {}
         for b in w.results.values():
             if isinstance(b, ColumnBatch):
                 b.prefetch_host()
+        return self._count_sink_rows(w.results.values())
 
     def _fetch_sink(self, w: TaskItem, sink_id: int) -> List[Any]:
         """The single device->host fetch of the batched data path: one
@@ -2344,6 +2375,8 @@ class LocalExecutor:
         batch = w.results[sink_id]
         with self.profiler.span("save:fetch", counter=_M_SINK_FETCH_SECONDS,
                                 task=w.task_idx):
+            if not self._async_sink_fetch_enabled():
+                self._count_sink_rows([batch])
             host = batch.to_host()
             if host is not batch:
                 _M_SINK_FETCH_BYTES.inc(host.data.nbytes)
@@ -2410,13 +2443,14 @@ class LocalExecutor:
                               bitrate=int(enc_opts.get("bitrate", 0)),
                               crf=int(enc_opts.get("crf", 20)),
                               keyint=keyint)
-                # a row fetched from a chip comes strided as the chip
-                # laid it out, and the encoder wants it contiguous: a
-                # copy of every frame, made just before its feed so
-                # that the encoder reads it warm (all of an item's
-                # copies first cost a saver 3 ms a row more).  Timed
-                # frame by frame into one number an item: it has a
-                # counter and no span of its own
+                # the encoder wants a frame contiguous.  A row fetched
+                # from a device is (its sink batch was laid out
+                # row-major there, ColumnBatch.prefetch_host) and comes
+                # back as it is; a host kernel may hand over a strided
+                # view, which is copied here, just before its feed so
+                # that the encoder reads it warm.  Timed frame by frame
+                # into one number an item: it has a counter and no span
+                # of its own
                 copying = 0.0
                 for f in frames:
                     t0 = time.time()

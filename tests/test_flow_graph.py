@@ -236,6 +236,38 @@ def test_gather_and_raw_spans_and_their_counters_move_together(
 
 
 
+def test_a_field_reads_back_the_same_from_planar_views_as_from_rows(
+        sc, monkeypatch):
+    """The raw branch of `_save_task` pickles a row whether it comes as
+    a strided view of planes (what a fetch handed over before sink
+    batches were laid out row-major on the device) or contiguous: both
+    read back as equal float32 arrays of the row's shape, C-contiguous;
+    the streams differ only in how the array is rebuilt."""
+    from scanner_tpu.engine.batch import ColumnBatch
+    real, handed = ColumnBatch.to_host, []
+
+    def planar(self):
+        got = real(self)
+        if got is not self and got.data.dtype == np.float32:
+            got.data = np.ascontiguousarray(
+                got.data.transpose(0, 3, 1, 2)).transpose(0, 2, 3, 1)
+            handed.append(got.data[0].flags.c_contiguous)
+        return got
+
+    _job, rows, rowwise = _run(sc, "rowwise", (16, 48),
+                               PerfParams.manual(16, 16))
+    monkeypatch.setattr(ColumnBatch, "to_host", planar)
+    _job, _rows, planes = _run(sc, "planes", (16, 48),
+                               PerfParams.manual(16, 16))
+    assert handed == [False, False] and len(planes) == len(rows) == 32
+    for a, b in zip(rowwise, planes):
+        assert a.dtype == b.dtype == np.float32
+        assert a.shape == b.shape == (H, W, 2)
+        assert a.flags.c_contiguous and b.flags.c_contiguous
+        np.testing.assert_array_equal(a, b)
+    assert np.abs(np.stack(planes)).max() > 0
+
+
 @pytest.mark.parametrize("yuv_wire,geometry,affinity", [
     (True, (40, 56, 12), "1"), (False, (40, 72, 12), "1"),
     (True, (40, 88, 12), "0")], ids=["wire", "rgb", "wire_on_one_chip"])

@@ -344,6 +344,45 @@ def test_save_spans_nest_and_their_counters_move_with_them(sc):
     assert pipeline.args["loaders"] >= 1
 
 
+def _planar_views(host):
+    """`host`'s frames as a fetch handed them over before sink batches
+    were laid out row-major on the device: views into planes."""
+    return np.ascontiguousarray(host.transpose(0, 3, 1, 2)) \
+        .transpose(0, 2, 3, 1)
+
+
+def test_an_item_encodes_the_same_from_planar_views_as_from_frames(
+        sc, monkeypatch):
+    """The encoder is fed the same pixels whether a frame reaches it as
+    a strided view of planes (its copy to a contiguous array is made
+    before the feed) or contiguous (the copy returns its argument): the
+    item on disk is the same, byte for byte."""
+    from scanner_tpu.engine.batch import ColumnBatch
+    from scanner_tpu.storage import metadata as md
+    real, handed = ColumnBatch.to_host, []
+
+    def planar(self):
+        got = real(self)
+        if got is not self and got.data.ndim == 4:
+            got.data = _planar_views(got.data)
+            handed.append(got.data[0].flags.c_contiguous)
+        return got
+
+    def items(name):
+        desc = sc._db.table_descriptor(name)
+        return [sc._db.backend.read(
+            md.column_item_path(desc.id, "frame", item)) for item in range(2)]
+
+    copied = "scanner_tpu_save_contiguous_seconds_total"
+    _blur_run(sc, "128x96", "blurred_contiguous")
+    monkeypatch.setattr(ColumnBatch, "to_host", planar)
+    before = _counter(copied)
+    _blur_run(sc, "128x96", "blurred_planar")
+    assert handed == [False, False] and _counter(copied) > before
+    assert items("blurred_planar") == items("blurred_contiguous")
+    assert all(len(item) > 0 for item in items("blurred_planar"))
+
+
 def test_a_saved_task_lets_go_of_its_results(sc, monkeypatch):
     """The run keeps every TaskItem until it returns; what a task holds
     of the device (6.2 MB a 1080p row of a frame column) goes when it is
