@@ -93,6 +93,30 @@ _M_DEVICE_WAIT_SECONDS = _mx.registry().counter(
     "on the chip (evaluate:device_wait: the wait that times the call "
     "for the roofline gauges, and the drain after a first call).",
     labels=["op"])
+# a device column handed to a host op: the wait for its producer's
+# program, the row-major layout where the chip held it otherwise
+# (ColumnBatch.prefetch_host, started when the producer's call was
+# dispatched) and the copy to the host; the evaluate:handoff span,
+# inside the op's evaluate:inputs or evaluate:window
+_M_HANDOFF_SECONDS = _mx.registry().counter(
+    "scanner_tpu_op_handoff_seconds_total",
+    "Evaluator seconds a host op (label) waited for a device column to "
+    "reach the host, a task or chunk: mirrors the evaluate:handoff "
+    "span.",
+    labels=["op"])
+_M_HANDOFF_BYTES = _mx.registry().counter(
+    "scanner_tpu_op_handoff_bytes_total",
+    "Bytes of the device columns brought to the host for a host op "
+    "(label).",
+    labels=["op"])
+_M_HANDOFF_ROWS = _mx.registry().counter(
+    "scanner_tpu_op_handoff_rows_total",
+    "Rows of the device columns brought to the host for a host op, by "
+    "how they came: `relaid` (the chip held the column off row-major, "
+    "planar frames, and one program laid it out row-major before the "
+    "copy), `asis` (copied as it lay).  Either way the host's array "
+    "is C-contiguous and its rows are contiguous views.",
+    labels=["op", "layout"])
 # a stencilled op's window: what it costs to have the producer's column
 # where the op runs (for a host op behind a device op, the wait for the
 # producer's kernel and the device->host fetch), and the rows the
@@ -1262,7 +1286,7 @@ class TaskEvaluator:
         self.fused: Dict[int, FusedKernelInstance] = {}
         self._chain_member_ids: set = set()
         if _fusion.enabled():
-            for ch in _fusion.plan_chains(info):
+            for ch in _fusion.plan_chains_once(info, graph_key(info)):
                 self.chains[ch.tail.id] = ch
                 self.fused[ch.tail.id] = FusedKernelInstance(
                     ch, [self.kernels[m.id] for m in ch.members])
@@ -1469,12 +1493,11 @@ class TaskEvaluator:
                     else:
                         store[(n.id, "output")] = self._run_sampler(
                             n, jr, plan, store)
-            elif n.id in self.chains:
-                outs = self._run_fused(n, jr, plan, store)
-                for col, b in outs.items():
-                    store[(n.id, col)] = b
             else:
-                outs = self._run_kernel(n, jr, plan, store)
+                run = self._run_fused if n.id in self.chains \
+                    else self._run_kernel
+                outs = run(n, jr, plan, store)
+                self._prefetch_for_host_ops(n, outs)
                 for col, b in outs.items():
                     store[(n.id, col)] = b
             self.last_peak_columns = max(self.last_peak_columns, len(store))
@@ -1549,6 +1572,35 @@ class TaskEvaluator:
         need = np.asarray(ts.valid_output_rows, np.int64)
         return in_b.take(in_b.positions(need - offset), need)
 
+    def _hand_off(self, op: str, b: ColumnBatch) -> ColumnBatch:
+        """`b` where host op `op` reads it: a device column brought to
+        the host, host data as it is."""
+        layout = b.sink_layout
+        if layout is None:
+            return b
+        with self.profiler.span(
+                "evaluate:handoff", op=op, rows=len(b), layout=layout,
+                counter=_M_HANDOFF_SECONDS.labels(op=op)):
+            b = b.to_host()
+        _M_HANDOFF_BYTES.labels(op=op).inc(b.data.nbytes)
+        _M_HANDOFF_ROWS.labels(op=op, layout=layout).inc(len(b))
+        return b
+
+    def _prefetch_for_host_ops(self, n: O.OpNode,
+                               outs: Dict[str, ColumnBatch]) -> None:
+        """Start the copy to the host of what device op (or chain tail)
+        `n` just made, where only host ops read it: the sink's own
+        mechanism (`ColumnBatch.prefetch_host`: row-major on the chip
+        first, then an asynchronous copy), at the producer's dispatch
+        and not at the consumer's first look."""
+        readers = [self.info.op_at(c) for c in self.info.consumers[n.id]]
+        if readers and all(
+                not r.is_builtin
+                and r.effective_device() != DeviceType.TPU
+                for r in readers):
+            for b in outs.values():
+                b.prefetch_host()
+
     # -- regular kernels -----------------------------------------------
 
     def _run_kernel(self, n: O.OpNode, jr: A.JobRows, plan: A.TaskPlan,
@@ -1618,7 +1670,7 @@ class TaskEvaluator:
                         and b.data.dtype != object:
                     b = b.to_device(ki.device)
                 elif not is_device_kernel:
-                    b = b.to_host()
+                    b = self._hand_off(n.name, b)
                 # resolve a pending wire-format conversion (YUV420 staged
                 # at 1.5 B/px) exactly once, where the data now lives: for
                 # device kernels a jitted program of its own ahead of the
@@ -2104,6 +2156,8 @@ class TaskEvaluator:
             body re-folds the window axes member by member)."""
             p = col_pos[sel].reshape(-1)
             if is_array_data(in_b.data):
+                if np.array_equal(p, np.arange(p[0], p[0] + len(p))):
+                    return rows_run(in_b.data, int(p[0]), len(p))
                 return rows_at(in_b.data, p)
             # object column: stack per-row host data into one array
             return np.stack([np.asarray(in_b.data[int(j)]) for j in p])

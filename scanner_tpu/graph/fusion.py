@@ -44,6 +44,7 @@ series below (scanner-check SC317 pins both contracts).
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
@@ -271,6 +272,43 @@ def plan_chains(info, min_chain: Optional[int] = None,
         ch = FusionChain(members=members)
         chains.append(ch)
         _M_CHAINS.labels(chain=ch.chain_id).set(len(members))
+    return chains
+
+
+# graph key -> the chains planned for it, each the positions of its
+# members in the graph's topological order; the oldest goes when there
+# are _DECIDED_MAX (a key holds its ops' init args by value)
+_DECIDED: Dict[tuple, List[tuple]] = {}
+_DECIDED_MAX = 64
+_DECIDED_LOCK = threading.Lock()
+
+
+def plan_chains_once(info, key) -> List[FusionChain]:
+    """`plan_chains(info)`, decided once a graph: `key` is the engine's
+    `graph_key(info)` (what of a graph its evaluators are made from),
+    and every later evaluator of that key plans the chains the first
+    one did.  The fuse decision reads the live roofline ledger, which a
+    run's own calls write: left to each evaluator, a graph could run
+    fused in one run and staged in the next, two sets of programs and a
+    recompile under one deployment.  None (a graph not keyable by
+    value): planned each time."""
+    if key is None:
+        return plan_chains(info)
+    key = (key, fusion_min_chain())
+    with _DECIDED_LOCK:
+        decided = _DECIDED.get(key)
+    if decided is None:
+        chains = plan_chains(info)
+        at = {n.id: i for i, n in enumerate(info.ops)}
+        with _DECIDED_LOCK:
+            if len(_DECIDED) >= _DECIDED_MAX:
+                del _DECIDED[next(iter(_DECIDED))]
+            _DECIDED.setdefault(
+                key, [tuple(at[m.id] for m in ch.members) for ch in chains])
+        return chains
+    chains = [FusionChain([info.ops[i] for i in idx]) for idx in decided]
+    for ch in chains:
+        _M_CHAINS.labels(chain=ch.chain_id).set(len(ch.members))
     return chains
 
 

@@ -2,17 +2,16 @@
 
 Capability parity: reference util/image_encoder.cpp (lodepng/jpeg encode)
 and the scannertools image ops.  PIL handles the codecs; these are host
-(CPU) ops by nature.
+(CPU) ops by nature.  (`Grayscale` is a device op: kernels/imgproc.py.)
 """
 
 from __future__ import annotations
 
 import io
-from typing import Any, Sequence
 
 import numpy as np
 
-from ..common import DeviceType, FrameType
+from ..common import FrameType
 from ..graph.ops import Kernel, register_op
 
 
@@ -41,13 +40,3 @@ class ImageDecode(Kernel):
     def execute(self, data: bytes) -> FrameType:
         from ..video.ingest import decode_image
         return decode_image(data)
-
-
-@register_op()
-class Grayscale(Kernel):
-    """RGB frame -> single-channel-replicated grayscale frame (host op)."""
-
-    def execute(self, frame: FrameType) -> FrameType:
-        f = np.asarray(frame).astype(np.float32)
-        g = (0.299 * f[..., 0] + 0.587 * f[..., 1] + 0.114 * f[..., 2])
-        return np.repeat(g[..., None], 3, axis=-1).astype(np.uint8)

@@ -423,6 +423,52 @@ class Blur(Kernel):
         return _blur_impl(jnp.asarray(frame), self.kern, self.ksize)
 
 
+# 0.299, 0.587, 0.114 in 16-bit fixed point; they sum to 2**16, so a
+# grey pixel (v, v, v) reads v
+GRAY_WEIGHTS = (19595, 38470, 7471)
+
+
+def gray3(frames):
+    """(..., 3) uint8 RGB -> (..., 3) uint8, each channel the luma
+    (19595 R + 38470 G + 7471 B) >> 16: integer arithmetic, so a numpy
+    batch on the host, a jax batch on a device and a tracer inside a
+    fused chain give the same bytes.  (In float32 they do not: XLA's
+    CPU backend contracts a multiply into an add, and 1001 of the 2**24
+    colours then truncate to another level than numpy's.)"""
+    xp = np if isinstance(frames, np.ndarray) else jnp
+    f = frames.astype(xp.int32)
+    wr, wg, wb = GRAY_WEIGHTS
+    g = ((wr * f[..., 0] + wg * f[..., 1] + wb * f[..., 2]) >> 16) \
+        .astype(xp.uint8)
+    return xp.stack([g, g, g], axis=-1)
+
+
+_gray3_impl = jax.jit(jax.named_scope("Grayscale")(gray3))
+
+
+@register_op(device=DeviceType.TPU, batch=16)
+class Grayscale(Kernel):
+    """RGB frame -> its luma in all three channels (the walkthrough's
+    colour op, reference examples/apps/walkthroughs): a device op, so
+    it fuses with a `Resize` or a `Blur` before it."""
+
+    def cost(self, shapes):
+        """Three multiplies, two adds and a shift a pixel.  uint8 in,
+        uint8 out, same geometry."""
+        s = _frame_shape(shapes)
+        if s is None or len(s) != 4:
+            return None
+        px = float(np.prod(s))
+        return CostDescriptor(flops=2.0 * px, bytes_in=px, bytes_out=px)
+
+    def execute(self, frame: Sequence[FrameType]) -> Sequence[FrameType]:
+        # a host batch (no device staging) stays on the host; device in
+        # -> device out
+        if isinstance(frame, np.ndarray):
+            return gray3(frame)
+        return _gray3_impl(jnp.asarray(frame))
+
+
 @jax.jit
 @jax.named_scope("OpticalFlow")
 def _grayscale(frames: jnp.ndarray) -> jnp.ndarray:

@@ -371,3 +371,39 @@ def test_the_wire_conversion_holds_nothing_wider_than_uint8_in_hbm(
              for ln in entry[kernel + 1:] if " = " in ln]
     assert after and set(after) == {"bitcast"}, after
     assert f"u8[{rows},1080,1920,3]" in entry[0]
+
+
+def test_the_walkthroughs_chain_compiles_for_the_v5e_at_a_1080p_packet(
+        one_v5e_chip):
+    """`Resize+Grayscale` as `FusedKernelInstance` traces it, compiled
+    for the chip at the walkthrough cell's packet (sixteen 1080p rows ->
+    640x480): the chain's and each member's scope are on its operations,
+    the 1080p input is never held as float32 (398 MB a packet: the
+    h-pass converts after its gathers), what it does hold between the
+    two passes is the 480-row float32 image and the taps' uint8 gathers
+    (0.40 GB in all), and its result is the uint8 frame the host op is
+    handed.  A compile is not a chip run.  (Here and not in
+    tests/test_walkthrough_graph.py: one file holds the described
+    chip.)"""
+    import jax
+    import jax.numpy as jnp
+    from scanner_tpu import DeviceType
+    from scanner_tpu.engine.evaluate import _trace_chain
+    from scanner_tpu.graph import ops as O
+    members = []
+    for name, args in (("Resize", {"width": 640, "height": 480}),
+                       ("Grayscale", {})):
+        kernel = O.registry.canonical_factory(O.registry.get(name))(
+            O.KernelConfig(device=DeviceType.TPU, args=args), **args)
+        members.append((name, kernel, 0))
+    packet = jax.ShapeDtypeStruct((16, 1080, 1920, 3), jnp.uint8,
+                                  sharding=one_v5e_chip)
+    compiled = jax.jit(lambda y: _trace_chain(
+        "Resize+Grayscale", members, y)).lower(packet).compile()
+    hlo = compiled.as_text()
+    assert "Resize+Grayscale/Resize/" in hlo
+    assert "Resize+Grayscale/Grayscale/" in hlo
+    assert "f32[16,1080,1920" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45e9
+    entry = hlo[hlo.index("ENTRY "):].splitlines()[0]
+    assert "u8[16,1080,1920,3]" in entry and "-> u8[16,480,640,3]" in entry
