@@ -81,8 +81,9 @@ class Client:
                  workers: Optional[List[str]] = None,
                  # None = derived at each run from the cores this
                  # process may use, the run's evaluator instances, its
-                 # queue depth and its tasks (engine/evaluate.py
-                 # default_load_workers).  An explicit value wins.
+                 # tasks and, where they load whole, its queue depth
+                 # (engine/evaluate.py default_load_workers).  An
+                 # explicit value wins.
                  num_load_workers: Optional[int] = None,
                  num_save_workers: int = 2,
                  # None = resolve at job launch: one device-affine

@@ -354,7 +354,8 @@ def usable_cores() -> int:
 def default_load_workers(configured: Optional[int] = None,
                          instances: int = 1, queues: int = 1,
                          qsize: int = 4, tasks: int = 0,
-                         decoder_threads: int = 1) -> int:
+                         decoder_threads: int = 1,
+                         streaming: bool = True) -> int:
     """Resolve the loader-thread count of one pipeline run, under
     default_pipeline_instances' contract: an explicit setting wins as
     given — ANY value, 1 included — and only an unset count (None/0)
@@ -363,19 +364,22 @@ def default_load_workers(configured: Optional[int] = None,
     - the host: usable_cores() less one per evaluator thread, one for
       the savers and one for the main thread; a loader takes
       `decoder_threads` of what is left.
-    - the pipeline's depth: a streaming task's chunks are decoded only
-      once the task sits in its evaluator's queue (executor.py
-      loader()), so at most `qsize` tasks per queue plus the one each
-      of the `instances` evaluators holds decode at once; a loader
-      beyond that only waits in load:queue_wait.
+    - the pipeline's depth, where tasks load whole (`streaming` off:
+      PerfParams.stream_work_packets): `qsize` loaded tasks per queue
+      plus the one each of the `instances` evaluators holds are what
+      the run means to keep in memory; a loader beyond that would
+      hold one more.  A streaming task takes no place in that bound
+      (executor.py _loaded_whole): each loader decodes into its own
+      task's two-chunk queue, so the stage is as wide as the host.
     - `tasks`, where the run knows its count (0 = open-ended, a
       cluster worker pulling from the master): a one-task query
       starts one loader.
     """
     if configured:
         return int(configured)
-    spare = (usable_cores() - instances - 2) // max(1, decoder_threads)
-    n = min(spare, queues * qsize + instances)
+    n = (usable_cores() - instances - 2) // max(1, decoder_threads)
+    if not streaming:
+        n = min(n, queues * qsize + instances)
     if tasks > 0:
         n = min(n, tasks)
     return max(1, n)

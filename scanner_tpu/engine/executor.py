@@ -149,7 +149,12 @@ _M_LOAD_WORKERS = _mx.registry().gauge(
     "scanner_tpu_load_workers",
     "Loader threads of the pipeline run that started last: the count "
     "given, or the one derived from usable cores, evaluator instances, "
-    "queue depth and tasks (evaluate.py default_load_workers).")
+    "tasks and, where tasks load whole, queue depth (evaluate.py "
+    "default_load_workers).")
+_M_RUN_LOADERS = _mx.registry().counter(
+    "scanner_tpu_run_loaders_total",
+    "Loader threads that local runs started, summed over runs: over "
+    "scanner_tpu_runs_total, the width of a run's load stage.")
 _M_STAGE_WAIT = _mx.registry().counter(
     "scanner_tpu_stage_wait_seconds_total",
     "Seconds a stage thread waited on a neighbor: load = blocked "
@@ -289,12 +294,14 @@ class _StageQueue:
     `close()` once its last producer has finished; consumers then take
     what is left and get None.  `abort()` is the error path: every
     blocked `put` and `get` returns at once, and what is queued is left
-    where it is."""
+    where it is.  `bounded` names the items the bound counts (all of
+    them where it is None): any other is queued past it, in its turn."""
 
-    def __init__(self, maxsize: int):
+    def __init__(self, maxsize: int, bounded=None):
         self._cond = threading.Condition()
         self._items: deque = deque()
         self._maxsize = maxsize
+        self._bounded = bounded or (lambda item: True)
         self._closed = False
         self._aborted = False
 
@@ -306,12 +313,16 @@ class _StageQueue:
         with self._cond:
             return sum(1 for item in self._items if pred(item))
 
+    def _full(self) -> bool:
+        return sum(map(self._bounded, self._items)) >= self._maxsize
+
     def put(self, item) -> bool:
-        """Blocks while full.  False, and the item not queued, once the
-        queue is aborted."""
+        """Blocks while full, if the bound counts `item`.  False, and
+        the item not queued, once the queue is aborted."""
         with self._cond:
-            while len(self._items) >= self._maxsize and not self._aborted:
-                self._cond.wait()
+            if self._bounded(item):
+                while self._full() and not self._aborted:
+                    self._cond.wait()
             if self._aborted:
                 return False
             self._items.append(item)
@@ -416,13 +427,23 @@ class TaskItem:
     decode_rows: int = 0
 
 
+def _loaded_whole(w: TaskItem) -> bool:
+    """What the evaluate queue's bound is there to limit: a task whose
+    every source row is loaded before it is queued.  A streaming task
+    holds its own `chunk_q` of two chunks and no more, so it takes no
+    place in that bound: its loader queues it and decodes at once, and
+    the load stage is as wide as the host's spare cores
+    (evaluate.py default_load_workers)."""
+    return w.chunk_q is None
+
+
 def _awaits_evaluator(w: TaskItem) -> bool:
     """A queued task that waits on the evaluator alone: loaded whole,
     or a streaming task whose loader has filled its chunk queue.  One
     whose chunks are still being decoded is in the evaluate queue only
     so that they can stream: a wide load stage keeps that queue full
     of such tasks while the evaluator starves."""
-    return w.chunk_q is None or w.chunk_q.full()
+    return _loaded_whole(w) or w.chunk_q.full()
 
 
 class _StatefulChain:
@@ -894,6 +915,7 @@ class LocalExecutor:
                             pipeline.args.update(loaders=loaders,
                                                  instances=instances,
                                                  savers=savers)
+                            _M_RUN_LOADERS.inc(loaders)
                         if self._last_save_end is not None:
                             _M_RUN_SECONDS.labels(phase="drain").inc(
                                 joined - self._last_save_end)
@@ -1046,14 +1068,16 @@ class LocalExecutor:
                                device_label)
         inst_devices = [assigned_device(i) for i in range(n_evals)]
         if n_evals > 1 and any(d is not None for d in inst_devices):
-            eval_qs = [_StageQueue(qsize) for _ in range(n_evals)]
+            eval_qs = [_StageQueue(qsize, _loaded_whole)
+                       for _ in range(n_evals)]
         else:
-            eval_qs = [_StageQueue(qsize)] * n_evals
+            eval_qs = [_StageQueue(qsize, _loaded_whole)] * n_evals
         uniq_qs = list({id(q): q for q in eval_qs}.values())
         save_q = _StageQueue(qsize)
         n_loaders = 1 if serialize else default_load_workers(
             self.num_load_workers, instances=n_evals, queues=len(uniq_qs),
-            qsize=qsize, tasks=total, decoder_threads=self.decoder_threads)
+            qsize=qsize, tasks=total, decoder_threads=self.decoder_threads,
+            streaming=self._stream_packets())
         self.stage_widths = (n_loaders, n_evals, self.num_save_workers)
         _M_LOAD_WORKERS.set(n_loaders)
         # live depth gauges sample the queues at scrape time; the last

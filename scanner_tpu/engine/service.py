@@ -3280,8 +3280,9 @@ class Worker:
 
     def __init__(self, master_address: str, db_path: str, port: int = 0,
                  storage_type: str = "posix",
-                 # None = derived per bulk from cores, instances and queue
-                 # depth (evaluate.py default_load_workers); explicit wins
+                 # None = derived per bulk from cores and instances, and
+                 # from queue depth where tasks load whole (evaluate.py
+                 # default_load_workers); explicit wins
                  num_load_workers: Optional[int] = None,
                  num_save_workers: int = 2,
                  # None = one device-affine instance per local chip on

@@ -76,8 +76,8 @@ CONFIG_KEYS = ("enabled", "rules")
 SEVERITIES = ("warning", "critical")
 FORMS = ("value", "rate", "p50", "p90", "p99", "burn", "backpressure")
 # clause option keys the [alerts] rules grammar accepts
-RULE_OPTION_KEYS = ("window", "for", "severity", "by", "objective",
-                    "budget", "short")
+RULE_OPTION_KEYS = ("window", "for", "resolve", "severity", "by",
+                    "objective", "budget", "short")
 
 _OPS: Dict[str, Callable[[float, float], bool]] = {
     ">": lambda a, b: a > b,
@@ -91,6 +91,20 @@ _OPS: Dict[str, Callable[[float, float], bool]] = {
 _BP_TASKS_SERIES = "scanner_tpu_stage_tasks_total"
 _BP_UPSTREAM = {"evaluate": "load", "save": "evaluate"}
 _BP_IMBALANCE = 1.5   # producer fps > 1.5x consumer fps counts as skew
+# ... and what says that a queued stage does not hold the pipeline back
+# after all: the share of its threads' time spent waiting on its
+# producer (for a task; the evaluate stage also for a streaming task's
+# next chunk, which its stage seconds include).  A wide load stage
+# finishes a round of tasks together: they all stand before an
+# evaluator that clears them in a moment and then starves until the
+# next round, and a sample that falls on a round reads a full queue.
+# A stage that sets the pace still waits while each run's first chunks
+# are decoded: 11 % of `pose_dense`'s evaluator on the chip, against
+# 33-73 % where the evaluator starves (PERF.md §6, PR 42)
+_BP_STAGE_SECONDS = "scanner_tpu_stage_seconds_total"
+_BP_STAGE_WAIT = "scanner_tpu_stage_wait_seconds_total"
+_BP_CHUNK_WAIT = "scanner_tpu_chunk_wait_seconds_total"
+_BP_STARVED = 0.2
 
 # engine/evaluate.py: evaluator work in flight that may block on XLA
 # (kernel set-up, ladder warm-up, first dispatch of a signature).  The
@@ -149,6 +163,10 @@ class AlertRule:
     window: float = 60.0
     # hold-down: the condition must stay true this long before firing
     for_seconds: float = 0.0
+    # ... and a firing alert resolves only once the condition has stayed
+    # false (or its group gone) this long: it stands through a lapse
+    # shorter than that instead of firing anew after each
+    resolve_seconds: float = 0.0
     severity: str = "warning"
     # label names each alert instance is keyed by (one alert per group)
     by: Tuple[str, ...] = ()
@@ -157,7 +175,8 @@ class AlertRule:
     match: Dict[str, str] = field(default_factory=dict)
     # quiet gate: a series that explains the condition away.  While its
     # samples sum above 0 anywhere inside `window`, the rule evaluates
-    # as not met (a pending hold-down restarts, a firing alert resolves)
+    # as not met (a pending hold-down restarts, a firing alert resolves
+    # as for any lapse)
     unless: str = ""
     # value form only: divide by this series' matching group (ratios
     # like hbm_in_use / hbm_limit)
@@ -202,13 +221,22 @@ DEFAULT_RULES = (
         name="stage_backpressure", form="backpressure",
         series="scanner_tpu_stage_queue_depth",
         op=">=", value=3.0, window=10.0, for_seconds=1.5,
+        # every run starts with empty queues, and a wide load stage
+        # takes a task's decode time to fill the evaluator's again:
+        # back-to-back runs behind one slow stage are one alert, not
+        # one a run
+        resolve_seconds=5.0,
         severity="warning", by=("stage",), unless=_WARMING_SERIES,
         description="a pipeline stage's input queue sits at its high "
                     "watermark (or its producer sustainably outruns it "
                     "with a backlog standing): the stage is the "
                     "bottleneck and upstream work is piling up (quiet "
                     "while an evaluator is warming up: the queue then "
-                    "waits on a compile)"),
+                    "waits on a compile, and for a stage that waited on "
+                    "its producer for over a fifth of its time in the "
+                    "window: it starves between the producer's rounds; "
+                    "stands through the seconds between one run's "
+                    "queues and the next's)"),
     AlertRule(
         name="worker_heartbeat_stale",
         series="scanner_tpu_worker_heartbeat_age_seconds",
@@ -329,6 +357,8 @@ def parse_rules(spec: str) -> List[AlertRule]:
                     rule.window = float(v)
                 elif k == "for":
                     rule.for_seconds = float(v)
+                elif k == "resolve":
+                    rule.resolve_seconds = float(v)
                 elif k == "severity":
                     rule.severity = v.strip()
                 elif k == "by":
@@ -465,7 +495,8 @@ class HealthEngine:
             if r.unless:
                 need.add(r.unless)
             if r.form == "backpressure":
-                need.add(_BP_TASKS_SERIES)
+                need.update((_BP_TASKS_SERIES, _BP_STAGE_SECONDS,
+                             _BP_STAGE_WAIT, _BP_CHUNK_WAIT))
         return need
 
     def _max_window(self, rules: Sequence[AlertRule]) -> float:
@@ -684,19 +715,32 @@ class HealthEngine:
         """Composite: per stage, fires when the stage's input queue sits
         at the watermark, OR a backlog is standing (depth >= 1) while
         the producer stage completes tasks > _BP_IMBALANCE x faster —
-        either way, downstream cannot keep up.  Returns
-        {key: (depth, fired)}."""
+        either way, downstream cannot keep up.  Neither says so of a
+        stage that spent more than _BP_STARVED of its time over the
+        window waiting on its producer: it starves between the
+        producer's rounds, whatever stands before it at a sample.
+        Returns {key: (depth, fired)}."""
         depths = self._series_groups(now_s, rule.series, rule)
         rates: Dict[Tuple[str, ...], float] = {}
+        starved = set()
         if then_s is not None:
             dt = now_s[0] - then_s[0]
             if dt >= max(0.5, self._interval / 2):
-                cur = self._groups(now_s[1].get(_BP_TASKS_SERIES),
-                                   rule.match, ("stage",))
-                old = self._groups(then_s[1].get(_BP_TASKS_SERIES),
-                                   rule.match, ("stage",))
-                rates = {k: max(v - old.get(k, 0.0), 0.0) / dt
-                         for k, v in cur.items()}
+                def grown(series, match=rule.match, by=("stage",)):
+                    cur = self._groups(now_s[1].get(series), match, by)
+                    old = self._groups(then_s[1].get(series), match, by)
+                    return {k: max(v - old.get(k, 0.0), 0.0)
+                            for k, v in cur.items()}
+
+                rates = {k: v / dt
+                         for k, v in grown(_BP_TASKS_SERIES).items()}
+                busy = grown(_BP_STAGE_SECONDS)
+                chunk_wait = grown(_BP_CHUNK_WAIT, {}, ()).get((), 0.0)
+                for k, task_wait in grown(_BP_STAGE_WAIT).items():
+                    waited = task_wait \
+                        + (chunk_wait if k == ("evaluate",) else 0.0)
+                    if waited > _BP_STARVED * (task_wait + busy.get(k, 0.0)):
+                        starved.add(k)
         out = {}
         for key, depth in depths.items():
             stage = key[rule.by.index("stage")] if "stage" in rule.by \
@@ -708,10 +752,26 @@ class HealthEngine:
                 my_rate = rates.get((stage,), 0.0)
                 fired = up_rate > 0 \
                     and up_rate > my_rate * _BP_IMBALANCE
-            out[key] = (depth, fired)
+            out[key] = (depth, fired and (stage,) not in starved)
         return out
 
     # -- evaluation + state machine -----------------------------------------
+
+    def _lapsed(self, rule: AlertRule, skey, val, now: float,
+                transitions: List[dict]) -> None:
+        """The condition of alert `skey` is not met (or its group is
+        gone) at this tick: a pending alert starts over, a firing one
+        resolves once that has lasted `resolve_seconds`.  Under the
+        state lock."""
+        st = self._states[skey]
+        if st["state"] == "firing":
+            if now - st["met_at"] < rule.resolve_seconds:
+                return
+            transitions.append({
+                "state": "resolved", "rule": rule.name,
+                "severity": rule.severity,
+                "labels": st["labels"], "value": val})
+        del self._states[skey]
 
     def evaluate(self, now: Optional[float] = None) -> List[dict]:
         """Run every rule against the sample history; update alert
@@ -757,6 +817,7 @@ class HealthEngine:
                                 "state": "pending", "since": now,
                                 "labels": dict(zip(rule.by, key))}
                         st["value"] = val
+                        st["met_at"] = now
                         if st["state"] == "pending" \
                                 and now - st["since"] >= rule.for_seconds:
                             st["state"] = "firing"
@@ -766,25 +827,14 @@ class HealthEngine:
                                 "severity": rule.severity,
                                 "labels": st["labels"], "value": val})
                     elif st is not None:
-                        if st["state"] == "firing":
-                            transitions.append({
-                                "state": "resolved", "rule": rule.name,
-                                "severity": rule.severity,
-                                "labels": st["labels"], "value": val})
-                        del states[skey]
+                        self._lapsed(rule, skey, val, now, transitions)
                 # groups that vanished from the series (a departed
                 # worker's gauge child, a finished pipeline's queue
                 # sampler) resolve like any condition going false
                 for skey in [k for k in states
                              if k[0] == rule.name and k not in seen]:
-                    st = states[skey]
-                    if st["state"] == "firing":
-                        transitions.append({
-                            "state": "resolved", "rule": rule.name,
-                            "severity": rule.severity,
-                            "labels": st["labels"],
-                            "value": st.get("value")})
-                    del states[skey]
+                    self._lapsed(rule, skey, states[skey].get("value"),
+                                 now, transitions)
                 n_firing = sum(1 for (rn, _k), st in states.items()
                                if rn == rule.name
                                and st["state"] == "firing")
@@ -866,7 +916,8 @@ class HealthEngine:
         out["rule_table"] = [{
             "name": r.name, "form": r.form, "series": r.series,
             "op": r.op, "value": r.value, "window": r.window,
-            "for": r.for_seconds, "severity": r.severity,
+            "for": r.for_seconds, "resolve": r.resolve_seconds,
+            "severity": r.severity,
             "by": list(r.by), "unless": r.unless,
             "description": r.description,
         } for r in self.rules()]

@@ -131,7 +131,7 @@ def test_snapshot_histogram_quantiles_shapes():
 def test_parse_rules_grammar():
     rules = health.parse_rules(
         "evalq:value(scanner_tpu_stage_queue_depth{stage=evaluate})>=8"
-        ":for=5:severity=critical;"
+        ":for=5:resolve=7.5:severity=critical;"
         "slow_rpc:p99(scanner_tpu_rpc_latency_seconds)>0.5:window=120;"
         "hbm:value(scanner_tpu_device_hbm_bytes_in_use"
         "/scanner_tpu_device_hbm_limit_bytes)>0.9:by=device;"
@@ -141,6 +141,7 @@ def test_parse_rules_grammar():
                                       "req_slo"]
     assert rules[0].match == {"stage": "evaluate"}
     assert rules[0].for_seconds == 5 and rules[0].severity == "critical"
+    assert rules[0].resolve_seconds == 7.5 and rules[1].resolve_seconds == 0
     assert rules[1].form == "p99" and rules[1].window == 120
     assert rules[2].ratio_to == "scanner_tpu_device_hbm_limit_bytes"
     assert rules[2].by == ("device",)
@@ -212,6 +213,66 @@ def test_threshold_hold_down_fire_and_resolve():
     assert eng.tick(104.0) == []
     g.labels(stage="save").set(0)
     assert eng.tick(105.0) == []
+
+
+@pytest.mark.parametrize("lapse", ["false", "gone"])
+def test_a_firing_alert_stands_through_a_lapse_shorter_than_resolve(lapse):
+    """Back-to-back runs behind one slow stage: each starts with an
+    empty queue (the depth reads 0, or the run's sampler is gone), and
+    the alert that fired in the first stands through those seconds
+    instead of firing once a run; it resolves once the condition has
+    stayed false `resolve_seconds`, counted from the last tick it held."""
+    reg = MetricsRegistry()
+    g = reg.gauge("scanner_tpu_t_qd", "x", labels=["stage"])
+    rule = health.AlertRule(
+        name=f"t_stand_{lapse}", series="scanner_tpu_t_qd", form="value",
+        op=">=", value=3, by=("stage",), for_seconds=1.5,
+        resolve_seconds=5.0)
+    eng = health.HealthEngine(reg=reg, rules=[rule], interval=0.1)
+
+    def drop():
+        if lapse == "false":
+            g.labels(stage="evaluate").set(0)
+        else:
+            for m in reg.metrics():
+                if m.name == "scanner_tpu_t_qd":
+                    m.remove_labels(stage="evaluate")
+
+    g.labels(stage="evaluate").set(6)
+    assert eng.tick(100.0) == [] and eng.tick(101.0) == []
+    assert [t["state"] for t in eng.tick(102.0)] == ["firing"]
+    for start in (103.0, 110.0, 117.0):      # three more runs
+        drop()
+        assert eng.tick(start) == [] and eng.tick(start + 1.0) == []
+        assert len(eng.firing()) == 1
+        g.labels(stage="evaluate").set(4)
+        for dt in (2.0, 3.0, 4.0, 5.0, 6.0):
+            assert eng.tick(start + dt) == []
+    assert _counter("scanner_tpu_alerts_transitions_total",
+                    rule=f"t_stand_{lapse}", state="firing") == 1
+    # the last run ended at 123: false at 124 .. 127 stands, 128 resolves
+    drop()
+    for t in (124.0, 125.0, 126.0, 127.0, 127.9):
+        assert eng.tick(t) == []
+    assert [t["state"] for t in eng.tick(128.0)] == ["resolved"]
+    assert eng.firing() == []
+    # a pending alert gets no such grace: its hold-down starts over
+    g.labels(stage="evaluate").set(6)
+    assert eng.tick(130.0) == []
+    drop()
+    assert eng.tick(131.0) == []
+    g.labels(stage="evaluate").set(6)
+    assert eng.tick(132.0) == [] and eng.tick(133.0) == []
+    assert [t["state"] for t in eng.tick(133.5)] == ["firing"]
+
+
+def test_stage_backpressure_holds_five_seconds_and_the_others_none():
+    held = {r.name: r.resolve_seconds for r in health.default_rules()}
+    assert held.pop("stage_backpressure") == 5.0
+    assert set(held.values()) == {0.0}
+    table = {r["name"]: r for r in health.HealthEngine(
+        reg=MetricsRegistry()).alertz_dict()["rule_table"]}
+    assert table["stage_backpressure"]["resolve"] == 5.0
 
 
 def test_vanished_series_resolves_firing_alert():
@@ -489,6 +550,62 @@ def test_backpressure_watermark_and_imbalance_branches():
     q.labels(stage="save").set(0)
     trans = eng.tick(109.0)
     assert [t["state"] for t in trans] == ["resolved"]
+
+
+@pytest.mark.parametrize(
+    "stage, busy, task_wait, chunk_wait, fires",
+    [   # chip readings a window of 10 s (PERF.md §6, PR 42)
+        ("evaluate", 9.8, 0.1, 1.1, True),    # pose_dense: sets the pace
+        ("evaluate", 9.9, 0.05, 7.2, False),  # hist_dense: starves
+        ("evaluate", 9.5, 0.3, 3.2, False),   # walkthrough_dense
+        ("evaluate", 3.0, 5.0, 0.0, False),   # waits for tasks, not chunks
+        ("save", 18.0, 1.5, 0.0, True),       # two savers, both at work
+        ("save", 6.0, 13.0, 9.0, False),      # chunk waits are not theirs
+        ("save", 18.0, 1.5, 9.0, True),
+    ])
+def test_backpressure_holds_quiet_for_a_stage_that_starves(
+        stage, busy, task_wait, chunk_wait, fires):
+    """A wide load stage finishes a round of tasks together, so a
+    sample that falls on a round reads a full queue before an evaluator
+    that waits on its loaders most of the time: the depth alone is not
+    backpressure.  A stage that spent over a fifth of the window
+    waiting on its producer is held quiet, by either branch."""
+    reg = MetricsRegistry()
+    q = reg.gauge("scanner_tpu_stage_queue_depth", "x", labels=["stage"])
+    secs = reg.counter("scanner_tpu_stage_seconds_total", "x",
+                       labels=["stage"])
+    waits = reg.counter("scanner_tpu_stage_wait_seconds_total", "x",
+                        labels=["stage"])
+    chunks = reg.counter("scanner_tpu_chunk_wait_seconds_total", "x")
+    tasks = reg.counter("scanner_tpu_stage_tasks_total", "x",
+                        labels=["stage"])
+    rule = health.AlertRule(
+        name="t_starved", series="scanner_tpu_stage_queue_depth",
+        form="backpressure", op=">=", value=3, by=("stage",),
+        window=10.0, for_seconds=0.0)
+    eng = health.HealthEngine(reg=reg, rules=[rule], interval=0.1)
+    for c in (secs, waits, tasks):
+        for st in ("load", "evaluate", "save"):
+            c.labels(stage=st).inc(0)
+    chunks.inc(0)
+    q.labels(stage=stage).set(0)
+    assert eng.tick(100.0) == []
+    secs.labels(stage=stage).inc(busy)
+    waits.labels(stage=stage).inc(task_wait)
+    chunks.inc(chunk_wait)
+    # the watermark branch ...
+    q.labels(stage=stage).set(6)
+    assert [t["state"] for t in eng.tick(110.0)] \
+        == (["firing"] if fires else [])
+    q.labels(stage=stage).set(0)
+    eng.tick(110.5)
+    # ... and the imbalance branch: a backlog of one behind a producer
+    # that completed four times the tasks
+    tasks.labels(stage=health._BP_UPSTREAM[stage]).inc(40)
+    tasks.labels(stage=stage).inc(10)
+    q.labels(stage=stage).set(1)
+    assert [t["state"] for t in eng.tick(111.0)] \
+        == (["firing"] if fires else [])
 
 
 def test_rollup_severity_mapping_and_alertz():

@@ -96,12 +96,15 @@ def test_abort_wakes_blocked_put_and_get_and_keeps_them_out():
     assert empty.put("late") is False
 
 
-def test_every_item_arrives_once_under_contention():
+@pytest.mark.parametrize("bounded", [None, lambda item: item[1] % 3 == 0],
+                         ids=["all", "some"])
+def test_every_item_arrives_once_under_contention(bounded):
     """More threads than cores on a queue of two slots, the interpreter
     switching threads every 10 us: no item lost or doubled, and every
-    consumer ends on close."""
+    consumer ends on close; the same where the bound counts only some
+    items and the others are queued past it."""
     n_producers, n_consumers, per_producer = 12, 12, 300
-    q = _StageQueue(2)
+    q = _StageQueue(2, bounded)
     got = [[] for _ in range(n_consumers)]
 
     def produce(k):
