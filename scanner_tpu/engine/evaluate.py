@@ -29,7 +29,9 @@ steady-state tasks never stall on a compile."""
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import dataclasses
 import enum
 import functools
 import os
@@ -89,9 +91,31 @@ _M_OP_DISPATCH_SECONDS = _mx.registry().counter(
     labels=["op"])
 _M_DEVICE_WAIT_SECONDS = _mx.registry().counter(
     "scanner_tpu_device_wait_seconds_total",
-    "Evaluator seconds blocked until a device op's call had finished "
-    "on the chip (evaluate:device_wait: the wait that times the call "
-    "for the roofline gauges, and the drain after a first call).",
+    "Evaluator seconds held back by the chip (evaluate:device_wait): "
+    "the wait for the oldest of the two calls an evaluator keeps in "
+    "flight, taken before the dispatch that would make a third, at the "
+    "evaluator's release, and the drain after a first call; by the op "
+    "whose call was waited for.",
+    labels=["op"])
+# the window of calls in flight (CallWindow)
+_M_OP_CALLS = _mx.registry().counter(
+    "scanner_tpu_op_calls_total",
+    "Batched device calls an evaluator dispatched, per op (a fused "
+    "chain under its id).",
+    labels=["op"])
+_M_OP_CALLS_DEFERRED = _mx.registry().counter(
+    "scanner_tpu_op_calls_deferred_total",
+    "Batched device calls whose wait was taken after a later call had "
+    "been dispatched, or at the evaluator's release: the host prepared "
+    "the next call while the chip ran this one.  What is left of "
+    "scanner_tpu_op_calls_total was drained at once: a signature's "
+    "first call.",
+    labels=["op"])
+_M_OP_CALLS_BLOCKED = _mx.registry().counter(
+    "scanner_tpu_op_calls_blocked_total",
+    "Deferred waits that found the call still running: the chip set "
+    "the pace there (and the call was timed for the roofline gauges). "
+    "The others found it done: the host did.",
     labels=["op"])
 # a device column handed to a host op: the wait for its producer's
 # program, the row-major layout where the chip held it otherwise
@@ -509,6 +533,169 @@ def _state_call_lengths(cap: int, warmup: int, wp: int) -> List[int]:
     runs = {warmup + wp} | {cs + wp for cs in range(0, warmup, wp)}
     return sorted({cap} | {r % cap for r in runs if r % cap},
                   reverse=True)
+
+
+# One call running and one queued keep a chip that runs its calls in
+# order fed whenever the host's work a call is shorter than the chip's;
+# a third buys nothing and holds a packet's inputs, temporaries and
+# result in HBM.  A constant: nothing observes a reason to change it.
+CALLS_IN_FLIGHT = 2
+
+
+@dataclasses.dataclass(slots=True)
+class _Call:
+    """One dispatched call of the window."""
+
+    result: Any
+    op: str                 # the op's name, a fused chain's id
+    dev: str
+    bucket: int             # rows called, padding included
+    rows: int               # rows asked for
+    t_dispatch: float
+    desc: Optional["_cs.CostDescriptor"]  # None: not to be timed
+    saved: Optional[float]  # a chain's HBM bytes saved, for its gauges
+    ki: Any                 # the op's KernelInstance, None for a chain
+    owner: Any              # the task that dispatched it
+
+
+class CallWindow:
+    """The batched device calls one evaluator has dispatched and not
+    yet waited for: at most CALLS_IN_FLIGHT, of whatever op or chain,
+    since the evaluator's chip runs them in the order they came.  The
+    wait for a call is taken when the dispatch that would make one too
+    many is due (`admit`), so the host prepares call k+1 while the chip
+    runs call k; it is the evaluator's back-pressure, with coststats on
+    or off, and where coststats is on it is also what times the call
+    (util/coststats.py CallClock).  The window lives across chunks,
+    tasks and ops and is emptied at the evaluator's release (`drain`).
+
+    A call that failed on the chip raises at its wait.  Taken inside
+    the task that dispatched it (`owner`), the failure is that task's;
+    taken later it is logged, and the task fails where its results are
+    fetched.  Either way the op is named, an OOM is noted and a
+    stateful kernel is reset."""
+
+    def __init__(self, profiler: Profiler):
+        self.profiler = profiler
+        self.owner: Any = None  # the task now dispatching
+        self._calls: "collections.deque[_Call]" = collections.deque()
+        self._clock = _cs.CallClock()
+        # op -> [seconds, flops, bytes] timed since the op's last
+        # take_run: its next op.efficiency event
+        self._runs: Dict[str, List[float]] = {}
+
+    def __len__(self) -> int:
+        return len(self._calls)
+
+    def admit(self) -> None:
+        """Before a dispatch: make room for its call."""
+        while len(self._calls) >= CALLS_IN_FLIGHT:
+            self._wait(self._calls.popleft())
+
+    def put(self, result, op: str, dev: str, bucket: int, rows: int,
+            t_dispatch: float, first: bool = False,
+            desc: Optional["_cs.CostDescriptor"] = None,
+            saved: Optional[float] = None, ki: Any = None) -> None:
+        """After a dispatch: its call is in flight.  A signature's
+        `first` call is drained at once, and what went before it: its
+        seconds hold its compile and never read as the op's."""
+        c = _Call(result, op, dev, bucket, rows, t_dispatch, desc, saved,
+                  ki, self.owner)
+        _M_OP_CALLS.labels(op=op).inc()
+        if first:
+            self.drain()
+            self._wait(c, deferred=False)
+        else:
+            self._calls.append(c)
+
+    def drain(self) -> None:
+        """Wait for every call in flight (the evaluator's release, and
+        ahead of a first call)."""
+        while self._calls:
+            self._wait(self._calls.popleft())
+
+    def abandon(self) -> None:
+        """The task now dispatching failed: its calls leave the window,
+        waited for and neither timed nor heard."""
+        mine = [c for c in self._calls if c.owner == self.owner]
+        self._calls = collections.deque(
+            c for c in self._calls if c.owner != self.owner)
+        for c in mine:
+            try:
+                _cs.wait_ready(c.result)
+            except Exception:  # noqa: BLE001 — the task has failed
+                _log.debug("abandoned call of %s failed", c.op,
+                           exc_info=True)
+
+    def take_run(self, op: str) -> Optional[Tuple[float, float, float]]:
+        """(seconds, flops, bytes) of `op`'s calls timed since it was
+        last asked, or None."""
+        run = self._runs.pop(op, None)
+        return tuple(run) if run else None
+
+    def _wait(self, c: _Call, deferred: bool = True) -> None:
+        with self.profiler.span(
+                "evaluate:device_wait", op=c.op,
+                counter=_M_DEVICE_WAIT_SECONDS.labels(op=c.op)) as span:
+            blocked = not _cs.result_ready(c.result)
+            span.args["blocked"] = blocked
+            try:
+                _cs.wait_ready(c.result)
+            except Exception as e:  # noqa: BLE001
+                self._failed(c, e)  # raises inside the call's own task
+                return
+            t_done = time.time()
+        if deferred:
+            _M_OP_CALLS_DEFERRED.labels(op=c.op).inc()
+            if blocked:
+                _M_OP_CALLS_BLOCKED.labels(op=c.op).inc()
+        secs = self._clock.done(c.t_dispatch, t_done,
+                                blocked and deferred)
+        if secs is None or c.desc is None:
+            return
+        # the chip's seconds joined with the analytical descriptor
+        cls = _cs.record_op_call(c.op, c.dev, c.bucket, c.rows, secs,
+                                 c.desc)
+        if cls is not None and c.saved is not None:
+            _fusion.chain_metrics_for(c.op, c.dev, c.bucket, cls, c.saved)
+        run = self._runs.setdefault(c.op, [0.0, 0.0, 0.0])
+        run[0] += secs
+        run[1] += c.desc.flops or 0.0
+        run[2] += c.desc.bytes_total
+
+    def _failed(self, c: _Call, e: Exception) -> None:
+        """A call failed on the chip.  Raises for the task that
+        dispatched it; another task's evaluation goes on."""
+        if c.ki is not None and c.ki.spec.is_stateful:
+            # as a failure at the dispatch: the state is partial
+            try:
+                c.ki.kernel.reset()
+            finally:
+                c.ki._last_row = None
+        _note_call_failure(e, f"op {c.op} on {c.dev}")
+        if c.owner == self.owner and c.owner is not None:
+            raise e
+        _log.error(
+            "device call of op %s on %s (task %s, %d rows) failed after "
+            "its task had left the evaluator; the task fails where its "
+            "results are fetched: %s: %s", c.op, c.dev, c.owner, c.rows,
+            type(e).__name__, str(e)[:300])
+
+
+def _note_call_failure(e: BaseException, detail: str) -> None:
+    """What a failed device call gets, at its dispatch or at its
+    deferred wait, once: the op's name on the exception, and for an OOM
+    the forensics (the report names the ledger entries, and their
+    tasks, that held HBM when this op's allocation failed)."""
+    if getattr(e, "_scanner_tpu_call_noted", False):
+        return
+    try:
+        e._scanner_tpu_call_noted = True
+        e.add_note(f"scanner_tpu: in {detail}")
+    except Exception:  # noqa: BLE001 — an exception without a __dict__
+        pass
+    if _ms.is_oom(e):
+        _ms.note_oom(e, site="dispatch", detail=detail)
 
 
 class StateCarryMiss(Exception):
@@ -1215,6 +1402,7 @@ class EvaluatorPool:
     def give(self, te: "TaskEvaluator") -> None:
         """The end of a run's hold on `te`: kept for the next run of
         its key, or closed."""
+        te.release()
         with self._lock:
             keep = te.pool_key is not None and te.pool_key == self._key \
                 and te.instance not in self._kept
@@ -1260,6 +1448,9 @@ class TaskEvaluator:
         self.pool_key: Any = None
         if devices is None:
             devices = instance_devices(instance, instances)
+        # the batched device calls in flight, of every op and chain of
+        # this instance: its chip runs them in order
+        self.calls = CallWindow(profiler)
         self.kernels: Dict[int, KernelInstance] = {}
         # kernel construction and set-up load weights and may compile
         # (a model's init): the pipeline waits on this, not on the chip
@@ -1408,7 +1599,16 @@ class TaskEvaluator:
         self._spawn_warm(claimed, hint)
         return len(claimed)
 
+    def release(self) -> None:
+        """The end of a driver's hold on this evaluator (its task
+        stream closed): the calls still in flight are waited for, so no
+        result outlives its run unwaited.  Their tasks have left: a
+        failure here is logged and fails its task at the fetch."""
+        self.calls.owner = None
+        self.calls.drain()
+
     def close(self) -> None:
+        self.release()
         with _LIVE_LOCK:
             _LIVE_EVALUATORS.discard(self)
         for ki in self.kernels.values():
@@ -1424,7 +1624,7 @@ class TaskEvaluator:
         their chip, their jitted callables, `_shape_sigs` and the
         warm-up's outcome.  The same `info` again (a Worker's bulk,
         entered again) keeps its streams and state where they are."""
-        self.profiler = profiler
+        self.profiler = self.calls.profiler = profiler
         for ki in self.kernels.values():
             ki.profiler = profiler
         if info is self.info:
@@ -1458,6 +1658,18 @@ class TaskEvaluator:
                      ) -> Dict[int, ColumnBatch]:
         """Run one task.  source_batches: Input node id -> ColumnBatch.
         Returns sink node id -> ColumnBatch of output rows."""
+        # whose the calls dispatched from here on are (a streaming
+        # task's chunks are one task)
+        self.calls.owner = (plan.job_idx, plan.task_idx)
+        try:
+            return self._execute_ops(jr, plan, source_batches)
+        except BaseException:
+            self.calls.abandon()
+            raise
+
+    def _execute_ops(self, jr: A.JobRows, plan: A.TaskPlan,
+                     source_batches: Dict[int, ColumnBatch]
+                     ) -> Dict[int, ColumnBatch]:
         store: Dict[ColKey, ColumnBatch] = {}
         results: Dict[int, ColumnBatch] = {}
         # remaining column-reads per producer: a column is dropped from the
@@ -1520,6 +1732,21 @@ class TaskEvaluator:
                     for key in [k for k in store if k[0] == pid]:
                         del store[key]
         return results
+
+    def _efficiency_event(self, op: str, device: str, **attrs) -> None:
+        """ONE op.efficiency event on the op's trace span (per-call
+        detail goes to the gauges): the roofline verdict of the op's
+        calls whose seconds the window read since its last span, which
+        with waits taken late are the calls of two calls back."""
+        run = self.calls.take_run(op)
+        if run is None:
+            return
+        secs, flops, nbytes = run
+        cls = _cs.classify(device, flops or None, nbytes, secs)
+        if cls is not None:
+            _tracing.add_event("op.efficiency", op=op, device=device,
+                               eff=round(cls["eff"], 6),
+                               bound=cls["bound"], **attrs)
 
     def _op_span(self, op: str, rows: int, device: str):
         """The `evaluate:<op>` span of one op over one task or chunk,
@@ -1830,15 +2057,14 @@ class TaskEvaluator:
             return args
 
         ki.ensure_warm()
-        # roofline attribution (util/coststats.py): device-kernel calls
-        # join their analytical cost descriptor with measured seconds;
-        # accumulated per op run so ONE op.efficiency event lands on the
-        # op's trace span (per-chunk detail goes to the gauges)
-        track_cost = _cs.enabled() and batched_call \
+        # a batched device call goes into the evaluator's window of
+        # calls in flight (CallWindow); with coststats on its analytical
+        # cost descriptor goes with it, to be joined with the chip's
+        # seconds at its deferred wait (util/coststats.py)
+        in_window = batched_call \
             and n.effective_device() == DeviceType.TPU
-        run_secs = run_flops = run_bytes = 0.0
+        track_cost = in_window and _cs.enabled()
         dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=n.name)
-        wait_s = _M_DEVICE_WAIT_SECONDS.labels(op=n.name)
         # a bounded-state task with a warm-up stands alone: its plan
         # begins with the rows that make its state, so the kernel is
         # reset at its first compute row whatever ran before (a later
@@ -1885,6 +2111,11 @@ class TaskEvaluator:
                                     _M_OP_PAD_ROWS.labels(
                                         op=n.name,
                                         device=ki.dev_label).inc(pad)
+                            if in_window:
+                                # back-pressure: the oldest call in
+                                # flight is waited for before this
+                                # one's arguments take their HBM
+                                self.calls.admit()
                             args = call_args_for(exec_sel)
                             # a never-seen arg (device, shape, dtype)
                             # signature means XLA compiles a fresh
@@ -1926,33 +2157,19 @@ class TaskEvaluator:
                                 else:
                                     with ki._call_lock:
                                         res = ki.kernel.execute(*args)
-                            if track_cost:
-                                # a first call's queued device work is
-                                # drained so the NEXT (measured) call
-                                # times only itself; a measured call is
-                                # blocked on, or async dispatch would
-                                # time the enqueue, not the op
-                                with self.profiler.span(
-                                        "evaluate:device_wait",
-                                        op=n.name, counter=wait_s):
-                                    res = _cs.block_until_ready(res)
-                            if track_cost and not new_sig:
-                                # measured call seconds joined with the
-                                # analytical descriptor; first calls of
-                                # a signature are excluded so compile
-                                # time never reads as inefficiency
-                                call_s = time.time() - t_call
-                                desc = _cs.descriptor_for(
-                                    ki.kernel, n.name, ki.dev_label,
-                                    len(exec_sel), args)
-                                _cs.record_op_call(
-                                    n.name, ki.dev_label,
-                                    len(exec_sel), len(live), call_s,
-                                    desc)
-                                if desc is not None:
-                                    run_secs += call_s
-                                    run_flops += desc.flops or 0.0
-                                    run_bytes += desc.bytes_total
+                            if in_window:
+                                # in flight: its wait is taken two
+                                # calls on (a first call's at once: the
+                                # compile never reads as the op's)
+                                self.calls.put(
+                                    res, n.name, ki.dev_label,
+                                    len(exec_sel), len(live), t_call,
+                                    first=new_sig,
+                                    desc=None if new_sig or not track_cost
+                                    else _cs.descriptor_for(
+                                        ki.kernel, n.name, ki.dev_label,
+                                        len(exec_sel), args),
+                                    ki=ki)
                             if pad:
                                 res = _strip_pad(res, len(live),
                                                  len(out_cols))
@@ -1972,20 +2189,11 @@ class TaskEvaluator:
                 if n.spec.is_stateful:
                     span.args.update(warmup_rows=warm_rows,
                                      resets=ki.resets - resets0)
-                if run_secs > 0:
-                    cls = _cs.classify(ki.dev_label, run_flops or None,
-                                       run_bytes, run_secs)
-                    if cls is not None:
-                        # straggler attribution: the op span carries
-                        # its own roofline verdict, so a slow
-                        # evaluate:<op> stage reads as INEFFICIENT
-                        # (low eff) vs OVERLOADED (high eff, deep
-                        # queues) in the master's analytics
-                        _tracing.add_event(
-                            "op.efficiency", op=n.name,
-                            device=ki.dev_label,
-                            eff=round(cls["eff"], 6),
-                            bound=cls["bound"])
+                # straggler attribution: the op span carries its own
+                # roofline verdict, so a slow evaluate:<op> stage reads
+                # as INEFFICIENT (low eff) vs OVERLOADED (high eff,
+                # deep queues) in the master's analytics
+                self._efficiency_event(n.name, ki.dev_label)
         except BaseException as e:
             # the kernel died mid-run: its internal state is partial and
             # _last_row may already claim the run's end.  Reset both so a
@@ -1997,12 +2205,7 @@ class TaskEvaluator:
                     ki.kernel.reset()
                 finally:
                     ki._last_row = None
-            if _ms.is_oom(e):
-                # dispatch-site OOM forensics: the report names the
-                # ledger entries (and their tasks) that held HBM when
-                # this op's allocation failed
-                _ms.note_oom(e, site="dispatch",
-                             detail=f"op {n.name} on {ki.dev_label}")
+            _note_call_failure(e, f"op {n.name} on {ki.dev_label}")
             raise
         _M_OP_ROWS.labels(op=n.name).inc(len(compute))
         if warm_rows or n.spec.is_stateful:
@@ -2169,9 +2372,7 @@ class TaskEvaluator:
         fki.ensure_warm()
         # chains are always batched TPU dispatch by construction
         track_cost = _cs.enabled()
-        run_secs = run_flops = run_bytes = 0.0
         dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=fki.chain_id)
-        wait_s = _M_DEVICE_WAIT_SECONDS.labels(op=fki.chain_id)
         try:
             with self._op_span(fki.chain_id, len(compute),
                                fki.dev_label):
@@ -2198,6 +2399,7 @@ class TaskEvaluator:
                             _M_OP_PAD_ROWS.labels(
                                 op=fki.chain_id,
                                 device=fki.dev_label).inc(pad)
+                    self.calls.admit()
                     arr = call_data(exec_sel)
                     sig = (fki.dev_label, tuple(arr.shape),
                            str(arr.dtype))
@@ -2227,47 +2429,24 @@ class TaskEvaluator:
                         else:
                             with fki._call_lock:
                                 res = fki.execute(arr)
-                    if track_cost:
-                        with self.profiler.span(
-                                "evaluate:device_wait", op=fki.chain_id,
-                                counter=wait_s):
-                            res = _cs.block_until_ready(res)
-                    if track_cost and not new_sig:
-                        call_s = time.time() - t_call
-                        desc, saved = fki.cost_for(arr.shape, arr.dtype)
-                        cls = _cs.record_op_call(
-                            fki.chain_id, fki.dev_label,
-                            len(exec_sel), len(live), call_s, desc)
-                        if cls is not None:
-                            _fusion.chain_metrics_for(
-                                fki.chain_id, fki.dev_label,
-                                len(exec_sel), cls, saved)
-                        if desc is not None:
-                            run_secs += call_s
-                            run_flops += desc.flops or 0.0
-                            run_bytes += desc.bytes_total
+                    desc, saved = (None, None) if new_sig \
+                        or not track_cost \
+                        else fki.cost_for(arr.shape, arr.dtype)
+                    self.calls.put(res, fki.chain_id, fki.dev_label,
+                                   len(exec_sel), len(live), t_call,
+                                   first=new_sig, desc=desc, saved=saved)
                     if pad:
                         res = _strip_pad(res, len(live), len(out_cols))
                     emit_result(compute[live], res)
                     i = j
-                if run_secs > 0:
-                    cls = _cs.classify(fki.dev_label, run_flops or None,
-                                       run_bytes, run_secs)
-                    if cls is not None:
-                        # straggler attribution for the fused span;
-                        # the chain attr lets timeline consumers group
-                        # fusion events without parsing op labels
-                        _tracing.add_event(
-                            "op.efficiency", op=fki.chain_id,
-                            chain=fki.chain_id,
-                            device=fki.dev_label,
-                            eff=round(cls["eff"], 6),
-                            bound=cls["bound"])
+                # straggler attribution for the fused span; the chain
+                # attr lets timeline consumers group fusion events
+                # without parsing op labels
+                self._efficiency_event(fki.chain_id, fki.dev_label,
+                                       chain=fki.chain_id)
         except BaseException as e:
-            if _ms.is_oom(e):
-                _ms.note_oom(e, site="dispatch",
-                             detail=f"chain {fki.chain_id} on "
-                                    f"{fki.dev_label}")
+            _note_call_failure(
+                e, f"chain {fki.chain_id} on {fki.dev_label}")
             raise
         _M_OP_ROWS.labels(op=fki.chain_id).inc(len(compute))
 

@@ -28,7 +28,9 @@ efficiency plane, two halves:
     (FLOPs and bytes in/out as a function of the call shape, declared
     via the ``Kernel.cost(shapes)`` hook with defaults derived from
     XLA's cost analysis of the compiled executable) joined with the
-    measured per-call seconds the dispatch site already takes, into
+    chip's seconds for the call (``CallClock``: read off the deferred
+    waits of the evaluator's window of calls in flight, no wait of its
+    own) into
     achieved FLOP/s, achieved bytes/s, and a compute-vs-memory-bound
     classification per (op, device, bucket) — the
     ``scanner_tpu_op_*`` efficiency gauges.  A slow task then reads as
@@ -39,7 +41,8 @@ Consumers: the /statusz Efficiency panel, ``scanner_top`` EFF%/bound
 columns and compile-cache hit rate, and ``tools/scanner_cost.py``.
 
 Knobs: ``SCANNER_TPU_COSTSTATS=0`` disables both halves (the dispatch
-sites then skip descriptor/ledger work entirely);
+sites then skip descriptor/ledger work entirely; the bound on the
+calls an evaluator keeps in flight does not hang on it);
 ``SCANNER_TPU_COMPILE_LEDGER`` sizes the ring (default 1024 entries).
 """
 
@@ -272,18 +275,64 @@ def device_peaks(device_label: str) -> Tuple[float, float]:
     return peak
 
 
-def block_until_ready(res: Any) -> Any:
-    """Wait for a kernel call's device work before timing it: on async
-    backends (TPU) execute() returns at enqueue, and host wall time
-    would measure the dispatch overhead, not the op — inflating
-    achieved FLOP/s past the roofline.  One sync per MEASURED chunk
-    call (compile-bearing calls are not measured); disabling coststats
-    removes it.  Pass-through (and guarded) for host-only results."""
+def result_ready(res: Any) -> bool:
+    """Whether the chip has finished a call's result: every leaf that
+    can say so (a jax.Array's `is_ready`) says yes.  A result with no
+    such leaf (host data, a kernel that fetched inside its execute) is
+    ready."""
     try:
         import jax
-        return jax.block_until_ready(res)
-    except Exception:  # noqa: BLE001 — timing aid must not fail a task
-        return res
+        leaves = jax.tree_util.tree_leaves(res)
+    except Exception:  # noqa: BLE001 — no jax, nothing to wait for
+        return True
+    for leaf in leaves:
+        ready = getattr(leaf, "is_ready", None)
+        if ready is not None and not ready():
+            return False
+    return True
+
+
+def wait_ready(res: Any) -> Any:
+    """Block until the chip has finished a call's result.  What the
+    call raised on the chip is raised here: the wait is where an
+    asynchronous failure surfaces.  Pass-through for host-only
+    results."""
+    import jax
+    return jax.block_until_ready(res)
+
+
+class CallClock:
+    """The chip's seconds for calls whose waits are taken late
+    (engine/evaluate.py CallWindow): one per evaluator, whose chip runs
+    its calls in the order they were dispatched.  Call k ran from
+    max(t_dispatch(k), t_done(k-1)) to t_done(k), and t_done(k) is seen
+    only by a wait that blocked and returned then.  A wait that found
+    its result ready saw no completion edge: that call is not timed,
+    and all it tells the calls after it is that the chip was busy until
+    its own start at the least.  So `_free` is a lower bound of the
+    time the chip had finished everything dispatched so far, a timed
+    call's start is never later than the true one, and no sample is
+    shorter than the chip's time for the call (but for the jitter of
+    the wake-up its predecessor's edge was seen with, which the sum of
+    consecutive samples, what the gauges divide by, does not have): an
+    efficiency gauge fed from here under-reads where the bound is
+    loose and never over-reads."""
+
+    def __init__(self) -> None:
+        self._free = 0.0
+
+    def done(self, t_dispatch: float, t_done: float,
+             blocked: bool) -> Optional[float]:
+        """A call's wait returned at `t_done`.  Its seconds on the chip
+        where the wait blocked (the completion edge was seen), else
+        None (as for a call that is not to be timed at all: a first
+        call, whose seconds hold its compile)."""
+        start = max(t_dispatch, self._free)
+        if not blocked:
+            self._free = start
+            return None
+        self._free = t_done
+        return t_done - start
 
 
 def classify(device_label: str, flops: Optional[float],

@@ -124,9 +124,21 @@ def test_a_part_lies_inside_its_stage_and_feeds_its_counter(
     kids = [iv for iv in ivs if iv.name == child]
     parents = [iv for iv in ivs if iv.name == parent]
     assert kids, f"no {child} interval"
-    for c in kids:
-        assert any(p.thread == c.thread and p.start <= c.start
-                   and c.end <= p.end for p in parents), c
+    out = [c for c in kids
+           if not any(p.thread == c.thread and p.start <= c.start
+                      and c.end <= p.end for p in parents)]
+    if child == "evaluate:device_wait":
+        # a call's wait is taken two calls on, inside the op's span of
+        # that later call; an evaluator's last two at its release,
+        # after its last task
+        for t in {c.thread for c in kids}:
+            last = max(iv.end for iv in ivs
+                       if iv.name == "evaluate" and iv.thread == t)
+            late = [c for c in out if c.thread == t]
+            assert len(late) <= 2 and all(c.start >= last for c in late)
+        assert len(kids) > len(out)
+    else:
+        assert not out, out
     # the counter took the spans' seconds at the spans' own clock reads
     assert delta[(series, tuple(labels.items()))] == pytest.approx(
         sum(iv.end - iv.start for iv in kids), rel=1e-6, abs=1e-9)
