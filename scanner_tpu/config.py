@@ -58,10 +58,6 @@ def default_config() -> Dict[str, Any]:
             # materialize in HBM.  On by default (off = the staged
             # path).
             "fusion_enabled": True,
-            # minimum chain length the fusion planner will fuse (a
-            # singleton IS the staged path; raise to bound planner
-            # aggressiveness).
-            "fusion_min_chain": 2,
         },
         "memory": {
             # memory observability (util/memstats.py): per-device HBM
@@ -263,12 +259,6 @@ class Config:
         """Whole-pipeline XLA fusion of device op chains."""
         return bool(self.config.get("perf", {}).get("fusion_enabled",
                                                     True))
-
-    @property
-    def fusion_min_chain(self) -> int:
-        """Minimum member count the fusion planner fuses (>= 2)."""
-        return int(self.config.get("perf", {}).get("fusion_min_chain",
-                                                   2))
 
     @property
     def memstats_enabled(self) -> bool:

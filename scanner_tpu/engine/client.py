@@ -132,7 +132,6 @@ class Client:
             # [perf] fusion_*: whole-pipeline XLA fusion defaults
             from ..graph import fusion as _fusion_cfg
             _fusion_cfg.set_enabled(cfg.fusion_enabled)
-            _fusion_cfg.set_min_chain(cfg.fusion_min_chain)
             # [alerts] section: health/SLO engine default + user rules;
             # the SCANNER_TPU_HEALTH env var (read at import) wins
             from ..util import health as _health_cfg

@@ -554,7 +554,7 @@ class _Call:
     t_dispatch: float
     desc: Optional["_cs.CostDescriptor"]  # None: not to be timed
     saved: Optional[float]  # a chain's HBM bytes saved, for its gauges
-    ki: Any                 # the op's KernelInstance, None for a chain
+    ki: Any                 # a stateful op's KernelInstance, else None
     owner: Any              # the task that dispatched it
 
 
@@ -666,7 +666,7 @@ class CallWindow:
     def _failed(self, c: _Call, e: Exception) -> None:
         """A call failed on the chip.  Raises for the task that
         dispatched it; another task's evaluation goes on."""
-        if c.ki is not None and c.ki.spec.is_stateful:
+        if c.ki is not None:
             # as a failure at the dispatch: the state is partial
             try:
                 c.ki.kernel.reset()
@@ -706,13 +706,133 @@ class StateCarryMiss(Exception):
     optimization, never a correctness dependency."""
 
 
-class KernelInstance:
+class _CallUnit:
+    """What TaskEvaluator._run_op calls: a staged op (KernelInstance)
+    or a fused chain (FusedKernelInstance).  Each answers the call
+    loop's few questions its own way (`label`, `cap_for`,
+    `input_columns`, `window_rows`, `call_args`, `execute`, `cost`,
+    and for the warm-up `_example_args`); what they share is here: the
+    chip, the signatures run so far, the lock around a call and the
+    bucket-ladder warm-up handshake."""
+
+    label: str
+    # a chain's member op names, for the compile ledger; None for an op
+    member_names: Optional[List[str]] = None
+
+    def __init__(self, device: Optional[Any]):
+        # the chip this unit's calls are pinned to (evaluator
+        # affinity); None = jax default placement.  Committed inputs on
+        # this device pull the shared jitted functions onto it.
+        self.device = device
+        self.dev_label = device_label(device)
+        # input (device, shape, dtype) signatures already executed (XLA
+        # recompile proxy — dtype included: equal shapes with different
+        # dtypes are distinct executables)
+        self._shape_sigs: set = set()
+        # bucket-ladder warm-up state: idle (not scheduled) | pending
+        # (scheduled, not started) | running | done
+        self._warm_lock = threading.Lock()
+        self._warm_state = "idle"
+        self._warm_done = threading.Event()
+        # serializes execute between the evaluation thread and a
+        # warm-up/re-warm thread: two concurrent execute() calls on one
+        # kernel instance are not guaranteed safe, and the ensure_warm
+        # handshake alone cannot cover a MID-RUN rewarm (the
+        # recompile_storm remediation).  Uncontended in steady state.
+        self._call_lock = threading.Lock()
+
+    def precompile(self, ladder: Sequence[int], h: int, w: int) -> None:
+        """Compile this unit's program at every ladder bucket.  A
+        warm-up shape that fails is logged at WARNING with its
+        exception and the rest of the ladder is abandoned (the job stays
+        alive; the real call then compiles — or raises — itself).  Runs
+        on the evaluator's warm-up thread; ensure_warm() on the
+        evaluation thread claims or waits."""
+        with self._warm_lock:
+            if self._warm_state != "pending":
+                return  # claimed by a real call racing ahead of us
+            self._warm_state = "running"
+        t0 = time.time()
+        _M_WARMING.inc()
+        try:
+            for b in ladder:
+                args = self._example_args(b, h, w)
+                if args is None:
+                    return
+                if self.device is not None:
+                    # warm THIS unit's chip: committed example inputs
+                    # compile the ladder executable for the device the
+                    # real calls will run on (the persistent compilation
+                    # cache dedups the XLA work across same-kind chips)
+                    import jax
+                    staged = []
+                    for a in args:
+                        if isinstance(a, np.ndarray):
+                            a = jax.device_put(a, self.device)
+                            # ledger: warm-up args hold HBM until this
+                            # bucket's compile finishes
+                            _ms.track_array(a, "warmup",
+                                            device=self.dev_label)
+                        staged.append(a)
+                    args = staged
+                try:
+                    # compile ledger: the warm-up compile of this
+                    # ladder rung is attributed to (op, device, bucket)
+                    # — with the persistent cache configured, a warmed
+                    # restart records it as a `hit`
+                    with self._call_lock, \
+                            _cs.observe_compiles(self.label,
+                                                 self.dev_label, b,
+                                                 f"warmup:b{b}",
+                                                 members=self.member_names):
+                        self.execute(*args)
+                except Exception:  # noqa: BLE001 — the job outlives
+                    # a failed warm-up, but never silently
+                    _log.warning("precompile of %s at batch %d failed; "
+                                 "abandoning its ladder warm-up",
+                                 self.label, b, exc_info=True)
+                    return
+            self._warm_window(ladder, h, w)
+            _M_OP_PRECOMPILE.labels(op=self.label,
+                                    device=self.dev_label).set(
+                time.time() - t0)
+        finally:
+            self._drop_warm_state()
+            _M_WARMING.dec()
+            with self._warm_lock:
+                self._warm_state = "done"
+            self._warm_done.set()
+
+    def _warm_window(self, ladder: Sequence[int], h: int, w: int) -> None:
+        """After the ladder: what else a call's inputs go through."""
+
+    def _drop_warm_state(self) -> None:
+        """After the warm-up, done or abandoned: what its example rows
+        left behind."""
+
+    def ensure_warm(self) -> None:
+        """Called before a real execute(): if this unit's warm-up is
+        mid-flight, wait for it (two concurrent execute() calls on one
+        kernel instance are not guaranteed safe); if it has not started
+        yet, claim it so the warm-up thread skips this unit."""
+        with self._warm_lock:
+            if self._warm_state == "pending":
+                self._warm_state = "done"
+                self._warm_done.set()
+                return
+            if self._warm_state != "running":
+                return
+        self._warm_done.wait()
+
+
+class KernelInstance(_CallUnit):
     """One live kernel with its stream/state bookkeeping."""
 
     def __init__(self, node: O.OpNode, profiler: Profiler,
                  devices: Optional[List[Any]] = None,
                  device: Optional[Any] = None):
         assert node.spec is not None and node.spec.kernel_factory is not None
+        super().__init__(device)
         self.node = node
         self.spec = node.spec
         cfg = O.KernelConfig(device=node.effective_device(),
@@ -725,37 +845,17 @@ class KernelInstance:
         factory = O.registry.canonical_factory(self.spec)
         self.kernel = factory(cfg, **node.init_args)
         self.profiler = profiler
-        # the chip this instance's calls are pinned to (evaluator
-        # affinity); None = jax default placement.  Committed inputs on
-        # this device pull the shared jitted functions onto it.
-        self.device = device
-        self.dev_label = device_label(device)
         self._cur_stream: Tuple[int, int] = (-1, -1)  # (job, slice group)
         self._last_row: Optional[int] = None
         # resets fired so far (reset_state): an op span reads its own
         self.resets = 0
         self._did_setup = False
-        # input (shape, dtype) signatures already executed (XLA recompile
-        # proxy — dtype included: equal shapes with different dtypes are
-        # distinct executables)
-        self._shape_sigs: set = set()
-        # bucket-ladder warm-up state: idle (not scheduled) | pending
-        # (scheduled, not started) | running | done
-        self._warm_lock = threading.Lock()
-        self._warm_state = "idle"
-        self._warm_done = threading.Event()
         # what the warm-up rehearses of a stencilled op's window besides
         # the op (TaskEvaluator._warm_targets): the row counts its input
         # column arrives in, chunk by chunk, and whether that column
         # travels as YUV420 wire
         self.window_chunks: Tuple[int, ...] = ()
         self.yuv_wire = False
-        # serializes kernel.execute between the evaluation thread and a
-        # warm-up/re-warm thread: two concurrent execute() calls on one
-        # kernel instance are not guaranteed safe, and the ensure_warm
-        # handshake alone cannot cover a MID-RUN rewarm (the
-        # recompile_storm remediation).  Uncontended in steady state.
-        self._call_lock = threading.Lock()
 
     def setup(self, fetch: bool = True) -> None:
         if not self._did_setup:
@@ -810,6 +910,74 @@ class KernelInstance:
             self.reset_state()
         self._last_row = row
 
+    # -- what the call loop asks (TaskEvaluator._run_op) ---------------
+
+    @property
+    def label(self) -> str:
+        return self.node.name
+
+    def cap_for(self, wp: Optional[int]) -> int:
+        """The per-call batch cap.  The batch DECLARATION fixes the
+        calling convention (batched kernels always receive row batches,
+        even 1-row ones) and CAPS the per-call batch (ops declare it as
+        a memory bound); within that cap the work packet `wp`
+        (PerfParams.work_packet_size; 0 = not known) sets the chunk —
+        the XLA batch dimension (reference io/work packet split,
+        master.cpp:1421) — unless the op was constructed with an
+        explicit batch= override."""
+        n = self.node
+        if n.batch is None and wp:
+            return max(1, min(n.effective_batch(), int(wp)))
+        return max(1, n.effective_batch())
+
+    def input_columns(self) -> List[Any]:
+        return self.node.input_columns()
+
+    def window_rows(self, compute: np.ndarray, max_in: int) -> np.ndarray:
+        """(k, W) input rows of compute rows `compute`: the op's stencil
+        around each, REPEAT_EDGE at the input's ends."""
+        sten = np.asarray(self.node.effective_stencil(), np.int64)
+        return np.clip(compute[:, None] + sten[None, :], 0, max_in - 1)
+
+    def call_args(self, batches: List[ColumnBatch],
+                  col_pos: List[np.ndarray], sel: np.ndarray) -> List[Any]:
+        """Kernel arguments for compute positions `sel` (indices into
+        the compute/col_pos arrays): per input column either a (k, ...)
+        batch slice, a (k, W, ...) stencil gather, or per-row python
+        objects."""
+        windowed = self.node.effective_stencil() != [0]
+        op = self.node.name
+        args = []
+        for b, pos in zip(batches, col_pos):
+            p = pos[sel]           # (k, W)
+            if is_array_data(b.data):
+                if windowed:
+                    with self.profiler.span(
+                            "evaluate:gather", op=op, rows=len(p),
+                            counter=_M_GATHER_SECONDS.labels(op=op)):
+                        args.append(_gather_window(b.data, p))
+                    _M_GATHER_BYTES.labels(op=op).inc(args[-1].nbytes)
+                else:
+                    q = p[:, 0]
+                    if len(q) and np.array_equal(
+                            q, np.arange(q[0], q[0] + len(q))):
+                        args.append(rows_run(b.data, int(q[0]), len(q)))
+                    else:
+                        args.append(rows_at(b.data, q))
+            elif windowed:
+                args.append([[b.data[int(j)] for j in row] for row in p])
+            else:
+                args.append([b.data[int(j)] for j in p[:, 0]])
+        return args
+
+    def execute(self, *args):
+        return self.kernel.execute(*args)
+
+    def cost(self, args: List[Any], bucket: int):
+        """(analytical cost descriptor of a call, None: no bytes saved)."""
+        return _cs.descriptor_for(self.kernel, self.label, self.dev_label,
+                                  bucket, args), None
+
     # -- bucket-ladder warm-up (precompile) ----------------------------
 
     def _example_args(self, b: int, h: int, w: int) -> Optional[List[Any]]:
@@ -835,73 +1003,6 @@ class KernelInstance:
                 args.append([[row] * win for _ in range(b)] if win
                             else [row] * b)
         return args
-
-    def precompile(self, ladder: Sequence[int], h: int, w: int) -> None:
-        """Compile this kernel's jitted function at every ladder bucket.
-        A warm-up shape that fails is logged at WARNING with its
-        exception and the rest of the ladder is abandoned (the job stays
-        alive; the real call then compiles — or raises — itself).  Runs
-        on the evaluator's warm-up thread; ensure_warm() on the
-        evaluation thread claims or waits."""
-        with self._warm_lock:
-            if self._warm_state != "pending":
-                return  # claimed by a real call racing ahead of us
-            self._warm_state = "running"
-        t0 = time.time()
-        _M_WARMING.inc()
-        try:
-            for b in ladder:
-                args = self._example_args(b, h, w)
-                if args is None:
-                    return
-                if self.device is not None:
-                    # warm THIS instance's chip: committed example
-                    # inputs compile the ladder executable for the
-                    # device the real calls will run on (the persistent
-                    # compilation cache dedups the XLA work across
-                    # same-kind chips)
-                    import jax
-                    staged = []
-                    for a in args:
-                        if isinstance(a, np.ndarray):
-                            a = jax.device_put(a, self.device)
-                            # ledger: warm-up args hold HBM until this
-                            # bucket's compile finishes (released when
-                            # the arrays are collected at loop exit)
-                            _ms.track_array(a, "warmup",
-                                            device=self.dev_label)
-                        staged.append(a)
-                    args = staged
-                try:
-                    # compile ledger: the warm-up compile of this
-                    # ladder rung is attributed to (op, device, bucket)
-                    # — with the persistent cache configured, a warmed
-                    # restart records it as a `hit`
-                    with self._call_lock, \
-                            _cs.observe_compiles(self.node.name,
-                                                 self.dev_label, b,
-                                                 f"warmup:b{b}"):
-                        self.kernel.execute(*args)
-                except Exception:  # noqa: BLE001 — the job outlives
-                    # a failed warm-up, but never silently
-                    _log.warning("precompile of %s at batch %d failed; "
-                                 "abandoning its ladder warm-up",
-                                 self.node.name, b, exc_info=True)
-                    return
-            self._warm_window(ladder, h, w)
-            _M_OP_PRECOMPILE.labels(op=self.node.name,
-                                    device=self.dev_label).set(
-                time.time() - t0)
-        finally:
-            if self.spec.is_stateful:
-                # the example rows went through the state: drop it before
-                # a task can see it
-                with self._call_lock:
-                    self.kernel.reset()
-            _M_WARMING.dec()
-            with self._warm_lock:
-                self._warm_state = "done"
-            self._warm_done.set()
 
     def _warm_window(self, ladder: Sequence[int], h: int, w: int) -> None:
         """The window's programs in front of a stencilled op, which the
@@ -934,19 +1035,12 @@ class KernelInstance:
                              self.node.name, n, exc_info=True)
                 return
 
-    def ensure_warm(self) -> None:
-        """Called before a real execute(): if this kernel's warm-up is
-        mid-flight, wait for it (two concurrent execute() calls on one
-        kernel instance are not guaranteed safe); if it has not started
-        yet, claim it so the warm-up thread skips this kernel."""
-        with self._warm_lock:
-            if self._warm_state == "pending":
-                self._warm_state = "done"
-                self._warm_done.set()
-                return
-            if self._warm_state != "running":
-                return
-        self._warm_done.wait()
+    def _drop_warm_state(self) -> None:
+        if self.spec.is_stateful:
+            # the example rows went through the state: drop it before a
+            # task can see it
+            with self._call_lock:
+                self.kernel.reset()
 
     def close(self) -> None:
         self.kernel.close()
@@ -1012,7 +1106,7 @@ def _trace_chain(chain_id: str, members, y):
     return y
 
 
-class FusedKernelInstance:
+class FusedKernelInstance(_CallUnit):
     """One planned fusion chain (graph/fusion.py) compiled as a SINGLE
     jitted program: the member kernels' `execute_traced` bodies compose
     inside one trace, so XLA fuses across op boundaries and member
@@ -1022,26 +1116,25 @@ class FusedKernelInstance:
     shape, dtype) signature the chain mints ONE executable where the
     staged path minted len(chain).
 
-    Mirrors KernelInstance's warm-up/call-lock protocol so the
-    evaluator's precompile thread, ensure_warm handshake, and the
-    recompile_storm rewarm path treat chains and single kernels
-    uniformly.  All attribution (recompile proxy, pad rows, compile
-    ledger, op rows/seconds, roofline) keys on the stable chain id
-    `"a+b+c"` — member names joined head to tail."""
+    To the evaluator's call loop, warm-up thread and recompile_storm
+    rewarm it is one more unit (_CallUnit): it reads the head's input
+    column through the composed member windows, and all attribution
+    (recompile proxy, pad rows, compile ledger, op rows/seconds,
+    roofline) keys on the stable chain id `"a+b+c"` — member names
+    joined head to tail."""
 
     def __init__(self, chain: "_fusion.FusionChain",
                  members: List[KernelInstance]):
-        self.chain = chain
-        self.members = members
-        self.chain_id = chain.chain_id
-        self.member_names = chain.member_names
-        self.head = members[0]
-        self.tail = members[-1]
         # all members share this evaluator instance's assigned chip
         # (the planner only fuses same-effective-device TPU runs, and
         # the evaluator pins every TPU kernel to its own chip)
-        self.device = self.tail.device
-        self.dev_label = self.tail.dev_label
+        super().__init__(members[-1].device)
+        self.chain = chain
+        self.members = members
+        self.label = self.chain_id = chain.chain_id
+        self.member_names = chain.member_names
+        self.head = members[0]
+        self.tail = members[-1]
         # per member, head->tail: window length (0 = no window axis,
         # matching _example_args' convention for stencil == [0])
         self.windows = chain.windows()
@@ -1052,14 +1145,9 @@ class FusedKernelInstance:
         # part of the shared-program key — a stream rebind that changes
         # them must map to a different compiled program
         self._stream_args: Optional[List[dict]] = None
-        self._shape_sigs: set = set()
         # (shape, dtype) -> (chain CostDescriptor | None, bytes of
         # member intermediates the fusion avoided materializing)
         self._cost_cache: Dict[Tuple, Tuple] = {}
-        self._warm_lock = threading.Lock()
-        self._warm_state = "idle"
-        self._warm_done = threading.Event()
-        self._call_lock = threading.Lock()
 
     # -- the fused program ---------------------------------------------
 
@@ -1140,10 +1228,36 @@ class FusedKernelInstance:
                               max_in - 1).reshape(-1)
         return pos
 
+    # -- what the call loop asks (TaskEvaluator._run_op) ---------------
+
+    def input_columns(self) -> List[Any]:
+        """The head's input: the only column the chain reads."""
+        return self.chain.head.input_columns()[:1]
+
+    def window_rows(self, compute: np.ndarray, max_in: int) -> np.ndarray:
+        """(k, width) head-input rows of tail compute rows `compute`
+        (compose_positions)."""
+        return self.compose_positions(compute, max_in).reshape(
+            len(compute), self.width)
+
+    def call_args(self, batches: List[ColumnBatch],
+                  col_pos: List[np.ndarray], sel: np.ndarray) -> List[Any]:
+        """The head-input gather for compute positions `sel`: one
+        (k * width, ...) array in composed-window order (the chain body
+        re-folds the window axes member by member)."""
+        (b,), (pos,) = batches, col_pos
+        p = pos[sel].reshape(-1)
+        if not is_array_data(b.data):
+            # object column: stack per-row host data into one array
+            return [np.stack([np.asarray(b.data[int(j)]) for j in p])]
+        if np.array_equal(p, np.arange(p[0], p[0] + len(p))):
+            return [rows_run(b.data, int(p[0]), len(p))]
+        return [rows_at(b.data, p)]
+
     # -- chain cost model ----------------------------------------------
 
-    def cost_for(self, shape, dtype):
-        """Analytical chain descriptor for a head-input signature:
+    def cost(self, args: List[Any], bucket: int):
+        """Analytical chain descriptor for a call's head input:
         member costs summed via stepwise shape inference
         (jax.eval_shape walks the chain without running it), with
         bytes_in/bytes_out taken at the chain BOUNDARY — the fused
@@ -1151,6 +1265,7 @@ class FusedKernelInstance:
         intermediate bytes fusion avoided (every non-tail output +
         every non-head input stays on-chip).  Cached per signature;
         (None, 0.0) when any member lacks a cost model."""
+        shape, dtype = args[0].shape, args[0].dtype
         key = (tuple(shape), str(dtype))
         hit = self._cost_cache.get(key)
         if hit is not None:
@@ -1193,23 +1308,17 @@ class FusedKernelInstance:
     def cap_for(self, wp: Optional[int]) -> int:
         """Per-call batch cap for the CHAIN: walking tail->head, member
         i runs at (tail rows x its downstream window expansion) rows
-        per call, so its own cap (the same work-packet derivation as
-        _run_kernel) divides down by that expansion.  The chain takes
-        the tightest bound — no member ever sees a call larger than it
-        would have accepted staged."""
-        cap = None
-        exp = 1
+        per call, so its own cap (KernelInstance.cap_for) divides down
+        by that expansion.  The chain takes the tightest bound — no
+        member ever sees a call larger than it would have accepted
+        staged."""
+        cap, exp = None, 1
         for ki, win in zip(reversed(self.members),
                            reversed(self.windows)):
-            n = ki.node
-            if n.batch is None and wp:
-                mcap = max(1, min(n.effective_batch(), int(wp)))
-            else:
-                mcap = max(1, n.effective_batch())
-            c = max(1, mcap // max(1, exp))
+            c = max(1, ki.cap_for(wp) // exp)
             cap = c if cap is None else min(cap, c)
             exp *= max(win, 1)
-        return max(1, cap if cap is not None else 1)
+        return cap
 
     def warmable(self) -> bool:
         """Generic warm-up synthesizes head frames at source geometry:
@@ -1220,55 +1329,11 @@ class FusedKernelInstance:
                     and n.spec.input_columns[0][1]
                     and _source_geometry_inputs(n))
 
-    def precompile(self, ladder: Sequence[int], h: int, w: int) -> None:
-        """Compile the fused program at every chain-ladder bucket (one
-        ladder for the WHOLE chain — this is the warm-up the staged
-        path would have run once per member)."""
-        with self._warm_lock:
-            if self._warm_state != "pending":
-                return
-            self._warm_state = "running"
-        t0 = time.time()
-        _M_WARMING.inc()
-        try:
-            for b in ladder:
-                arr = np.zeros((b * self.width, h, w, 3), np.uint8)
-                if self.device is not None:
-                    import jax
-                    arr = jax.device_put(arr, self.device)
-                    _ms.track_array(arr, "warmup", device=self.dev_label)
-                try:
-                    with self._call_lock, \
-                            _cs.observe_compiles(self.chain_id,
-                                                 self.dev_label, b,
-                                                 f"warmup:b{b}",
-                                                 members=self.member_names):
-                        self.execute(arr)
-                except Exception:  # noqa: BLE001 — the job outlives
-                    # a failed warm-up, but never silently
-                    _log.warning("precompile of chain %s at batch %d "
-                                 "failed; abandoning its ladder warm-up",
-                                 self.chain_id, b, exc_info=True)
-                    return
-            _M_OP_PRECOMPILE.labels(op=self.chain_id,
-                                    device=self.dev_label).set(
-                time.time() - t0)
-        finally:
-            _M_WARMING.dec()
-            with self._warm_lock:
-                self._warm_state = "done"
-            self._warm_done.set()
-
-    def ensure_warm(self) -> None:
-        """Same handshake as KernelInstance.ensure_warm."""
-        with self._warm_lock:
-            if self._warm_state == "pending":
-                self._warm_state = "done"
-                self._warm_done.set()
-                return
-            if self._warm_state != "running":
-                return
-        self._warm_done.wait()
+    def _example_args(self, b: int, h: int, w: int) -> List[Any]:
+        """The head's frames for `b` tail rows: one ladder for the
+        WHOLE chain — the warm-up the staged path would have run once
+        per member."""
+        return [np.zeros((b * self.width, h, w, 3), np.uint8)]
 
 
 # every live TaskEvaluator, weakly held: the recompile_storm
@@ -1477,16 +1542,10 @@ class TaskEvaluator:
         # fusable consecutive device ops execute as ONE jitted program.
         # Non-tail members never dispatch (or materialize an output
         # column) on their own — the tail node runs the whole chain.
-        self.chains: Dict[int, "_fusion.FusionChain"] = {}
-        self.fused: Dict[int, FusedKernelInstance] = {}
-        self._chain_member_ids: set = set()
-        if _fusion.enabled():
-            for ch in _fusion.plan_chains_once(info, graph_key(info)):
-                self.chains[ch.tail.id] = ch
-                self.fused[ch.tail.id] = FusedKernelInstance(
-                    ch, [self.kernels[m.id] for m in ch.members])
-                for m in ch.members[:-1]:
-                    self._chain_member_ids.add(m.id)
+        self._key_chains([
+            FusedKernelInstance(ch, [self.kernels[m.id] for m in ch.members])
+            for ch in (_fusion.plan_chains(info) if _fusion.enabled()
+                       else ())])
         # bucket-ladder warm-up: compile every device op's ladder shapes
         # on a background thread so the compiles overlap the first
         # task's decode instead of stalling its evaluation.  `precompile`
@@ -1520,6 +1579,14 @@ class TaskEvaluator:
         with _LIVE_LOCK:
             _LIVE_EVALUATORS.add(self)
 
+    def _key_chains(self, chains: List[FusedKernelInstance]) -> None:
+        """`fused`: tail node id -> its chain; `_chain_member_ids`: the
+        members that never run on their own."""
+        self.fused: Dict[int, FusedKernelInstance] = {
+            fki.tail.node.id: fki for fki in chains}
+        self._chain_member_ids = {ki.node.id for fki in chains
+                                  for ki in fki.members[:-1]}
+
     def _warm_targets(self, precompile: Tuple[int, int, int],
                       rewarm: bool = False
                       ) -> List[Tuple[Any, List[int]]]:
@@ -1532,18 +1599,14 @@ class TaskEvaluator:
         targets: List[Tuple[Any, List[int]]] = []
         for ki in self.kernels.values():
             n = ki.node
-            if n.id in self._chain_member_ids or n.id in self.chains:
+            if n.id in self._chain_member_ids or n.id in self.fused:
                 continue  # fused members warm as one chain, below
             if n.effective_device() != DeviceType.TPU \
                     or n.effective_batch() <= 1 \
                     or not n.stands_alone() or ki.spec.variadic \
                     or not _source_geometry_inputs(n):
                 continue
-            # same per-call cap derivation as _run_kernel
-            if n.batch is None and wp:
-                cap = max(1, min(n.effective_batch(), int(wp)))
-            else:
-                cap = max(1, n.effective_batch())
+            cap = ki.cap_for(wp)
             if ki.spec.is_stateful:
                 if not rewarm:
                     targets.append((ki, _state_call_lengths(
@@ -1640,15 +1703,10 @@ class TaskEvaluator:
             ki._cur_stream = (-1, -1)
             ki._last_row = None
             kernels[ki.node.id] = ki
-        chains, fused = {}, {}
         for fki in self.fused.values():
-            fki.chain = _fusion.FusionChain(
-                [node[m.id] for m in fki.chain.members])
-            chains[fki.chain.tail.id] = fki.chain
-            fused[fki.chain.tail.id] = fki
-        self._chain_member_ids = {node[i].id
-                                  for i in self._chain_member_ids}
-        self.kernels, self.chains, self.fused = kernels, chains, fused
+            fki.chain = _fusion.FusionChain([ki.node for ki in fki.members])
+        self.kernels = kernels
+        self._key_chains(list(self.fused.values()))
         self.info = info
 
     # ------------------------------------------------------------------
@@ -1710,19 +1768,18 @@ class TaskEvaluator:
                         store[(n.id, "output")] = self._run_sampler(
                             n, jr, plan, store)
             else:
-                run = self._run_fused if n.id in self.chains \
-                    else self._run_kernel
-                outs = run(n, jr, plan, store)
+                outs = self._run_op(n, jr, plan, store)
                 self._prefetch_for_host_ops(n, outs)
                 for col, b in outs.items():
                     store[(n.id, col)] = b
             self.last_peak_columns = max(self.last_peak_columns, len(store))
-            if n.id in self.chains:
+            fki = self.fused.get(n.id)
+            if fki is not None:
                 # the whole chain's input edges are consumed here: the
                 # head's (and every member's) reads happen at tail time,
                 # and member columns themselves were never stored
-                cons_cols = [c for m in self.chains[n.id].members
-                             for c in m.input_columns()]
+                cons_cols = [c for ki in fki.members
+                             for c in ki.node.input_columns()]
             else:
                 cons_cols = n.input_columns()
             for c in cons_cols:
@@ -1832,34 +1889,38 @@ class TaskEvaluator:
             for b in outs.values():
                 b.prefetch_host()
 
-    # -- regular kernels -----------------------------------------------
+    # -- ops and chains ------------------------------------------------
 
-    def _run_kernel(self, n: O.OpNode, jr: A.JobRows, plan: A.TaskPlan,
-                    store) -> Dict[str, ColumnBatch]:
+    def _run_op(self, n: O.OpNode, jr: A.JobRows, plan: A.TaskPlan,
+                store) -> Dict[str, ColumnBatch]:
+        """Run non-builtin node `n` over its compute rows: a staged op,
+        or the fused chain whose tail `n` is (its members never run on
+        their own and their outputs never materialize).  What differs
+        between the two the unit answers (_CallUnit); a staged op's
+        state (runs, resets, the carry check) and its batch-1 per-row
+        calls are branches that a chain, stateless and batched, never
+        takes.  A chain's rows are the staged path's exactly:
+        REPEAT_EDGE padding at every member level (compose_positions),
+        null propagation over the composed window, bucketed tail-chunk
+        padding, nulls-last assembly."""
         ts = plan.streams[n.id]
-        ki = self.kernels[n.id]
+        ki = self.fused.get(n.id)
+        staged = ki is None
+        if staged:
+            ki = self.kernels[n.id]
         ki.bind_stream(plan.job_idx, plan.slice_group)
+        op = ki.label
+        stateful = n.spec.is_stateful
 
-        in_cols = n.input_columns()
+        in_cols = ki.input_columns()
         in_batches = [store[(c.op.id, c.column)] for c in in_cols]
         g = plan.slice_group if self.info.slice_level[n.id] > 0 else 0
-        in_op = in_cols[0].op
-        max_in = jr.rows[in_op.id][g]
-        stencil = n.effective_stencil()
-        has_stencil = stencil != [0]
-        # The batch DECLARATION fixes the calling convention (batched
-        # kernels always receive row batches, even 1-row ones) and CAPS
-        # the per-call batch (ops declare it as a memory bound); within
-        # that cap, PerfParams.work_packet_size sets the chunk — the XLA
-        # batch dimension (reference io/work packet split, master.cpp:1421)
-        # — unless the op was constructed with an explicit batch= override.
+        max_in = jr.rows[in_cols[0].op.id][g]
+        # a staged op's stencil: its inputs are a window (a chain's
+        # composed window is read as its inputs)
+        windowed = staged and n.effective_stencil() != [0]
         batched_call = n.effective_batch() > 1
-        if batched_call and n.batch is None:
-            batch = max(1, min(n.effective_batch(),
-                               int(getattr(jr, "work_packet_size",
-                                           n.effective_batch()))))
-        else:
-            batch = max(1, n.effective_batch())
+        batch = ki.cap_for(int(getattr(jr, "work_packet_size", 0) or 0))
 
         # Shape-stable dispatch: device-placed batched kernels wrap
         # jitted functions that compile one executable per (shape,
@@ -1868,8 +1929,8 @@ class TaskEvaluator:
         # the padding off after).  Host/python kernels keep exact shapes
         # (retracing is free), and so do stateful kernels: padding rows
         # would advance their state past the real stream position.
-        use_buckets = (batched_call and not n.spec.is_stateful
-                       and n.effective_device() == DeviceType.TPU
+        on_chip = n.effective_device() == DeviceType.TPU
+        use_buckets = (batched_call and not stateful and on_chip
                        and _bucketing_enabled())
         ladder = bucket_ladder(batch) if use_buckets else None
 
@@ -1881,27 +1942,25 @@ class TaskEvaluator:
         # jitted kernel functions onto it, and a batch the loader
         # pre-staged for this instance is already there (to_device no-ops
         # instead of silently copying cross-chip).
-        is_device_kernel = (n.effective_device() == DeviceType.TPU
-                            and _device_staging_enabled())
+        is_device_kernel = on_chip and _device_staging_enabled()
         compute = np.asarray(ts.compute_rows, np.int64)
-        sten = np.asarray(stencil, np.int64)
         # a stencilled op's window is one span: the columns brought to
         # where the op runs, and the window's rows looked up in them
         # (evaluate:window); the same of an op without one is
         # evaluate:inputs
         window = self.profiler.span(
-            "evaluate:window", op=n.name, rows=len(compute),
-            counter=_M_WINDOW_SECONDS.labels(op=n.name)) \
-            if has_stencil else self.profiler.span(
-                "evaluate:inputs", op=n.name, rows=len(compute),
-                counter=_M_OP_INPUT_SECONDS.labels(op=n.name))
+            "evaluate:window", op=op, rows=len(compute),
+            counter=_M_WINDOW_SECONDS.labels(op=op)) \
+            if windowed else self.profiler.span(
+                "evaluate:inputs", op=op, rows=len(compute),
+                counter=_M_OP_INPUT_SECONDS.labels(op=op))
         with window:
             for i, (c, b) in enumerate(zip(in_cols, in_batches)):
                 if is_device_kernel and isinstance(b.data, np.ndarray) \
                         and b.data.dtype != object:
                     b = b.to_device(ki.device)
                 elif not is_device_kernel:
-                    b = self._hand_off(n.name, b)
+                    b = self._hand_off(op, b)
                 # resolve a pending wire-format conversion (YUV420 staged
                 # at 1.5 B/px) exactly once, where the data now lives: for
                 # device kernels a jitted program of its own ahead of the
@@ -1912,12 +1971,10 @@ class TaskEvaluator:
                 in_batches[i] = b
                 store[(c.op.id, c.column)] = b
             # window positions per compute row per input column
-            # (REPEAT_EDGE)
-            win_rows = np.clip(compute[:, None] + sten[None, :], 0,
-                               max_in - 1)
+            win_rows = ki.window_rows(compute, max_in)
             col_pos = [b.positions(win_rows.reshape(-1)).reshape(
                 win_rows.shape) for b in in_batches]
-        if has_stencil:
+        if windowed:
             halo = len(np.setdiff1d(win_rows, compute))
             if halo:
                 for c in in_cols:
@@ -1939,7 +1996,7 @@ class TaskEvaluator:
         if n.spec.unbounded_state and len(compute) and int(compute[0]) > 0:
             if ki._last_row != int(compute[0]) - 1:
                 raise StateCarryMiss(
-                    f"{n.name}: carry plan expects state at row "
+                    f"{op}: carry plan expects state at row "
                     f"{int(compute[0]) - 1} of stream "
                     f"({plan.job_idx}, {plan.slice_group}); instance is "
                     f"at {ki._last_row}")
@@ -1962,13 +2019,10 @@ class TaskEvaluator:
         mask_nulls = use_buckets and all(
             b.nulls is None or is_array_data(b.data) for b in in_batches)
 
-        # contiguous runs of compute rows; reset state between runs
-        run_bounds: List[Tuple[int, int]] = []
-        start = 0
-        for i in range(1, len(compute) + 1):
-            if i == len(compute) or compute[i] != compute[i - 1] + 1:
-                run_bounds.append((start, i))
-                start = i
+        # contiguous runs of a staged op's compute rows, its state reset
+        # between them; a chain holds no state and calls across them
+        run_bounds = _row_runs(compute) if staged \
+            else [(0, len(compute))]
         out_parts: Dict[str, List[ColumnBatch]] = {c: [] for c in out_cols}
 
         def emit(col: str, rows: np.ndarray, data, per_row: bool) -> None:
@@ -2004,16 +2058,17 @@ class TaskEvaluator:
                 cols_res = tuple(list(col) for col in zip(*res))
             else:
                 raise JobException(
-                    f"{n.name}: expected {len(out_cols)}-tuple output")
+                    f"{op}: expected {len(out_cols)}-tuple output")
             for col, r in zip(out_cols, cols_res):
                 if is_array_data(r) and len(r) == len(rows):
                     emit(col, rows, r, per_row=False)
                 else:
                     if r is None or len(r) != len(rows):
                         raise JobException(
-                            f"{n.name}: batch kernel returned "
-                            f"{0 if r is None else len(r)} results "
-                            f"for {len(rows)} inputs")
+                            f"{op}: "
+                            f"{'batch kernel' if staged else 'fused chain'}"
+                            f" returned {0 if r is None else len(r)} "
+                            f"results for {len(rows)} inputs")
                     emit(col, rows, list(r), per_row=True)
 
         null_out_rows: List[int] = []
@@ -2023,66 +2078,32 @@ class TaskEvaluator:
             if keep.any():
                 null_out_rows.extend(rows[keep].tolist())
 
-        def call_args_for(sel: np.ndarray) -> List[Any]:
-            """Kernel arguments for compute positions `sel` (indices into
-            the compute/col_pos arrays): per input column either a
-            (k, ...) batch slice, a (k, W, ...) stencil gather, or per-row
-            python objects."""
-            args = []
-            for b, pos in zip(in_batches, col_pos):
-                p = pos[sel]           # (k, W)
-                if is_array_data(b.data):
-                    if has_stencil:
-                        with self.profiler.span(
-                                "evaluate:gather", op=n.name, rows=len(p),
-                                counter=_M_GATHER_SECONDS.labels(
-                                    op=n.name)):
-                            args.append(_gather_window(b.data, p))
-                        _M_GATHER_BYTES.labels(op=n.name).inc(
-                            args[-1].nbytes)
-                    else:
-                        q = p[:, 0]
-                        if len(q) and np.array_equal(
-                                q, np.arange(q[0], q[0] + len(q))):
-                            args.append(rows_run(b.data, int(q[0]),
-                                                 len(q)))
-                        else:
-                            args.append(rows_at(b.data, q))
-                else:
-                    if has_stencil:
-                        args.append([[b.data[int(j)] for j in row]
-                                     for row in p])
-                    else:
-                        args.append([b.data[int(j)] for j in p[:, 0]])
-            return args
-
         ki.ensure_warm()
         # a batched device call goes into the evaluator's window of
         # calls in flight (CallWindow); with coststats on its analytical
         # cost descriptor goes with it, to be joined with the chip's
         # seconds at its deferred wait (util/coststats.py)
-        in_window = batched_call \
-            and n.effective_device() == DeviceType.TPU
+        in_window = batched_call and on_chip
         track_cost = in_window and _cs.enabled()
-        dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=n.name)
+        dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=op)
         # a bounded-state task with a warm-up stands alone: its plan
         # begins with the rows that make its state, so the kernel is
         # reset at its first compute row whatever ran before (a later
         # chunk of a streaming task goes on from the chunk before it)
-        fresh_task = n.spec.is_stateful and n.stands_alone() \
-            and not plan.resumes
-        resets0 = ki.resets
+        fresh_task = stateful and n.stands_alone() and not plan.resumes
+        resets0 = ki.resets if stateful else 0
         warm_rows = len(compute) - len(valid_out)
         try:
             with self._op_span(
-                    n.name, len(compute),
+                    op, len(compute),
                     ki.dev_label if is_device_kernel else "host") as span:
                 for lo, hi in run_bounds:
-                    if lo == 0 and fresh_task:
-                        ki.reset_state()
-                    else:
-                        ki.maybe_reset(int(compute[lo]))
-                    ki._last_row = int(compute[hi - 1])
+                    if stateful:
+                        if lo == 0 and fresh_task:
+                            ki.reset_state()
+                        else:
+                            ki.maybe_reset(int(compute[lo]))
+                        ki._last_row = int(compute[hi - 1])
                     i = lo
                     while i < hi:
                         j = min(i + batch, hi)
@@ -2109,14 +2130,15 @@ class TaskEvaluator:
                                         [live,
                                          np.repeat(live[-1:], pad)])
                                     _M_OP_PAD_ROWS.labels(
-                                        op=n.name,
+                                        op=op,
                                         device=ki.dev_label).inc(pad)
                             if in_window:
                                 # back-pressure: the oldest call in
                                 # flight is waited for before this
                                 # one's arguments take their HBM
                                 self.calls.admit()
-                            args = call_args_for(exec_sel)
+                            args = ki.call_args(in_batches, col_pos,
+                                                exec_sel)
                             # a never-seen arg (device, shape, dtype)
                             # signature means XLA compiles a fresh
                             # executable for a jitted kernel — surface it
@@ -2131,85 +2153,90 @@ class TaskEvaluator:
                             if new_sig:
                                 ki._shape_sigs.add(sig)
                                 _M_OP_RECOMPILES.labels(
-                                    op=n.name,
-                                    device=ki.dev_label).inc()
+                                    op=op, device=ki.dev_label).inc()
                                 # a recompile inside a traced task is a
                                 # latency cliff worth pinning to the
                                 # exact op span that paid it
                                 _tracing.add_event(
-                                    "xla.recompile", op=n.name,
+                                    "xla.recompile", op=op,
                                     device=ki.dev_label)
                             t_call = time.time()
                             with self.profiler.span(
-                                    "evaluate:dispatch", op=n.name,
+                                    "evaluate:dispatch", op=op,
                                     rows=len(exec_sel),
                                     counter=dispatch_s):
                                 if new_sig:
                                     # first call of a fresh signature:
                                     # any XLA compile inside lands in
                                     # the compile ledger under this
-                                    # (op, device, bucket)
+                                    # (op, device, bucket); a chain's
+                                    # entry names its members
                                     with ki._call_lock, _first_call(
-                                            n.name, ki.dev_label,
+                                            op, ki.dev_label,
                                             len(exec_sel), repr(sig[1:]),
-                                            track_cost):
-                                        res = ki.kernel.execute(*args)
+                                            track_cost,
+                                            members=ki.member_names):
+                                        res = ki.execute(*args)
                                 else:
                                     with ki._call_lock:
-                                        res = ki.kernel.execute(*args)
+                                        res = ki.execute(*args)
                             if in_window:
                                 # in flight: its wait is taken two
                                 # calls on (a first call's at once: the
                                 # compile never reads as the op's)
+                                desc, saved = (None, None) \
+                                    if new_sig or not track_cost \
+                                    else ki.cost(args, len(exec_sel))
                                 self.calls.put(
-                                    res, n.name, ki.dev_label,
+                                    res, op, ki.dev_label,
                                     len(exec_sel), len(live), t_call,
-                                    first=new_sig,
-                                    desc=None if new_sig or not track_cost
-                                    else _cs.descriptor_for(
-                                        ki.kernel, n.name, ki.dev_label,
-                                        len(exec_sel), args),
-                                    ki=ki)
+                                    first=new_sig, desc=desc, saved=saved,
+                                    ki=ki if stateful else None)
                             if pad:
                                 res = _strip_pad(res, len(live),
                                                  len(out_cols))
                             emit_result(compute[live], res)
                         else:
-                            args = call_args_for(live)
+                            args = ki.call_args(in_batches, col_pos, live)
                             row_args = []
                             for a in args:
                                 e = a[0]
-                                if has_stencil and is_array_data(a):
+                                if windowed and is_array_data(a):
                                     e = list(a[0])
                                 row_args.append(e)
                             with ki._call_lock:
-                                res = ki.kernel.execute(*row_args)
-                            emit_result(compute[live], _single(res, n, out_cols))
+                                res = ki.execute(*row_args)
+                            emit_result(compute[live],
+                                        _single(res, op, out_cols))
                         i = j
-                if n.spec.is_stateful:
+                if stateful:
                     span.args.update(warmup_rows=warm_rows,
                                      resets=ki.resets - resets0)
                 # straggler attribution: the op span carries its own
                 # roofline verdict, so a slow evaluate:<op> stage reads
                 # as INEFFICIENT (low eff) vs OVERLOADED (high eff,
-                # deep queues) in the master's analytics
-                self._efficiency_event(n.name, ki.dev_label)
+                # deep queues) in the master's analytics; a chain's
+                # carries its id, so timeline consumers group fusion
+                # events without parsing op labels
+                self._efficiency_event(
+                    op, ki.dev_label, **({} if staged else {"chain": op}))
         except BaseException as e:
             # the kernel died mid-run: its internal state is partial and
             # _last_row may already claim the run's end.  Reset both so a
             # subsequent carry plan MISSES (fallback) instead of silently
             # continuing from half-advanced state, and a self-contained
             # re-run starts from a clean reset.
-            if ki.spec.is_stateful:
+            if stateful:
                 try:
                     ki.kernel.reset()
                 finally:
                     ki._last_row = None
-            _note_call_failure(e, f"op {n.name} on {ki.dev_label}")
+            _note_call_failure(
+                e, f"{'op' if staged else 'chain'} {op} on {ki.dev_label}")
             raise
-        _M_OP_ROWS.labels(op=n.name).inc(len(compute))
-        if warm_rows or n.spec.is_stateful:
-            _M_STATE_WARMUP_ROWS.labels(op=n.name).inc(warm_rows)
+        _M_OP_ROWS.labels(op=op).inc(len(compute))
+        if warm_rows or stateful:
+            _M_STATE_WARMUP_ROWS.labels(op=op).inc(warm_rows)
 
         # assemble output columns in row order; null-propagated rows (rare)
         # interleave with kernel results, so columns containing them fall
@@ -2242,250 +2269,26 @@ class TaskEvaluator:
             if got != valid_set:
                 missing = sorted(valid_set - got)
                 raise JobException(
-                    f"{n.name}: missing output rows {missing[:5]}...")
-        return outputs
-
-    # -- fused chains ---------------------------------------------------
-
-    def _run_fused(self, n: O.OpNode, jr: A.JobRows, plan: A.TaskPlan,
-                   store) -> Dict[str, ColumnBatch]:
-        """Dispatch one fused chain at its tail node `n`: gather the
-        composed stencil window from the HEAD member's input column,
-        run the single jitted chain program through the chain's bucket
-        ladder, and emit only the tail's outputs — member intermediates
-        never materialize.  Chain-level row semantics reproduce the
-        staged path exactly: REPEAT_EDGE padding at every member level
-        (compose_positions), null propagation over the composed window
-        (a tail row is null iff ANY transitively-read input row is
-        null), bucketed tail-chunk padding, nulls-last assembly."""
-        chain = self.chains[n.id]
-        fki = self.fused[n.id]
-        ts = plan.streams[n.id]
-        fki.bind_stream(plan.job_idx, plan.slice_group)
-
-        head = chain.head
-        in_col = head.input_columns()[0]
-        in_b = store[(in_col.op.id, in_col.column)]
-        g = plan.slice_group if self.info.slice_level[n.id] > 0 else 0
-        max_in = jr.rows[in_col.op.id][g]
-
-        # one chain-wide batch cap (see FusedKernelInstance.cap_for)
-        wp = int(getattr(jr, "work_packet_size", 0) or 0)
-        batch = fki.cap_for(wp)
-        use_buckets = _bucketing_enabled()
-        ladder = bucket_ladder(batch) if use_buckets else None
-
-        compute = np.asarray(ts.compute_rows, np.int64)
-        out_cols = [c for c, _ in n.spec.output_columns]
-        valid_out = np.asarray(ts.valid_output_rows, np.int64)
-        valid_set = set(valid_out.tolist())
-        width = fki.width
-        with self.profiler.span(
-                "evaluate:inputs", op=fki.chain_id, rows=len(compute),
-                counter=_M_OP_INPUT_SECONDS.labels(op=fki.chain_id)):
-            # device staging: ONE host->device move for the head column
-            # — the only HBM traffic the whole chain pays on the input
-            # side
-            if _device_staging_enabled() \
-                    and isinstance(in_b.data, np.ndarray) \
-                    and in_b.data.dtype != object:
-                in_b = in_b.to_device(fki.device)
-            if in_b.convert is not None:
-                in_b = in_b.converted()
-            store[(in_col.op.id, in_col.column)] = in_b
-            # composed window positions per tail compute row
-            # (REPEAT_EDGE at every member level = the staged transitive
-            # dilation)
-            win_rows = fki.compose_positions(compute, max_in).reshape(
-                len(compute), width)
-            col_pos = in_b.positions(win_rows.reshape(-1)).reshape(
-                win_rows.shape)
-
-        # null propagation across the whole chain in one step
-        null_in = np.zeros(len(compute), bool)
-        if in_b.nulls is not None:
-            null_in |= in_b.nulls[col_pos].any(axis=1)
-        mask_nulls = use_buckets and (in_b.nulls is None
-                                      or is_array_data(in_b.data))
-
-        out_parts: Dict[str, List[ColumnBatch]] = {c: [] for c in out_cols}
-
-        def emit(col: str, rows: np.ndarray, data, per_row: bool) -> None:
-            keep = np.isin(rows, valid_out)
-            if not keep.any():
-                return
-            if per_row:
-                kept = [d for d, k in zip(data, keep) if k]
-                out_parts[col].append(
-                    ColumnBatch.from_elements(rows[keep], kept))
-            else:
-                if keep.all():
-                    out_parts[col].append(ColumnBatch(rows, data))
-                else:
-                    idx = np.flatnonzero(keep)
-                    out_parts[col].append(
-                        ColumnBatch(rows[keep], data[idx]))
-
-        def emit_result(rows: np.ndarray, res) -> None:
-            if len(out_cols) == 1:
-                cols_res = (res,)
-            elif isinstance(res, tuple) and len(res) == len(out_cols):
-                cols_res = res
-            elif (isinstance(res, list) and len(res) == len(rows)
-                  and all(isinstance(r, tuple) and len(r) == len(out_cols)
-                          for r in res)):
-                cols_res = tuple(list(col) for col in zip(*res))
-            else:
-                raise JobException(
-                    f"{fki.chain_id}: expected {len(out_cols)}-tuple "
-                    f"output")
-            for col, r in zip(out_cols, cols_res):
-                if is_array_data(r) and len(r) == len(rows):
-                    emit(col, rows, r, per_row=False)
-                else:
-                    if r is None or len(r) != len(rows):
-                        raise JobException(
-                            f"{fki.chain_id}: fused chain returned "
-                            f"{0 if r is None else len(r)} results "
-                            f"for {len(rows)} inputs")
-                    emit(col, rows, list(r), per_row=True)
-
-        null_out_rows: List[int] = []
-
-        def null_rows(rows: np.ndarray) -> None:
-            keep = np.isin(rows, valid_out)
-            if keep.any():
-                null_out_rows.extend(rows[keep].tolist())
-
-        def call_data(sel: np.ndarray):
-            """The head-input gather for compute positions `sel`: a
-            (k * width, ...) array in composed-window order (the chain
-            body re-folds the window axes member by member)."""
-            p = col_pos[sel].reshape(-1)
-            if is_array_data(in_b.data):
-                if np.array_equal(p, np.arange(p[0], p[0] + len(p))):
-                    return rows_run(in_b.data, int(p[0]), len(p))
-                return rows_at(in_b.data, p)
-            # object column: stack per-row host data into one array
-            return np.stack([np.asarray(in_b.data[int(j)]) for j in p])
-
-        fki.ensure_warm()
-        # chains are always batched TPU dispatch by construction
-        track_cost = _cs.enabled()
-        dispatch_s = _M_OP_DISPATCH_SECONDS.labels(op=fki.chain_id)
-        try:
-            with self._op_span(fki.chain_id, len(compute),
-                               fki.dev_label):
-                i = 0
-                while i < len(compute):
-                    j = min(i + batch, len(compute))
-                    sel = np.arange(i, j)
-                    dead = sel[null_in[sel]]
-                    if len(dead):
-                        null_rows(compute[dead])
-                    if mask_nulls and len(dead) < len(sel):
-                        live = sel
-                    else:
-                        live = sel[~null_in[sel]]
-                    if not len(live):
-                        i = j
-                        continue
-                    exec_sel, pad = live, 0
-                    if use_buckets:
-                        pad = bucket_for(len(live), ladder) - len(live)
-                        if pad:
-                            exec_sel = np.concatenate(
-                                [live, np.repeat(live[-1:], pad)])
-                            _M_OP_PAD_ROWS.labels(
-                                op=fki.chain_id,
-                                device=fki.dev_label).inc(pad)
-                    self.calls.admit()
-                    arr = call_data(exec_sel)
-                    sig = (fki.dev_label, tuple(arr.shape),
-                           str(arr.dtype))
-                    new_sig = sig not in fki._shape_sigs
-                    if new_sig:
-                        fki._shape_sigs.add(sig)
-                        _M_OP_RECOMPILES.labels(
-                            op=fki.chain_id,
-                            device=fki.dev_label).inc()
-                        _tracing.add_event("xla.recompile",
-                                           op=fki.chain_id,
-                                           device=fki.dev_label)
-                    t_call = time.time()
-                    with self.profiler.span(
-                            "evaluate:dispatch", op=fki.chain_id,
-                            rows=len(exec_sel), counter=dispatch_s):
-                        if new_sig:
-                            # fresh signature: ONE ledger entry for the
-                            # whole chain, members recorded for
-                            # attribution
-                            with fki._call_lock, _first_call(
-                                    fki.chain_id, fki.dev_label,
-                                    len(exec_sel), repr(sig[1:]),
-                                    track_cost,
-                                    members=fki.member_names):
-                                res = fki.execute(arr)
-                        else:
-                            with fki._call_lock:
-                                res = fki.execute(arr)
-                    desc, saved = (None, None) if new_sig \
-                        or not track_cost \
-                        else fki.cost_for(arr.shape, arr.dtype)
-                    self.calls.put(res, fki.chain_id, fki.dev_label,
-                                   len(exec_sel), len(live), t_call,
-                                   first=new_sig, desc=desc, saved=saved)
-                    if pad:
-                        res = _strip_pad(res, len(live), len(out_cols))
-                    emit_result(compute[live], res)
-                    i = j
-                # straggler attribution for the fused span; the chain
-                # attr lets timeline consumers group fusion events
-                # without parsing op labels
-                self._efficiency_event(fki.chain_id, fki.dev_label,
-                                       chain=fki.chain_id)
-        except BaseException as e:
-            _note_call_failure(
-                e, f"chain {fki.chain_id} on {fki.dev_label}")
-            raise
-        _M_OP_ROWS.labels(op=fki.chain_id).inc(len(compute))
-
-        # assembly: identical to _run_kernel (nulls LAST so they win)
-        null_set = set(null_out_rows)
-        outputs: Dict[str, ColumnBatch] = {}
-        for col in out_cols:
-            parts = out_parts[col]
-            if not parts and not null_set:
-                outputs[col] = ColumnBatch(np.zeros(0, np.int64), [])
-                continue
-            if null_set:
-                by_row: Dict[int, Elem] = {}
-                for p in parts:
-                    for r, e in zip(p.rows.tolist(), p.elements()):
-                        by_row[r] = e
-                for r in null_set:
-                    by_row[int(r)] = NullElement()
-                rows_sorted = np.asarray(sorted(by_row), np.int64)
-                outputs[col] = ColumnBatch.from_elements(
-                    rows_sorted, [by_row[int(r)] for r in rows_sorted])
-            else:
-                parts.sort(
-                    key=lambda p: int(p.rows[0]) if len(p.rows) else 0)
-                outputs[col] = concat_batches(parts)
-            got = set(outputs[col].rows.tolist())
-            if got != valid_set:
-                missing = sorted(valid_set - got)
-                raise JobException(
-                    f"{fki.chain_id}: missing output rows "
-                    f"{missing[:5]}...")
+                    f"{op}: missing output rows {missing[:5]}...")
         return outputs
 
 
-def _single(res, n, out_cols):
+def _row_runs(compute: np.ndarray) -> List[Tuple[int, int]]:
+    """[lo, hi) index ranges of the contiguous runs in `compute`."""
+    runs: List[Tuple[int, int]] = []
+    start = 0
+    for i in range(1, len(compute) + 1):
+        if i == len(compute) or compute[i] != compute[i - 1] + 1:
+            runs.append((start, i))
+            start = i
+    return runs
+
+
+def _single(res, op: str, out_cols):
     """Wrap a batch=1 result to per-row list form for emit_result."""
     if len(out_cols) == 1:
         return [res]
     if not isinstance(res, tuple) or len(res) != len(out_cols):
         raise JobException(
-            f"{n.name}: expected {len(out_cols)}-tuple output")
+            f"{op}: expected {len(out_cols)}-tuple output")
     return tuple([v] for v in res)

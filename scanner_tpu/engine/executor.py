@@ -1052,7 +1052,7 @@ class LocalExecutor:
         # a row DISCONTINUITY, so order is correctness there, not a
         # perf knob.  A bounded-state task with a warm-up begins with
         # the rows that make its state and is reset at its first
-        # compute row (evaluate.py _run_kernel): it stands alone, and
+        # compute row (evaluate.py _run_op): it stands alone, and
         # the run keeps its loaders and instances.
         ordered = any(not n.stands_alone() for n in info.ops)
         serialize = bool(self._chains) or ordered

@@ -14,18 +14,15 @@ boundaries become fusion candidates:
     fusable device ops.  A node is fusable when it is a stateless,
     non-variadic, batched (batch > 1) single-input/single-output TPU
     kernel whose class declares a ``cost()`` descriptor (the hook both
-    feeds the fuse decision and marks the execute body as
+    feeds the chain's roofline gauges and marks the execute body as
     trace-composable — see ``Kernel.execute_traced``).  Host/python
     ops, stateful kernels, and explicit ``fuse=False`` node overrides
     break chains.  A chain extends only while its tail has exactly ONE
     consumer (an intermediate read by anything else must materialize,
     so it becomes the chain's tail instead).
-  * The fuse decision is cost-driven: when the roofline ledger
-    (util/coststats.py ``op_efficiency``) already classified EVERY
-    member of a candidate chain as compute-bound, fusion cannot save
-    HBM traffic and the chain stays staged (a fresh compile for no
-    bandwidth win); any memory-bound (or not-yet-measured) member makes
-    the chain worth one fused executable.
+  * The fuse decision is the graph's alone: a run of two or more
+    fusable ops is a chain, whatever a run has measured, so a graph
+    plans the same chains in every evaluator of every run.
   * Stencil members fuse by composing their window math into the
     chain's input stencil: the chain's read window is the composition
     of member windows, with REPEAT_EDGE clamping applied at every
@@ -37,23 +34,19 @@ The execution half — ``FusedKernelInstance`` composing the member
 engine/evaluate.py.
 
 ``set_enabled(False)`` is the kill switch / A/B lever; the ``[perf]
-fusion_enabled`` / ``fusion_min_chain`` config keys carry deployment
-defaults (docs/guide.md).  docs/observability.md §Fusion catalogs the
+fusion_enabled`` config key carries the deployment default
+(docs/guide.md).  docs/observability.md §Fusion catalogs the
 series below (scanner-check SC317 pins both contracts).
 """
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List
 
 from ..common import DeviceType
 from ..util import metrics as _mx
-from ..util.log import get_logger
 from . import ops as O
-
-_log = get_logger("fusion")
 
 # the SC317 contract: this tuple, the series registered below, and the
 # marker-delimited table in docs/observability.md §Fusion may not drift
@@ -67,7 +60,7 @@ FUSION_SERIES = (
 
 # the [perf] fusion_* config keys config.default_config() must declare
 # — exactly these (scanner-check SC317, both directions)
-CONFIG_KEYS = ("fusion_enabled", "fusion_min_chain")
+CONFIG_KEYS = ("fusion_enabled",)
 
 _M_CHAINS = _mx.registry().gauge(
     "scanner_tpu_fusion_chains_planned",
@@ -95,7 +88,7 @@ _M_BYTES_SAVED = _mx.registry().counter(
     labels=["chain", "device"])
 
 
-# -- knobs ------------------------------------------------------------------
+# -- the kill switch --------------------------------------------------------
 
 _ENABLED = True
 
@@ -111,19 +104,8 @@ def set_enabled(on: bool) -> None:
     _ENABLED = bool(on)
 
 
+# a chain of one IS the staged path
 _MIN_CHAIN = 2
-
-
-def fusion_min_chain() -> int:
-    return _MIN_CHAIN
-
-
-def set_min_chain(n: int) -> None:
-    """[perf] fusion_min_chain config wiring: minimum member count for
-    a chain to fuse (< 2 is meaningless — a singleton IS the staged
-    path)."""
-    global _MIN_CHAIN
-    _MIN_CHAIN = max(2, int(n))
 
 
 # -- the planner ------------------------------------------------------------
@@ -174,10 +156,9 @@ class FusionChain:
 
 def fusable(node: O.OpNode) -> bool:
     """Chain eligibility for one node.  The ``cost()``-override
-    requirement is load-bearing twice over: the planner needs the
-    descriptor for the fuse decision and the chain-level roofline
-    gauges, and declaring it marks the kernel's execute body as
-    trace-composable (SC317 enforces the pairing with
+    requirement is load-bearing twice over: the chain-level roofline
+    gauges need the descriptor, and declaring it marks the kernel's
+    execute body as trace-composable (SC317 enforces the pairing with
     ``execute_traced`` overrides)."""
     if node.is_builtin or node.spec is None:
         return False
@@ -200,34 +181,9 @@ def fusable(node: O.OpNode) -> bool:
     return True
 
 
-def _ledger_probe(node: O.OpNode) -> Optional[str]:
-    """Roofline verdict for one op from the live ledger: "compute" /
-    "memory" when every measured (device, bucket) row of the op agrees
-    or any row is memory-bound, None when the op was never measured."""
-    try:
-        from ..util import coststats as _cs
-        rows = _cs.op_efficiency()
-    except Exception:  # noqa: BLE001 — planning must never fail a job
-        return None
-    bounds = {r["bound"] for r in rows if r["op"] == node.name}
-    if not bounds:
-        return None
-    if "memory" in bounds:
-        return "memory"
-    return "compute"
-
-
-def plan_chains(info, min_chain: Optional[int] = None,
-                probe: Optional[Callable[[O.OpNode], Optional[str]]]
-                = None) -> List[FusionChain]:
+def plan_chains(info) -> List[FusionChain]:
     """Group maximal runs of fusable ops in `info` (a GraphInfo) into
-    FusionChains.  `min_chain` defaults to the configured
-    [perf] fusion_min_chain; `probe` defaults to the roofline-ledger
-    verdict (tests inject their own)."""
-    if min_chain is None:
-        min_chain = fusion_min_chain()
-    if probe is None:
-        probe = _ledger_probe
+    FusionChains."""
     chains: List[FusionChain] = []
     used: set = set()
     for n in info.ops:
@@ -258,57 +214,11 @@ def plan_chains(info, min_chain: Optional[int] = None,
             members.append(nxt)
             used.add(nxt.id)
             cur = nxt
-        if len(members) < max(2, int(min_chain)):
-            continue
-        # cost-driven no-fuse: when the ledger already judged EVERY
-        # member compute-bound, fusing saves no HBM traffic — skip the
-        # fresh chain compile.  Any memory-bound or unmeasured member
-        # keeps the chain.
-        verdicts = [probe(m) for m in members]
-        if all(v == "compute" for v in verdicts):
-            _log.debug("chain %s stays staged: all members compute-bound",
-                       "+".join(m.name for m in members))
+        if len(members) < _MIN_CHAIN:
             continue
         ch = FusionChain(members=members)
         chains.append(ch)
         _M_CHAINS.labels(chain=ch.chain_id).set(len(members))
-    return chains
-
-
-# graph key -> the chains planned for it, each the positions of its
-# members in the graph's topological order; the oldest goes when there
-# are _DECIDED_MAX (a key holds its ops' init args by value)
-_DECIDED: Dict[tuple, List[tuple]] = {}
-_DECIDED_MAX = 64
-_DECIDED_LOCK = threading.Lock()
-
-
-def plan_chains_once(info, key) -> List[FusionChain]:
-    """`plan_chains(info)`, decided once a graph: `key` is the engine's
-    `graph_key(info)` (what of a graph its evaluators are made from),
-    and every later evaluator of that key plans the chains the first
-    one did.  The fuse decision reads the live roofline ledger, which a
-    run's own calls write: left to each evaluator, a graph could run
-    fused in one run and staged in the next, two sets of programs and a
-    recompile under one deployment.  None (a graph not keyable by
-    value): planned each time."""
-    if key is None:
-        return plan_chains(info)
-    key = (key, fusion_min_chain())
-    with _DECIDED_LOCK:
-        decided = _DECIDED.get(key)
-    if decided is None:
-        chains = plan_chains(info)
-        at = {n.id: i for i, n in enumerate(info.ops)}
-        with _DECIDED_LOCK:
-            if len(_DECIDED) >= _DECIDED_MAX:
-                del _DECIDED[next(iter(_DECIDED))]
-            _DECIDED.setdefault(
-                key, [tuple(at[m.id] for m in ch.members) for ch in chains])
-        return chains
-    chains = [FusionChain([info.ops[i] for i in idx]) for idx in decided]
-    for ch in chains:
-        _M_CHAINS.labels(chain=ch.chain_id).set(len(ch.members))
     return chains
 
 

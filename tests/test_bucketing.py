@@ -545,6 +545,47 @@ def test_precompile_claim_beats_warmup(sc, monkeypatch):
         ki.close()
 
 
+@pytest.mark.parametrize("batch,cap", [(None, 8), (4, 4), (32, 32)],
+                         ids=["declared", "smaller", "larger"])
+def test_one_batch_cap_for_the_calls_and_the_warm_up(monkeypatch, batch,
+                                                     cap):
+    """KernelInstance.cap_for is the one derivation: the warm-up's
+    ladder tops out at it, and the call loop chunks at it — the work
+    packet (8) under the declared batch (16), an explicit batch= as
+    given."""
+    from scanner_tpu.engine import evaluate as ev
+    from scanner_tpu.graph import analysis as A
+    from scanner_tpu.graph import ops as O
+    from scanner_tpu.graph.streams_dsl import IOGenerator
+    from scanner_tpu.util.profiler import Profiler
+
+    class Src:
+        is_video = False
+
+    monkeypatch.setenv("SCANNER_TPU_PRECOMPILE", "0")
+    io_g = IOGenerator()
+    frame = io_g.Input([Src()])
+    kw = {} if batch is None else {"batch": batch}
+    info = A.analyze([io_g.Output(O.OpGenerator().BkStat(frame=frame, **kw),
+                                  [Src()])])
+    te = ev.TaskEvaluator(info, Profiler())
+    try:
+        (ki,) = te.kernels.values()
+        ((warmed, ladder),) = te._warm_targets((4, 4, 8))
+        assert warmed is ki and ladder[-1] == ki.cap_for(8) == cap
+        src = info.sources[0]
+        jr = A.job_rows(info, 0, {src.id: 40})
+        jr.work_packet_size = 8
+        plan = A.derive_task_streams(info, jr, (0, 40), task_idx=0)
+        rows = np.asarray(plan.source_rows[src.id], np.int64)
+        BkStat.calls.clear()
+        te.execute_task(jr, plan, {src.id: ColumnBatch(
+            rows, np.zeros((len(rows), 4, 4, 3), np.uint8))})
+        assert max(BkStat.calls) == cap
+    finally:
+        te.close()
+
+
 # ---------------------------------------------------------------------------
 # contiguous-range fast path (ColumnBatch.take_rows / take_range)
 # ---------------------------------------------------------------------------

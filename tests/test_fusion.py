@@ -6,8 +6,7 @@ Contracts pinned here:
 1. **Planner** — maximal runs of fusable device ops form chains; host
    ops, stateful kernels, explicit ``fuse=False`` overrides, missing
    cost() models, and externally-consumed intermediates break chains;
-   ``fusion_min_chain`` and the cost-driven all-compute-bound no-fuse
-   verdict drop candidates.
+   a run of one stays staged.
 2. **Bit-exact equivalence** — the fused chain program produces exactly
    the staged per-op pipeline's rows: stateless chains, stencil
    composition (head and tail stencils), null-interleaved domains,
@@ -19,7 +18,8 @@ Contracts pinned here:
    warms the chain ladder.
 """
 
-from typing import Any, Sequence
+import dataclasses
+from typing import Any, Sequence, Tuple
 
 import numpy as np
 import pytest
@@ -29,12 +29,16 @@ from scanner_tpu import (CacheMode, Client, DeviceType, FrameType, Kernel,
                          PerfParams, register_op)
 import scanner_tpu.kernels  # noqa: F401  (registers the stdlib ops)
 from scanner_tpu import video as scv
+from scanner_tpu.engine import evaluate as ev
+from scanner_tpu.engine.batch import ColumnBatch
 from scanner_tpu.engine.evaluate import bucket_ladder
 from scanner_tpu.graph import analysis as A
 from scanner_tpu.graph import fusion
 from scanner_tpu.graph import ops as O
 from scanner_tpu.graph.streams_dsl import IOGenerator
+from scanner_tpu.common import JobException
 from scanner_tpu.util import coststats as _cs
+from scanner_tpu.util.profiler import Profiler
 from scanner_tpu.util.metrics import registry
 
 N_FRAMES = 50
@@ -117,17 +121,18 @@ class _FzNoCost(Kernel):
         return np.asarray(frame)  # pragma: no cover
 
 
+@register_op(name="FzTwo", device=DeviceType.TPU, batch=8)
+class _FzTwo(Kernel):
+    def execute(self, frame: Sequence[FrameType]) -> Tuple[Any, Any]:
+        return np.zeros(len(frame))  # one column of the two declared
+
+
 def _info(*mk):
     """Build Input -> mk[0] -> mk[1] -> ... -> Output and analyze it."""
     col = io.Input([FakeStream(24)])
     for f in mk:
         col = f(col)
     return A.analyze([io.Output(col, [FakeStream(0)])])
-
-
-def _plan(info, **kw):
-    kw.setdefault("probe", lambda n: None)
-    return fusion.plan_chains(info, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -137,7 +142,7 @@ def _plan(info, **kw):
 def test_plan_basic_chain():
     info = _info(lambda c: ops.FzA(frame=c), lambda c: ops.FzB(frame=c),
                  lambda c: ops.FzC(frame=c))
-    chains = _plan(info)
+    chains = fusion.plan_chains(info)
     assert len(chains) == 1
     ch = chains[0]
     assert ch.member_names == ["FzA", "FzB", "FzC"]
@@ -150,14 +155,14 @@ def test_plan_breaks_at_host_op():
     info = _info(lambda c: ops.FzA(frame=c),
                  lambda c: ops.FzHost(frame=c),
                  lambda c: ops.FzB(frame=c))
-    assert _plan(info) == []
+    assert fusion.plan_chains(info) == []
 
 
 def test_plan_breaks_at_stateful():
     info = _info(lambda c: ops.FzA(frame=c),
                  lambda c: ops.FzState(frame=c),
                  lambda c: ops.FzB(frame=c))
-    assert _plan(info) == []
+    assert fusion.plan_chains(info) == []
 
 
 def test_plan_breaks_at_fuse_false():
@@ -165,11 +170,11 @@ def test_plan_breaks_at_fuse_false():
     info = _info(lambda c: ops.FzA(frame=c),
                  lambda c: ops.FzB(frame=c, fuse=False),
                  lambda c: ops.FzC(frame=c))
-    assert _plan(info) == []
+    assert fusion.plan_chains(info) == []
     # fuse=False at the tail keeps the upstream pair
     info = _info(lambda c: ops.FzA(frame=c), lambda c: ops.FzB(frame=c),
                  lambda c: ops.FzC(frame=c, fuse=False))
-    chains = _plan(info)
+    chains = fusion.plan_chains(info)
     assert [c.member_names for c in chains] == [["FzA", "FzB"]]
 
 
@@ -177,7 +182,7 @@ def test_plan_breaks_at_missing_cost():
     info = _info(lambda c: ops.FzA(frame=c),
                  lambda c: ops.FzNoCost(frame=c),
                  lambda c: ops.FzB(frame=c))
-    assert _plan(info) == []
+    assert fusion.plan_chains(info) == []
 
 
 def test_plan_breaks_at_external_consumer():
@@ -189,34 +194,8 @@ def test_plan_breaks_at_external_consumer():
     c = ops.FzC(frame=b)
     info = A.analyze([io.Output(c, [FakeStream(0)]),
                       io.Output(b, [FakeStream(0)])])
-    chains = _plan(info)
+    chains = fusion.plan_chains(info)
     assert [ch.member_names for ch in chains] == [["FzA", "FzB"]]
-
-
-def test_plan_min_chain():
-    info = _info(lambda c: ops.FzA(frame=c), lambda c: ops.FzB(frame=c))
-    assert len(_plan(info)) == 1
-    assert _plan(info, min_chain=3) == []
-    old = fusion.fusion_min_chain()
-    try:
-        fusion.set_min_chain(3)
-        assert _plan(info, min_chain=None) == []
-        fusion.set_min_chain(0)  # clamps to 2: a singleton IS staged
-        assert fusion.fusion_min_chain() == 2
-    finally:
-        fusion.set_min_chain(old)
-
-
-def test_plan_cost_no_fuse():
-    info = _info(lambda c: ops.FzA(frame=c), lambda c: ops.FzB(frame=c))
-    # every member already judged compute-bound: no HBM win, stay staged
-    assert _plan(info, probe=lambda n: "compute") == []
-    # any memory-bound member keeps the chain
-    assert len(_plan(
-        info, probe=lambda n: "memory" if n.name == "FzB"
-        else "compute")) == 1
-    # unmeasured members fuse by default
-    assert len(_plan(info, probe=lambda n: None)) == 1
 
 
 def test_golden_chain_geometry():
@@ -231,7 +210,7 @@ def test_golden_chain_geometry():
     h = ops.Histogram(frame=b)
     d = ops.HistDiff(frame=h)
     info = A.analyze([io.Output(d, [FakeStream(0)])])
-    chains = _plan(info)
+    chains = fusion.plan_chains(info)
     assert len(chains) == 1
     ch = chains[0]
     assert ch.chain_id == "Resize+Blur+Histogram"
@@ -249,11 +228,54 @@ def test_plan_windowed_op_only_heads_a_chain():
     d = ops.HistDiff(frame=a)       # windowed: breaks the extension
     c = ops.FzB(frame=d)
     info = A.analyze([io.Output(c, [FakeStream(0)])])
-    chains = _plan(info)
+    chains = fusion.plan_chains(info)
     # HistDiff itself heads a chain with FzB; FzA stays a singleton
     assert [ch.member_names for ch in chains] == [["HistDiff", "FzB"]]
     assert chains[0].windows() == [2, 0]
     assert chains[0].width() == 2
+
+
+# ---------------------------------------------------------------------------
+# the one call loop (TaskEvaluator._run_op) names what it ran
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("graph,label,text", [
+    ("op", "Histogram", "missing output rows [24]..."),
+    ("chain", "Blur+Histogram", "missing output rows [24]..."),
+    ("two", "FzTwo", "expected 2-tuple output"),
+], ids=["op", "chain", "tuple"])
+def test_the_call_loop_names_its_op_or_chain_in_errors(monkeypatch, graph,
+                                                        label, text):
+    """A staged op and a chain go through one body and fail in its
+    words, naming the op or the chain id: rows asked for that the calls
+    did not make, a result of fewer columns than declared."""
+    monkeypatch.setenv("SCANNER_TPU_PRECOMPILE", "0")
+    col = io.Input([FakeStream(24)])
+    if graph == "chain":
+        col = ops.Blur(frame=col, kernel_size=3, sigma=1.0)
+    col = ops.FzTwo(frame=col).outputs[0] if graph == "two" \
+        else ops.Histogram(frame=col)
+    info = A.analyze([io.Output(col, [FakeStream(0)])])
+    te = ev.TaskEvaluator(info, Profiler())
+    try:
+        assert [f.chain_id for f in te.fused.values()] \
+            == ([label] if graph == "chain" else [])
+        src = info.sources[0]
+        jr = A.job_rows(info, 0, {src.id: 24})
+        plan = A.derive_task_streams(info, jr, (0, 16), task_idx=0)
+        if graph != "two":
+            # one row asked for past what the task computes
+            tail = col.op.id
+            ts = plan.streams[tail]
+            plan.streams[tail] = dataclasses.replace(
+                ts, valid_output_rows=np.append(ts.valid_output_rows, 24))
+        rows = np.asarray(plan.source_rows[src.id], np.int64)
+        with pytest.raises(JobException) as err:
+            te.execute_task(jr, plan, {src.id: ColumnBatch(
+                rows, np.zeros((len(rows), 16, 16, 3), np.uint8))})
+        assert str(err.value) == f"{label}: {text}"
+    finally:
+        te.close()
 
 
 # ---------------------------------------------------------------------------

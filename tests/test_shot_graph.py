@@ -200,7 +200,7 @@ def test_halo_rows_are_one_per_boundary(sc, perf, units):
 
 
 def test_window_and_host_op_spans(sc):
-    """`evaluate:window` once per stencilled call of `_run_kernel`, with
+    """`evaluate:window` once per stencilled call of `_run_op`, with
     the op's name; `evaluate:<op>` says where the op ran."""
     node = sc.ops.HistogramDelta(hist=sc.ops.Histogram(
         frame=sc.io.Input([NamedVideoStream(sc, "movie")])))

@@ -673,8 +673,8 @@ def _fusion_repo(tmp_path,
                              "scanner_tpu_fusion_b"),
                  doc_series=("scanner_tpu_fusion_a",
                              "scanner_tpu_fusion_b"),
-                 cfg_keys=("fusion_enabled", "fusion_min_chain"),
-                 schema_keys=("fusion_enabled", "fusion_min_chain"),
+                 cfg_keys=("fusion_enabled", "fusion_second_key"),
+                 schema_keys=("fusion_enabled", "fusion_second_key"),
                  with_markers=True,
                  kernel_has_cost=True):
     """Synthetic mini-repo for the SC317 fusion contract lints."""
@@ -769,8 +769,8 @@ def test_fusion_missing_marker_table(tmp_path):
 def test_fusion_config_schema_both_directions(tmp_path):
     _fusion_repo(
         tmp_path,
-        cfg_keys=("fusion_enabled", "fusion_min_chain", "fusion_bogus"),
-        schema_keys=("fusion_enabled", "fusion_min_chain",
+        cfg_keys=("fusion_enabled", "fusion_second_key", "fusion_bogus"),
+        schema_keys=("fusion_enabled", "fusion_second_key",
                      "fusion_ghost_knob"))
     _, findings = _analyze(tmp_path, "pkg")
     msgs = [f.message for f in findings if f.code == "SC317"]

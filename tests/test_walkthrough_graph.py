@@ -5,7 +5,7 @@ against the benchmark's plain reference (benchmark/reference/
 Walkthrough.py) before the encoder; fused against staged; the device
 `Grayscale` against its host flavour; the device column handed to the
 host op (`evaluate:handoff`, its counters, contiguous rows); the kept
-evaluator adopted on a second run; the fuse decision made once a graph;
+evaluator adopted on a second run; the fuse decision made from the graph;
 the spans a cell reads; and the reference's controls, each failing its
 own number.
 """
@@ -27,6 +27,7 @@ from scanner_tpu.engine import evaluate as ev
 from scanner_tpu.engine import framecache as fc
 from scanner_tpu.graph import fusion
 from scanner_tpu.kernels import imgproc
+from scanner_tpu.util import coststats as cs
 from scanner_tpu.util.metrics import registry
 from scanner_tpu.util.profiler import Profiler
 
@@ -296,22 +297,25 @@ def test_the_kept_evaluator_is_adopted_on_a_second_run(sc, bench, cfg):
     assert all(np.array_equal(a, b) for a, b in zip(first, second))
 
 
-def test_the_fuse_decision_is_made_once_a_graph(sc, bench, cfg,
-                                                monkeypatch):
-    """The ledger judging every member compute-bound after a graph's
-    first evaluator was made does not take the graph's later evaluators
-    off the chain; forgotten, the decision is made anew."""
-    rows = _counter("scanner_tpu_op_rows_total", op=CHAIN)
-    _run(sc, bench, cfg, "once0", 8)
-    monkeypatch.setattr(fusion, "_ledger_probe", lambda node: "compute")
-    sc._evaluators.close()  # the next run makes its evaluator anew
-    _run(sc, bench, cfg, "once1", 8)
-    assert _counter("scanner_tpu_op_rows_total", op=CHAIN) == rows + 16
-    fusion._DECIDED.clear()
-    sc._evaluators.close()
-    _run(sc, bench, cfg, "once2", 8)
-    assert _counter("scanner_tpu_op_rows_total", op=CHAIN) == rows + 16
-    fusion._DECIDED.clear()
+def test_the_fuse_decision_is_made_once_a_graph(sc, bench, cfg):
+    """The planner reads the graph alone: with the roofline ledger
+    judging every member compute-bound after a first run (coststats
+    on), a fresh evaluator of the graph still runs the chain."""
+    was = cs.enabled()
+    cs.set_enabled(True)
+    try:
+        _run(sc, bench, cfg, "once0", 8)
+        busy = cs.CostDescriptor(flops=1e15, bytes_in=1.0, bytes_out=1.0)
+        for op in ("Resize", "Grayscale"):
+            cs.record_op_call(op, "unit:once", 8, 8, 1.0, busy)
+        assert {"Resize", "Grayscale"} <= {
+            r["op"] for r in cs.op_efficiency() if r["bound"] == "compute"}
+        sc._evaluators.close()  # the next run makes its evaluator anew
+        rows = _counter("scanner_tpu_op_rows_total", op=CHAIN)
+        _run(sc, bench, cfg, "once1", 8)
+        assert _counter("scanner_tpu_op_rows_total", op=CHAIN) == rows + 8
+    finally:
+        cs.set_enabled(was)
 
 
 def test_the_chains_span_keeps_its_children_and_the_host_op_its_own(
