@@ -141,6 +141,22 @@ _M_HANDOFF_ROWS = _mx.registry().counter(
     "copy), `asis` (copied as it lay).  Either way the host's array "
     "is C-contiguous and its rows are contiguous views.",
     labels=["op", "layout"])
+# the other way: a host column (a host op's output, a column the loader
+# left on the host) put on a device op's chip by the evaluator, once a
+# task or chunk, as one array; the evaluate:upload span, inside the
+# op's evaluate:inputs or evaluate:window
+_M_UPLOAD_SECONDS = _mx.registry().counter(
+    "scanner_tpu_op_upload_seconds_total",
+    "Evaluator seconds a device op (label) spent putting a host column "
+    "on its chip, a task or chunk: the dispatch of one host->device "
+    "copy of the whole column (the copy itself runs on under later "
+    "work); mirrors the evaluate:upload span.",
+    labels=["op"])
+_M_UPLOAD_BYTES = _mx.registry().counter(
+    "scanner_tpu_op_upload_bytes_total",
+    "Bytes of the host columns the evaluator put on a device op's "
+    "(label) chip.",
+    labels=["op"])
 # a stencilled op's window: what it costs to have the producer's column
 # where the op runs (for a host op behind a device op, the wait for the
 # producer's kernel and the device->host fetch), and the rows the
@@ -984,8 +1000,10 @@ class KernelInstance(_CallUnit):
         """Synthesized execute() args at batch size `b` for warm-up:
         frame columns get (b[, W], h, w, 3) uint8 zeros, non-frame
         columns come from the kernel's optional `precompile_input(name)`
-        hook.  None = this op is not generically warmable (variadic, or
-        a non-frame input without a hook)."""
+        hook, a row an array stacked as the real path stages a host
+        column of equal arrays (ColumnBatch.from_elements).  None = this
+        op is not generically warmable (variadic, or a non-frame input
+        without a hook)."""
         if self.spec.variadic:
             return None
         sten = self.node.effective_stencil()
@@ -1000,8 +1018,12 @@ class KernelInstance(_CallUnit):
                 row = hook(name) if hook is not None else None
                 if row is None:
                     return None
-                args.append([[row] * win for _ in range(b)] if win
-                            else [row] * b)
+                if win:
+                    args.append([[row] * win for _ in range(b)])
+                elif isinstance(row, np.ndarray):
+                    args.append(np.stack([row] * b))
+                else:
+                    args.append([row] * b)
         return args
 
     def _warm_window(self, ladder: Sequence[int], h: int, w: int) -> None:
@@ -1874,6 +1896,16 @@ class TaskEvaluator:
         _M_HANDOFF_ROWS.labels(op=op, layout=layout).inc(len(b))
         return b
 
+    def _upload(self, op: str, b: ColumnBatch, device) -> ColumnBatch:
+        """Host array column `b` put on device op `op`'s chip: one
+        asynchronous copy of the whole column, committed to `device`."""
+        with self.profiler.span(
+                "evaluate:upload", op=op, rows=len(b),
+                counter=_M_UPLOAD_SECONDS.labels(op=op)):
+            out = b.to_device(device)
+        _M_UPLOAD_BYTES.labels(op=op).inc(b.data.nbytes)
+        return out
+
     def _prefetch_for_host_ops(self, n: O.OpNode,
                                outs: Dict[str, ColumnBatch]) -> None:
         """Start the copy to the host of what device op (or chain tail)
@@ -1935,9 +1967,10 @@ class TaskEvaluator:
         ladder = bucket_ladder(batch) if use_buckets else None
 
         # Device staging: a device kernel gets its inputs moved host->device
-        # ONCE per task column (async, whole batch); a host kernel gets
-        # device inputs fetched once.  Updated in the store so sibling
-        # consumers of the same column reuse the placement.  The target is
+        # ONCE per task column (async, whole batch: evaluate:upload); a
+        # host kernel gets device inputs fetched once.  Updated in the
+        # store so sibling consumers of the same column reuse the
+        # placement.  The target is
         # THIS instance's assigned chip: committed inputs pull the shared
         # jitted kernel functions onto it, and a batch the loader
         # pre-staged for this instance is already there (to_device no-ops
@@ -1958,7 +1991,7 @@ class TaskEvaluator:
             for i, (c, b) in enumerate(zip(in_cols, in_batches)):
                 if is_device_kernel and isinstance(b.data, np.ndarray) \
                         and b.data.dtype != object:
-                    b = b.to_device(ki.device)
+                    b = self._upload(op, b, ki.device)
                 elif not is_device_kernel:
                     b = self._hand_off(op, b)
                 # resolve a pending wire-format conversion (YUV420 staged

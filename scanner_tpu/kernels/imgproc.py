@@ -345,9 +345,10 @@ class CropResize(Kernel):
 
     def execute(self, frame: Sequence[FrameType],
                 box: Sequence[Any]) -> Sequence[FrameType]:
-        boxes = jnp.asarray(np.stack([np.asarray(b, np.float32)
-                                      for b in box]))
-        return _crop_resize_impl(jnp.asarray(frame), boxes, self.height,
+        # the box column arrives as one (b, 4) array, on the instance's
+        # chip where the engine stages (evaluate:upload)
+        return _crop_resize_impl(jnp.asarray(frame),
+                                 jnp.asarray(box, jnp.float32), self.height,
                                  self.width)
 
 

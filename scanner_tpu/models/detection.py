@@ -120,18 +120,21 @@ def pack_detections(cls, deltas, anchors, score_thresh: float,
     packed (B, top_k, 6) rows [y1, x1, y2, x2, score, valid] plus the
     selected boxes (B, top_k, 4).  Every detection-family kernel
     (ObjectDetect, FaceDetect, InstanceSegment) packs through here so the
-    row layout and NMS policy cannot diverge between them."""
-    probs = jax.nn.softmax(cls, axis=-1)[..., 1:]  # drop background
-    scores = probs.max(axis=-1)
-    boxes = decode_boxes(anchors, deltas)
-    idx, ssc = batched_nms(boxes, scores, top_k=top_k)
-    sel = jnp.take_along_axis(boxes, jnp.maximum(idx, 0)[..., None],
-                              axis=1)
-    valid = ((idx >= 0) & (ssc > score_thresh)).astype(jnp.float32)
-    # packed fixed shape end to end so results stay on device
-    # (variable-length filtering happens at the consumer)
-    packed = jnp.concatenate([sel, ssc[..., None], valid[..., None]],
-                             axis=-1)
+    row layout and NMS policy cannot diverge between them.  Traced under
+    the scope `nms`: the device trace tells its time (the scan's top_k
+    steps) apart from the network's."""
+    with jax.named_scope("nms"):
+        probs = jax.nn.softmax(cls, axis=-1)[..., 1:]  # drop background
+        scores = probs.max(axis=-1)
+        boxes = decode_boxes(anchors, deltas)
+        idx, ssc = batched_nms(boxes, scores, top_k=top_k)
+        sel = jnp.take_along_axis(boxes, jnp.maximum(idx, 0)[..., None],
+                                  axis=1)
+        valid = ((idx >= 0) & (ssc > score_thresh)).astype(jnp.float32)
+        # packed fixed shape end to end so results stay on device
+        # (variable-length filtering happens at the consumer)
+        packed = jnp.concatenate([sel, ssc[..., None], valid[..., None]],
+                                 axis=-1)
     return packed, sel
 
 
@@ -149,6 +152,18 @@ def unpack_detections(row) -> Dict[str, np.ndarray]:
     return {"boxes": a[keep, :4], "scores": a[keep, 4]}
 
 
+def anchors_for(cache, shape, device=None) -> jnp.ndarray:
+    """The anchors of a (B, H, W, 3) batch's SAME-padded stride-16
+    feature map (ceil-divided), made once a resolution into `cache`;
+    committed to `device` where one is given."""
+    fh, fw = -(-shape[1] // 16), -(-shape[2] // 16)
+    if (fh, fw) not in cache:
+        a = make_anchors(fh, fw)
+        cache[(fh, fw)] = jnp.asarray(a) if device is None \
+            else jax.device_put(a, device)
+    return cache[(fh, fw)]
+
+
 def anchored_cost_flops(kern, batch):
     """Shared MFU probe for the anchors-based detection family
     (ObjectDetect / FaceDetect / InstanceSegment): resolve the batch's
@@ -156,12 +171,9 @@ def anchored_cost_flops(kern, batch):
     analysis for the jitted inference's FLOPs (infer.lowered_flops)."""
     from .infer import lowered_flops
     images = jnp.asarray(batch)
-    fh = -(-images.shape[1] // 16)
-    fw = -(-images.shape[2] // 16)
-    if (fh, fw) not in kern._anchors:
-        kern._anchors[(fh, fw)] = jnp.asarray(make_anchors(fh, fw))
     return lowered_flops(kern._infer, kern.params, images,
-                         kern._anchors[(fh, fw)])
+                         anchors_for(kern._anchors, images.shape,
+                                     getattr(kern, "_device", None)))
 
 
 @register_op(device=DeviceType.TPU, batch=8)
@@ -188,9 +200,16 @@ class ObjectDetect(Kernel):
         if checkpoint_dir is None and pretrained \
                 and width == self._shipped_width and num_classes == 2:
             checkpoint_dir = shipped_weights(self._shipped)
-        self.params = init_or_restore(
+        params = init_or_restore(
             self.model, jax.random.PRNGKey(seed),
             jnp.zeros((1, 128, 128, 3), jnp.uint8), checkpoint_dir)
+        # an instance that owns one chip commits its weights and anchors
+        # there (as DataParallelApply does for the other model ops):
+        # init_or_restore leaves them uncommitted on the default chip
+        devices = list(config.devices or [])
+        self._device = devices[0] if len(devices) == 1 else None
+        self.params = params if self._device is None \
+            else jax.device_put(params, self._device)
         self.score_thresh = float(score_thresh)
         self._anchors = {}  # (fh, fw) -> anchor tensor, per resolution
 
@@ -218,9 +237,6 @@ class ObjectDetect(Kernel):
         fetches once per task (a per-packet fetch would serialize the
         pipeline on d2h latency, PERF.md §5)."""
         images = jnp.asarray(frame)
-        # SAME-padded stride-16 backbone -> ceil-divided feature map
-        fh = -(-images.shape[1] // 16)
-        fw = -(-images.shape[2] // 16)
-        if (fh, fw) not in self._anchors:
-            self._anchors[(fh, fw)] = jnp.asarray(make_anchors(fh, fw))
-        return self._infer(self.params, images, self._anchors[(fh, fw)])
+        return self._infer(self.params, images,
+                           anchors_for(self._anchors, images.shape,
+                                       self._device))

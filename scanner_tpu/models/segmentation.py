@@ -30,7 +30,7 @@ import numpy as np
 
 from ..common import DeviceType, FrameType
 from ..graph.ops import Kernel, register_op
-from .detection import SSDHead, make_anchors, pack_detections
+from .detection import SSDHead, anchors_for, pack_detections
 from .nets import Backbone
 
 ROI_SIZE = 8          # roi-align grid (mask head upsamples 2x)
@@ -253,8 +253,5 @@ class InstanceSegment(Kernel):
         """Returns a (B, top_k, 6 + M*M) float32 batch, device-resident
         (single fetch per task at the sink, PERF.md §5)."""
         images = jnp.asarray(frame)
-        fh = -(-images.shape[1] // 16)
-        fw = -(-images.shape[2] // 16)
-        if (fh, fw) not in self._anchors:
-            self._anchors[(fh, fw)] = jnp.asarray(make_anchors(fh, fw))
-        return self._infer(self.params, images, self._anchors[(fh, fw)])
+        return self._infer(self.params, images,
+                           anchors_for(self._anchors, images.shape))
