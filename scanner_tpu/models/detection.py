@@ -4,6 +4,9 @@ Capability parity: reference examples/apps/object_detection_tensorflow
 (SSD mobilenet TF kernel) — rebuilt as an anchor-based SSD head over the
 shared JAX backbone, with jit-compiled box decode and a vectorized NMS that
 runs as a fixed-iteration lax loop (no data-dependent shapes on device).
+The boxes are decoded into four lane-dense (B, N) coordinate planes, and
+the loop reads them and the boxes' areas as loop-invariant operands over
+the whole batch at once; only the kept boxes are stacked, after it.
 """
 
 from __future__ import annotations
@@ -64,54 +67,57 @@ class SSDDetector(nn.Module):
                        dtype=self.dtype)(feat)
 
 
-def decode_boxes(anchors: jnp.ndarray, deltas: jnp.ndarray) -> jnp.ndarray:
-    """Standard SSD box decode -> [y1, x1, y2, x2] unit coords, clipped to
-    the image (downstream crops must never sample fully out of frame)."""
+def decode_boxes(anchors: jnp.ndarray, deltas: jnp.ndarray
+                 ) -> Tuple[jnp.ndarray, ...]:
+    """Standard SSD box decode -> the four coordinate planes (y1, x1, y2,
+    x2), each (B, N) in unit coords and clipped to the image (downstream
+    crops must never sample fully out of frame).  Planes and not a
+    (B, N, 4) stack: on the TPU a 4-wide minor dimension is padded to the
+    128 lanes, and the NMS loop reads each coordinate across all N."""
     cy = anchors[:, 0] + deltas[..., 0] * anchors[:, 2]
     cx = anchors[:, 1] + deltas[..., 1] * anchors[:, 3]
     h = anchors[:, 2] * jnp.exp(jnp.clip(deltas[..., 2], -4, 4))
     w = anchors[:, 3] * jnp.exp(jnp.clip(deltas[..., 3], -4, 4))
-    boxes = jnp.stack([cy - h / 2, cx - w / 2, cy + h / 2, cx + w / 2],
-                      axis=-1)
-    return jnp.clip(boxes, 0.0, 1.0)
+    return tuple(jnp.clip(c, 0.0, 1.0)
+                 for c in (cy - h / 2, cx - w / 2, cy + h / 2, cx + w / 2))
 
 
 @functools.partial(jax.jit, static_argnames=("top_k",))
-def batched_nms(boxes, scores, top_k: int = 32, iou_thresh: float = 0.5):
-    """Greedy NMS with a fixed iteration count: selects up to top_k boxes
-    per image; returns (idx, keep_scores) with -1/0 padding.  Fixed shapes
-    keep the whole postprocess on-device (no host sync per frame)."""
-    def one_image(b, s):
-        def area(bb):
-            return jnp.maximum(bb[..., 2] - bb[..., 0], 0) * \
-                jnp.maximum(bb[..., 3] - bb[..., 1], 0)
+def batched_nms(planes, scores, top_k: int = 32, iou_thresh: float = 0.5):
+    """Greedy NMS with a fixed iteration count over a batch: `planes` are
+    the (y1, x1, y2, x2) coordinate planes and `scores` the scores, each
+    (B, N).  Selects up to top_k boxes a row; returns (idx, keep_scores),
+    each (B, top_k), with -1/0 padding.  Fixed shapes keep the whole
+    postprocess on-device (no host sync per frame).
 
-        def iou(b1, b2):
-            y1 = jnp.maximum(b1[0], b2[..., 0])
-            x1 = jnp.maximum(b1[1], b2[..., 1])
-            y2 = jnp.minimum(b1[2], b2[..., 2])
-            x2 = jnp.minimum(b1[3], b2[..., 3])
-            inter = jnp.maximum(y2 - y1, 0) * jnp.maximum(x2 - x1, 0)
-            return inter / jnp.maximum(area(b1) + area(b2) - inter, 1e-9)
+    The planes and every box's area (computed once, before the loop) are
+    loop-invariant and lane-dense; the loop carries only the (B, N)
+    scores.  A step takes each row's argmax (first index on ties), reads
+    the chosen box's coordinates and area from the planes, and suppresses
+    by IoU against all N boxes at once."""
+    y1, x1, y2, x2 = planes
+    areas = jnp.maximum(y2 - y1, 0) * jnp.maximum(x2 - x1, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
 
-        def step(carry, _):
-            sc, sel_idx, sel_sc, i = carry
-            j = jnp.argmax(sc)
-            best = sc[j]
-            sel_idx = sel_idx.at[i].set(jnp.where(best > 0, j, -1))
-            sel_sc = sel_sc.at[i].set(jnp.maximum(best, 0))
-            overl = iou(b[j], b)
-            sc = jnp.where(overl > iou_thresh, -1.0, sc)
-            sc = sc.at[j].set(-1.0)
-            return (sc, sel_idx, sel_sc, i + 1), None
+    def pick(a, j):
+        return jnp.take_along_axis(a, j, axis=1)
 
-        init = (s, jnp.full((top_k,), -1, jnp.int32),
-                jnp.zeros((top_k,), jnp.float32), 0)
-        final, _ = jax.lax.scan(step, init, None, length=top_k)
-        _sc, idx, ssc, _i = final
-        return idx, ssc
+    def step(sc, _):
+        j = jnp.argmax(sc, axis=1)[:, None].astype(jnp.int32)
+        best = pick(sc, j)
+        iy1 = jnp.maximum(pick(y1, j), y1)
+        ix1 = jnp.maximum(pick(x1, j), x1)
+        iy2 = jnp.minimum(pick(y2, j), y2)
+        ix2 = jnp.minimum(pick(x2, j), x2)
+        inter = jnp.maximum(iy2 - iy1, 0) * jnp.maximum(ix2 - ix1, 0)
+        overl = inter / jnp.maximum(pick(areas, j) + areas - inter, 1e-9)
+        sc = jnp.where(overl > iou_thresh, -1.0, sc)
+        sc = jnp.where(lanes == j, -1.0, sc)
+        return sc, (jnp.where(best > 0, j, -1)[:, 0],
+                    jnp.maximum(best, 0)[:, 0])
 
-    return jax.vmap(one_image)(boxes, scores)
+    _sc, (idx, ssc) = jax.lax.scan(step, scores, None, length=top_k)
+    return idx.T, ssc.T
 
 
 def pack_detections(cls, deltas, anchors, score_thresh: float,
@@ -120,16 +126,19 @@ def pack_detections(cls, deltas, anchors, score_thresh: float,
     packed (B, top_k, 6) rows [y1, x1, y2, x2, score, valid] plus the
     selected boxes (B, top_k, 4).  Every detection-family kernel
     (ObjectDetect, FaceDetect, InstanceSegment) packs through here so the
-    row layout and NMS policy cannot diverge between them.  Traced under
-    the scope `nms`: the device trace tells its time (the scan's top_k
-    steps) apart from the network's."""
+    row layout and NMS policy cannot diverge between them.  The boxes
+    stay four (B, N) planes through the NMS; only the top_k kept ones
+    are gathered and stacked, after the loop.  Traced under the scope
+    `nms`: the device trace tells its time (the scan's top_k steps) apart
+    from the network's."""
     with jax.named_scope("nms"):
         probs = jax.nn.softmax(cls, axis=-1)[..., 1:]  # drop background
         scores = probs.max(axis=-1)
-        boxes = decode_boxes(anchors, deltas)
-        idx, ssc = batched_nms(boxes, scores, top_k=top_k)
-        sel = jnp.take_along_axis(boxes, jnp.maximum(idx, 0)[..., None],
-                                  axis=1)
+        planes = decode_boxes(anchors, deltas)
+        idx, ssc = batched_nms(planes, scores, top_k=top_k)
+        kept = jnp.maximum(idx, 0)
+        sel = jnp.stack([jnp.take_along_axis(c, kept, axis=1)
+                         for c in planes], axis=-1)
         valid = ((idx >= 0) & (ssc > score_thresh)).astype(jnp.float32)
         # packed fixed shape end to end so results stay on device
         # (variable-length filtering happens at the consumer)
