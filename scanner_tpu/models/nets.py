@@ -14,6 +14,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax.linen.dtypes import promote_dtype
+
+from ..util import metrics as _mx
+
+_M_STEM = _mx.registry().counter(
+    "scanner_tpu_backbone_stem_total",
+    "Backbone stems traced, by the form the input's shape chose: s2d "
+    "(H and W multiples of 4: the 7x7 stride-4 convolution over "
+    "12-channel column blocks) or plain (over the 3-channel frame).",
+    labels=["path"])
 
 
 class ResBlock(nn.Module):
@@ -36,6 +46,46 @@ class ResBlock(nn.Module):
         return nn.relu(x + h)
 
 
+class Stem(nn.Module):
+    """The backbone's 7x7 stride-4 SAME convolution, with `nn.Conv`'s
+    parameters: a (7, 7, Cin, C) `lecun_normal` kernel, a (C,) bias.
+
+    Where H and W are multiples of 4 it reads the frame as column
+    blocks: (B, H, W, Cin) -> (B, H, W/4, 4 Cin) is a reshape that moves
+    nothing, the kernel gains zero columns to 12 taps (3 before, 2 after)
+    regrouped to (7, 3, 4 Cin, C), and the convolution strides (4, 1)
+    with SAME's own padding, (1, 2) rows and one block each side.  The
+    products are the same and the added ones exact zeros.  A 3-channel
+    batch is what the TPU compiler would otherwise relay into a copy
+    with the batch on lanes and the channels on sublanes, padded ~21
+    times.  Other shapes take the plain convolution."""
+
+    features: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        B, H, W, cin = x.shape
+        kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                            (7, 7, cin, self.features))
+        bias = self.param("bias", nn.initializers.zeros_init(),
+                          (self.features,))
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        dn = ("NHWC", "HWIO", "NHWC")
+        if H % 4 == 0 and W % 4 == 0:
+            _M_STEM.labels(path="s2d").inc()
+            x = x.reshape(B, H, W // 4, 4 * cin)
+            kernel = jnp.pad(kernel, ((0, 0), (3, 2), (0, 0), (0, 0)))
+            kernel = kernel.reshape(7, 3, 4 * cin, self.features)
+            y = jax.lax.conv_general_dilated(
+                x, kernel, (4, 1), ((1, 2), (1, 1)), dimension_numbers=dn)
+        else:
+            _M_STEM.labels(path="plain").inc()
+            y = jax.lax.conv_general_dilated(
+                x, kernel, (4, 4), "SAME", dimension_numbers=dn)
+        return y + bias
+
+
 class Backbone(nn.Module):
     """ResNet-lite feature extractor: (B, H, W, 3) -> (B, H/16, W/16, C).
 
@@ -50,8 +100,7 @@ class Backbone(nn.Module):
     @nn.compact
     def __call__(self, x):
         x = x.astype(self.dtype) / 255.0
-        x = nn.Conv(self.width, (7, 7), strides=(4, 4), dtype=self.dtype,
-                    padding="SAME")(x)
+        x = Stem(self.width, dtype=self.dtype, name="Conv_0")(x)
         x = nn.GroupNorm(num_groups=8, dtype=self.dtype)(x)
         x = nn.relu(x)
         ch = self.width
